@@ -336,7 +336,8 @@ class FederatedTopology(LoadgenTopology):
             self._logs.append(logf)
             self.procs.append(subprocess.Popen(
                 cmd, stdout=logf, stderr=subprocess.STDOUT,
-                env=dict(os.environ),
+                # N schedulers cannot share one chip: a host-only drill
+                env=dict(os.environ, JAX_PLATFORMS="cpu"),
             ))
         self._wait_federation(startup_timeout)
 

@@ -99,6 +99,38 @@ def test_executor_uses_sidecar_then_falls_back(sidecar, sock_path):
     np.testing.assert_array_equal(after_death, local)
 
 
+def test_scheduler_behind_sidecar_falls_back_on_host(sock_path, monkeypatch):
+    """The sidecar holds the chip: a vtpu-scheduler started with
+    VTPU_COMPUTE_PLANE and no explicit JAX_PLATFORMS pins itself to the
+    CPU before touching JAX, stages nothing on a device, and with the
+    sidecar down still runs its exact in-process fallback on the host."""
+    import jax
+
+    from volcano_tpu.cmd import scheduler as sched_cmd
+    from volcano_tpu.faults.breaker import reset_breakers
+
+    monkeypatch.setenv("VTPU_COMPUTE_PLANE", sock_path)  # nothing listens
+    monkeypatch.setattr(sched_cmd, "configure_compile_cache", lambda: None)
+    monkeypatch.setattr(sched_cmd, "SchedulerDaemon", lambda *a, **k: None)
+    monkeypatch.setattr(sched_cmd, "serve_forever", lambda daemon: 0)
+    jax.config.update("jax_platforms", "tpu,cpu")  # as if unpinned
+    try:
+        assert sched_cmd.main([]) == 0
+        assert jax.config.jax_platforms == "cpu"
+        executor_mod.configure(sock_path)
+        assert not executor_mod.owns_device()
+        snap = generate_snapshot(n_tasks=64, n_nodes=16, gang_size=4, seed=4)
+        np.testing.assert_array_equal(
+            executor_mod.execute_allocate(snap), run_packed_auto(snap)
+        )
+        assert executor_mod.last_allocate_executor() != "auto"  # local
+    finally:
+        jax.config.update("jax_platforms", "cpu")
+        reset_breakers()  # the failed probe opened compute-plane's
+        executor_mod.configure(None)
+    assert executor_mod.owns_device()
+
+
 def test_action_through_sidecar_binds_identically(sidecar, sock_path, tmp_path):
     """Full framework path over the boundary: the jax-allocate action
     with the kernel executed in the SIDECAR process boundary produces

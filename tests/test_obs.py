@@ -426,6 +426,7 @@ class TestFederatedGangWaterfall:
         repl:quorum_wait span, correctly parented, across ≥3 OS
         processes."""
         from volcano_tpu.bus import connect_bus
+        from volcano_tpu.bus.protocol import NotLeaderError
 
         ports = [_free_port(), _free_port()]
         endpoints = ",".join(f"tcp://127.0.0.1:{p}" for p in ports)
@@ -444,7 +445,17 @@ class TestFederatedGangWaterfall:
             api = connect_bus(endpoints, wait=60.0)
             kube = KubeClient(api)
             vc = VolcanoClient(api)
-            vc.create_queue(build_queue("default"))
+            # reachable is not elected: the first write waits out the
+            # replicas' first election
+            deadline = time.monotonic() + 30.0
+            while True:
+                try:
+                    vc.create_queue(build_queue("default"))
+                    break
+                except NotLeaderError:
+                    if time.monotonic() > deadline:
+                        raise
+                    time.sleep(0.1)
             # n0-n3 hash to shard 0, n4-n7 to shard 1 (crc32 % 2): four
             # single-gang-task nodes per shard
             for i in range(8):

@@ -11,9 +11,8 @@ equivalence to scale:
                                              (≥10k nodes, VERDICT #3)
 
 Real-TPU compiled-Mosaic equivalence at the full 50k × 10k headline
-shape is asserted every round by bench.py's identical_bindings field
-(the driver records it in BENCH_rN.json); interpret mode here covers the
-kernel logic itself on CPU CI."""
+shape is asserted on the chip by chip_smoke.py; interpret mode here
+covers the kernel logic itself on CPU CI."""
 
 from __future__ import annotations
 

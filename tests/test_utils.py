@@ -72,3 +72,20 @@ class TestSerde:
         c = job.clone()
         c.spec.tasks[0].replicas = 9
         assert job.spec.tasks[0].replicas == 1
+
+
+class TestNativeBuild:
+    def test_built_from_source_keyed_by_hash(self, tmp_path, monkeypatch):
+        import hashlib
+        import os
+
+        from volcano_tpu import native
+
+        monkeypatch.setattr(native, "_BUILD_DIR", str(tmp_path))
+        with open(native._SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        so = native._build()
+        assert so == os.path.join(str(tmp_path), f"baseline-{digest}.so")
+        mtime = os.path.getmtime(so)
+        assert native._build() == so and os.path.getmtime(so) == mtime
+        assert os.listdir(tmp_path) == [os.path.basename(so)]  # no temp left

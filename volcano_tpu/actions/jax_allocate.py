@@ -48,6 +48,7 @@ from volcano_tpu.api import FitError, TaskInfo, TaskStatus
 from volcano_tpu.framework.interface import Action
 from volcano_tpu.framework.session import Session
 from volcano_tpu.metrics import metrics
+from volcano_tpu.ops.executor import owns_device
 from volcano_tpu.utils.logging import get_logger
 
 log = get_logger(__name__)
@@ -271,13 +272,16 @@ class JaxAllocateAction(Action):
         last_phase_stats["pack_ms"] = pack_s * 1e3
         metrics.update_kernel_duration("pack", pack_s)
 
-        if snap.cache_key is not None:
+        if snap.cache_key is not None and owns_device():
             # attach the device-resident mirror: only dirty rows travel
             try:
                 from volcano_tpu.ops.device_stage import get_stager
 
                 snap.device_planes = get_stager(snap.cache_key).stage(snap)
             except Exception as e:  # noqa: BLE001 — numpy path still valid
+                # counted with the other demotions, so a run that must
+                # stay on the device (chip_smoke.py) can see it
+                metrics.register_executor_fallback("device-stage", "host", "error")
                 log.error("device staging failed (%s); numpy planes", e)
 
         t0 = time.perf_counter()
@@ -347,7 +351,7 @@ class JaxAllocateAction(Action):
         # transfer runs concurrently with the pure-host ORDER replay and
         # the remaining relay is only the (delta-sized) task planes.
         prestaged = False
-        if pc is not None and nodes:
+        if pc is not None and nodes and owns_device():
             t0 = time.perf_counter()
             pending = pc.begin_nodes(
                 nodes, epoch, "predicates" in ssn.predicate_fns

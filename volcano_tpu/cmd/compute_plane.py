@@ -2,9 +2,12 @@
 
 The scheduler daemon runs the control plane; this process owns the
 device and serves the packed kernels over the versioned socket protocol
-(serving/compute_plane.py).  Colocate it with the accelerator and point
-the scheduler at it via ``VTPU_COMPUTE_PLANE=<socket>``; if it dies the
-scheduler's executors fall back in-process and re-probe.
+(serving/compute_plane.py).  On a chip host exactly one process holds
+the chip, and with a sidecar that process is this one: start the
+scheduler with ``VTPU_COMPUTE_PLANE=<socket>``, which pins the
+scheduler's JAX to the CPU (cmd/scheduler.py).  If the sidecar dies the
+scheduler's executors fall back in-process on the host, count the
+fallback, and re-probe.
 
 Usage: python -m volcano_tpu.cmd.compute_plane --socket /run/vtpu.sock
 """
@@ -15,6 +18,7 @@ import argparse
 import time
 
 from volcano_tpu.serving.compute_plane import ComputePlaneServer
+from volcano_tpu.utils.compile_cache import configure_compile_cache
 from volcano_tpu.utils.logging import get_logger
 
 log = get_logger(__name__)
@@ -37,6 +41,7 @@ def main(argv=None) -> int:
     from volcano_tpu.cmd.daemon import apply_faults
 
     apply_faults(args.faults)
+    configure_compile_cache()
 
     if args.warmup:
         # populate the jit cache so the first real session doesn't pay

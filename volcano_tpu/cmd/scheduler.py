@@ -9,11 +9,13 @@ cmd/scheduler/app/options/options.go:44-66.
 from __future__ import annotations
 
 import argparse
+import os
 
 from volcano_tpu.cache import SchedulerCache
 from volcano_tpu.client import APIServer, SchedulerClient
 from volcano_tpu.cmd.daemon import apply_faults, BaseDaemon, serve_forever
 from volcano_tpu.scheduler.scheduler import Scheduler
+from volcano_tpu.utils.compile_cache import configure_compile_cache
 
 
 def _explain_source(daemon: "SchedulerDaemon", namespace: str, job: str):
@@ -201,6 +203,18 @@ def resolve_bus(bus: str):
         raise SystemExit(str(e)) from e
 
 
+def pin_host_behind_sidecar() -> None:
+    """On a chip host exactly one process holds the chip.  Behind a
+    compute-plane sidecar that process is the sidecar, so this
+    scheduler runs JAX on the host: its in-process fallback (sidecar
+    down or restarting) must not start the TPU backend and contend for
+    the sidecar's chip.  Call before anything touches JAX."""
+    if os.environ.get("VTPU_COMPUTE_PLANE"):
+        import jax
+
+        jax.config.update("jax_platforms", "cpu")
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(prog="vtpu-scheduler")
     parser.add_argument("--scheduler-conf", default="")
@@ -361,7 +375,9 @@ def main(argv=None) -> int:
     )
     add_common_args(parser)
     args = parser.parse_args(argv)
+    pin_host_behind_sidecar()
     apply_faults(args.faults)
+    configure_compile_cache()
 
     from volcano_tpu.scheduler import util as sched_util
 
@@ -372,8 +388,6 @@ def main(argv=None) -> int:
     )
 
     if args.warmup:
-        import os
-
         if os.environ.get("VTPU_COMPUTE_PLANE"):
             # kernels run in the sidecar (which has its own --warmup);
             # the in-process copies only serve the failure fallback —

@@ -590,6 +590,13 @@ def register_executor_fallback(from_: str, to: str, cause: str) -> None:
     )
 
 
+def executor_fallbacks_total() -> float:
+    """Every demotion counted so far, across all label sets."""
+    name = f"{_NAMESPACE}_executor_fallbacks_total"
+    with registry._lock:
+        return sum(v for (n, _), v in registry._counters.items() if n == name)
+
+
 def update_circuit_breaker_state(executor: str, value: float) -> None:
     """0 = closed, 0.5 = half-open (probing), 1 = open (tripped)."""
     # label-vocab: executor — the per-name breaker registry

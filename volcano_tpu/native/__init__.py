@@ -11,30 +11,48 @@ reference" column.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import tempfile
 from typing import Optional
 
 import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "baseline.cpp")
-_SO = os.path.join(_HERE, "_baseline.so")
+#: build outputs (gitignored): one shared object per source content
+_BUILD_DIR = os.path.join(_HERE, "_build")
 
 _lib = None
 
 
 def _build() -> Optional[str]:
-    """Compile the shared object on demand (cached by mtime)."""
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return _SO
+    """Compile the shared object on demand, keyed by a hash of the
+    committed source — a stale or foreign binary is never loaded.  The
+    write goes through a temp name and an atomic rename, so processes
+    building at once each see a whole file."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = os.path.join(_BUILD_DIR, f"baseline-{digest}.so")
+    if os.path.exists(so):
+        return so
     try:
-        subprocess.run(
-            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread", _SRC, "-o", _SO],
-            check=True,
-            capture_output=True,
-        )
-        return _SO
+        os.makedirs(_BUILD_DIR, exist_ok=True)
+        fd, tmp = tempfile.mkstemp(suffix=".so", dir=_BUILD_DIR)
+        os.close(fd)
+        try:
+            subprocess.run(
+                ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
+                 _SRC, "-o", tmp],
+                check=True,
+                capture_output=True,
+            )
+            os.replace(tmp, so)
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
+        return so
     except (OSError, subprocess.CalledProcessError):
         return None
 

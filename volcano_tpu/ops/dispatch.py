@@ -20,6 +20,7 @@ import os
 
 import numpy as np
 
+from volcano_tpu.ops.chip_memory import SMEM_BUDGET, VMEM_BUDGET
 from volcano_tpu.ops.kernels import (
     DEFAULT_WEIGHTS,
     f32_lr_exact,
@@ -31,14 +32,6 @@ from volcano_tpu.ops.packing import PackedSnapshot
 #: sessions below this task*node area keep the plain scan (compile cost
 #: of the fancier kernels outweighs the win)
 _SMALL_AREA = 1_000_000
-
-#: VMEM budget for Pallas kernels.  v5e VMEM is 128 MiB; leave headroom
-#: for Mosaic's own buffers and the double-buffered grid pipeline.
-_VMEM_BUDGET = 96 * 1024 * 1024
-
-#: SMEM (scalar memory) budget — ~1 MiB on TPU; the preempt kernel's
-#: per-job scalar state must fit (large-J sessions fall back to dense).
-_SMEM_BUDGET = 768 * 1024
 
 #: node count above which a multi-device session shards the node axis
 #: instead of running the single-chip blocked formulation
@@ -134,7 +127,7 @@ def select_executor(
     if f32_lr_exact(snap) and _tpu_available():
         from volcano_tpu.ops.pallas_session import pallas_vmem_bytes
 
-        if pallas_vmem_bytes(snap) <= _VMEM_BUDGET:
+        if pallas_vmem_bytes(snap) <= VMEM_BUDGET:
             return "pallas"
     if _device_count() >= 2 and snap.n_nodes >= _SHARD_MIN_NODES:
         return "sharded"
@@ -187,8 +180,8 @@ def select_preempt_executor(pk) -> str:
         )
 
         if (
-            preempt_vmem_bytes(pk) <= _VMEM_BUDGET
-            and preempt_smem_bytes(pk) <= _SMEM_BUDGET
+            preempt_vmem_bytes(pk) <= VMEM_BUDGET
+            and preempt_smem_bytes(pk) <= SMEM_BUDGET
         ):
             return "pallas"
     return "dense"

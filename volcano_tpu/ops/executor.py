@@ -8,7 +8,10 @@ timeout, protocol error — falls back to the in-process executor and
 marks the sidecar unhealthy; a background-free probe-on-next-session
 retries it, so a bounced sidecar is picked back up without operator
 action.  Semantics are identical either way (the sidecar runs the same
-run_packed_auto / preempt dispatch on the same packed arrays).
+run_packed_auto / preempt dispatch on the same packed arrays).  The
+sidecar holds the chip, so a scheduler behind it runs on the host
+(vtpu-scheduler pins JAX to the CPU at startup) and stages nothing on a
+device (:func:`owns_device`).
 """
 
 from __future__ import annotations
@@ -93,6 +96,14 @@ def _get_remote() -> Optional[_Remote]:
         path = os.environ.get("VTPU_COMPUTE_PLANE", "")
         _remote = _Remote(path) if path else None
     return _remote
+
+
+def owns_device() -> bool:
+    """Whether this process may touch the accelerator.  On a chip host
+    exactly one process holds the chip: with a compute-plane sidecar
+    configured that process is the sidecar, and this one stages nothing
+    on a device."""
+    return _get_remote() is None
 
 
 #: did the last execute_allocate run in-process or on the sidecar?

@@ -42,7 +42,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from volcano_tpu.ops.blocked import (
     _block_scores,
@@ -88,12 +88,7 @@ def _sharded_blocked_kernel(
     every shard (inputs to the replicated scan are gathered, hence
     bit-identical)."""
     my = jax.lax.axis_index(AXIS)
-    # jax.lax.axis_size is recent API; psum of 1 over the axis is the
-    # portable equivalent (constant-folded at trace time)
-    if hasattr(jax.lax, "axis_size"):
-        n_dev = jax.lax.axis_size(AXIS)
-    else:
-        n_dev = jax.lax.psum(1, AXIS)
+    n_dev = jax.lax.axis_size(AXIS)
     n_loc1 = node_idle.shape[0]
     n_loc = n_loc1 - 1  # real rows; row n_loc is the infeasible dummy
     T = task_resreq.shape[0]
@@ -261,67 +256,76 @@ def _sharded_blocked_kernel(
     return chosen, job_assigned
 
 
+#: partition of each argument of the sharded pass, in call order:
+#: node-axis arrays split over AXIS, task/class/job arrays replicated
+_IN_SPECS = (
+    P(None, None),  # task_resreq
+    P(None),  # task_job
+    P(None),  # task_feas_class
+    P(None, None),  # class_sel_bits
+    P(None, None),  # class_tol_bits
+    P(AXIS, None),  # node_idle
+    P(AXIS, None),  # node_used
+    P(AXIS, None),  # node_alloc
+    P(AXIS, None),  # node_label_bits
+    P(AXIS, None),  # node_taint_bits
+    P(AXIS),  # node_ok
+    P(AXIS),  # node_task_count
+    P(AXIS),  # node_max_tasks
+    P(None),  # job_min_available
+    P(None),  # tolerance
+    P(None),  # active
+)
+
+
 def make_sharded_session(
     mesh: Mesh,
     weights: ScoreWeights = DEFAULT_WEIGHTS,
     block_size: int = 64,
     top_k: int = 8,
 ):
-    """Build the jitted node-sharded blocked pass for ``mesh``.  Node-axis
-    arrays are sharded over AXIS; task/class/job arrays are replicated.
-    Returns fn(arrays…) → (chosen global node ids, job_assigned)."""
-    node_spec2 = P(AXIS, None)
-    node_spec1 = P(AXIS)
-    rep2 = P(None, None)
-    rep1 = P(None)
-
+    """Build the jitted node-sharded blocked pass for ``mesh``
+    (arguments partitioned as ``_IN_SPECS``).  Returns fn(arrays…) →
+    (chosen global node ids, job_assigned)."""
     body = functools.partial(
         _sharded_blocked_kernel,
         weights=weights,
         block_size=block_size,
         top_k=top_k,
     )
-
-    # jax.shard_map is recent API; older jax ships it under
-    # jax.experimental with `check_rep` instead of `check_vma`
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None or not callable(shard_map):
-        from jax.experimental.shard_map import shard_map
-    import inspect
-
-    _params = inspect.signature(shard_map).parameters
-    _check_kw = {"check_vma": False} if "check_vma" in _params else {"check_rep": False}
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(
-            rep2,  # task_resreq
-            rep1,  # task_job
-            rep1,  # task_feas_class
-            rep2,  # class_sel_bits
-            rep2,  # class_tol_bits
-            node_spec2,  # node_idle
-            node_spec2,  # node_used
-            node_spec2,  # node_alloc
-            node_spec2,  # node_label_bits
-            node_spec2,  # node_taint_bits
-            node_spec1,  # node_ok
-            node_spec1,  # node_task_count
-            node_spec1,  # node_max_tasks
-            rep1,  # job_min_available
-            rep1,  # tolerance
-            rep1,  # active
-        ),
-        out_specs=(rep1, rep1),
-        **_check_kw,
+        in_specs=_IN_SPECS,
+        out_specs=(P(None), P(None)),
+        check_vma=False,
     )
     return jax.jit(sharded)
+
+
+def sharded_session_inputs(snap: PackedSnapshot, n_dev: int, block_size: int = 64):
+    """Host arrays of one sharded session, in ``_IN_SPECS`` order minus
+    the trailing per-round ``active`` mask → (arrays, T_blk)."""
+    task_feas_class, class_sel, class_tol = _feasibility_classes(snap)
+    T_blk, pad_tasks = task_block_padding(snap, block_size)
+    arrays = [
+        pad_tasks(snap.task_resreq),
+        pad_tasks(snap.task_job),
+        pad_tasks(task_feas_class),
+        class_sel,
+        class_tol,
+        *_shard_nodes_with_dummies(snap, n_dev),
+        snap.job_min_available,
+        snap.tolerance,
+    ]
+    return arrays, T_blk
 
 
 def _shard_nodes_with_dummies(snap: PackedSnapshot, n_dev: int):
     """Rearrange node arrays into n_dev chunks of n_loc real rows + one
     trailing infeasible dummy row each → global width n_dev*(n_loc+1).
-    Global id mapping: (shard s, local i) ↔ true node s*n_loc + i."""
+    Global id mapping: (shard s, local i) ↔ true node s*n_loc + i.
+    Returned in ``_IN_SPECS`` order."""
     N_pad = snap.node_idle.shape[0]
     if N_pad % n_dev:
         raise ValueError(
@@ -336,16 +340,16 @@ def _shard_nodes_with_dummies(snap: PackedSnapshot, n_dev: int):
             n_dev * (n_loc + 1), *arr.shape[1:]
         )
 
-    return {
-        "node_idle": rearrange(snap.node_idle),
-        "node_used": rearrange(snap.node_used),
-        "node_alloc": rearrange(snap.node_alloc),
-        "node_label_bits": rearrange(snap.node_label_bits),
-        "node_taint_bits": rearrange(snap.node_taint_bits),
-        "node_ok": rearrange(snap.node_ok, fill=False),
-        "node_task_count": rearrange(snap.node_task_count),
-        "node_max_tasks": rearrange(snap.node_max_tasks),
-    }, n_loc
+    return [
+        rearrange(snap.node_idle),
+        rearrange(snap.node_used),
+        rearrange(snap.node_alloc),
+        rearrange(snap.node_label_bits),
+        rearrange(snap.node_taint_bits),
+        rearrange(snap.node_ok, fill=False),
+        rearrange(snap.node_task_count),
+        rearrange(snap.node_max_tasks),
+    ]
 
 
 def run_packed_sharded(
@@ -364,39 +368,21 @@ def run_packed_sharded(
     if not f32_lr_exact(snap):
         weights = weights._replace(lr_int_exact=True)
 
-    task_feas_class, class_sel, class_tol = _feasibility_classes(snap)
-    node_arrays, n_loc = _shard_nodes_with_dummies(snap, n_dev)
-
-    T_blk, pad_tasks = task_block_padding(snap, block_size)
-
-    task_job = pad_tasks(snap.task_job)
-
+    arrays, T_blk = sharded_session_inputs(snap, n_dev, block_size)
     fn = make_sharded_session(
         mesh, weights=weights, block_size=block_size, top_k=top_k
     )
-    # Hoist the invariant arrays to device ONCE — only `active` changes
+    # Place each invariant array ONCE with its final sharding — every
+    # device receives only its node shard; only `active` changes
     # between gang rounds.
     dev = [
-        jnp.asarray(pad_tasks(snap.task_resreq)),
-        jnp.asarray(task_job),
-        jnp.asarray(pad_tasks(task_feas_class)),
-        jnp.asarray(class_sel),
-        jnp.asarray(class_tol),
-        jnp.asarray(node_arrays["node_idle"]),
-        jnp.asarray(node_arrays["node_used"]),
-        jnp.asarray(node_arrays["node_alloc"]),
-        jnp.asarray(node_arrays["node_label_bits"]),
-        jnp.asarray(node_arrays["node_taint_bits"]),
-        jnp.asarray(node_arrays["node_ok"]),
-        jnp.asarray(node_arrays["node_task_count"]),
-        jnp.asarray(node_arrays["node_max_tasks"]),
-        jnp.asarray(snap.job_min_available),
-        jnp.asarray(snap.tolerance),
+        jax.device_put(a, NamedSharding(mesh, spec))
+        for a, spec in zip(arrays, _IN_SPECS)
     ]
 
     return gang_fixpoint(
         lambda active: fn(*dev, active),
-        task_job,
+        arrays[1],
         snap.job_min_available,
         snap.job_ready_count,
         snap.n_tasks,

@@ -459,8 +459,12 @@ class ComputePlaneClient:
         # requires-lock: self._lock
         if self._sock is None:
             s = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-            s.settimeout(self.timeout)
-            s.connect(self.socket_path)
+            try:
+                s.settimeout(self.timeout)
+                s.connect(self.socket_path)
+            except OSError:
+                s.close()  # a sidecar that is down must not cost an fd
+                raise
             self._sock = s
         return self._sock
 
