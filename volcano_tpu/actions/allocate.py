@@ -12,8 +12,9 @@ so the replay-order == host-order premise cannot drift.
 
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
+from volcano_tpu import trace
 from volcano_tpu.api import FitError, JobInfo, NodeInfo, TaskInfo, TaskStatus
 from volcano_tpu.api import unschedule_info as reasons
 from volcano_tpu.apis import scheduling
@@ -133,21 +134,26 @@ def make_predicate_fn(ssn: Session):
     return predicate_fn
 
 
-def host_node_chooser(ssn: Session):
+def host_node_chooser(ssn: Session, tally: Optional[Dict[str, float]] = None):
     """The reference per-task path: PredicateNodes → PrioritizeNodes →
-    SelectBestNode (allocate.go:191-199)."""
+    SelectBestNode (allocate.go:191-199).  Each call is one
+    ``vtpu/allocate.host_sweep`` span with the task's uid and the count
+    of nodes whose predicates it evaluated; with ``tally`` given, the
+    calls also add to its ``host_sweep_nodes`` and ``host_sweep_ms``."""
     all_nodes = sched_util.get_node_list(ssn.nodes)
     predicate_fn = make_predicate_fn(ssn)
 
-    def choose(task: TaskInfo, job: JobInfo) -> Optional[NodeInfo]:
+    def sweep(task: TaskInfo, job: JobInfo) -> Tuple[Optional[NodeInfo], int]:
         predicate_nodes, fit_errors = sched_util.predicate_nodes(
             task, all_nodes, predicate_fn
         )
+        # node names are unique: every node tried either fit or failed
+        tried = len(predicate_nodes) + len(fit_errors.nodes)
         if not predicate_nodes:
             job.nodes_fit_errors[task.uid] = fit_errors
             for reason in fit_errors.histogram():
                 metrics.register_unschedulable_reason(reason)
-            return None
+            return None, tried
         node_scores = sched_util.prioritize_nodes(
             task,
             predicate_nodes,
@@ -155,7 +161,17 @@ def host_node_chooser(ssn: Session):
             ssn.node_order_map_fn,
             ssn.node_order_reduce_fn,
         )
-        return sched_util.select_best_node(node_scores)
+        return sched_util.select_best_node(node_scores), tried
+
+    def choose(task: TaskInfo, job: JobInfo) -> Optional[NodeInfo]:
+        with trace.span("vtpu/allocate.host_sweep", "allocate", task=task.uid) as sp:
+            node, tried = sweep(task, job)
+            sp.set(nodes=tried)
+        metrics.register_host_sweep(tried)
+        if tally is not None:
+            tally["host_sweep_nodes"] = tally.get("host_sweep_nodes", 0) + tried
+            tally["host_sweep_ms"] = tally.get("host_sweep_ms", 0.0) + sp.seconds * 1e3
+        return node
 
     return choose
 

@@ -385,6 +385,7 @@ def try_fast_apply(
     _ja.last_phase_stats["commit_handoff_ms"] = (
         time.perf_counter() - t0
     ) * 1e3
+    _ja.last_phase_stats["fast_apply_tasks"] = len(bulk)
     # journal only after the batch landed — "bind" means an actual
     # cache bind, and bind_batch mutates nothing when it raises
     if ssn._trace.enabled:

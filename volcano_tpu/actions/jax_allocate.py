@@ -34,9 +34,9 @@ keeps the result valid — semantics never degrade below the host action.
 from __future__ import annotations
 
 import os
-import time
 from typing import Dict, List, Optional, Tuple
 
+from volcano_tpu import trace
 from volcano_tpu.actions.allocate import (
     drive_allocate_loop,
     gang_end_job,
@@ -242,35 +242,34 @@ class JaxAllocateAction(Action):
             return {}, None
 
         enforce = "predicates" in ssn.predicate_fns
-        t0 = time.perf_counter()
-        if pack_cache is not None and ssn.pack_epoch is not None:
-            # warm path: delta-assemble from the cycle-persistent cache
-            snap = pack_cache.pack(
-                ordered_tasks,
-                list(jobs.values()),
-                nodes,
-                ssn.pack_epoch,
-                enforce_pod_count=enforce,
-            )
-            last_phase_stats.update(pack_cache.last_stats)
-            if getattr(ssn.cache, "in_micro_cycle", False):
-                # a micro-triggered cycle that still had to cold-rebuild
-                # (registry overflow, axis change, …) paid full-cycle
-                # cost — attribute the cause so the SLO harness can see
-                # why the incremental path was unsound
-                cause = pack_cache.last_stats.get("cold_cause")
-                if cause is not None:
-                    metrics.register_full_cycle_fallback(cause)
-        else:
-            snap = pack_session(
-                ordered_tasks,
-                list(jobs.values()),
-                nodes,
-                enforce_pod_count=enforce,
-            )
-        pack_s = time.perf_counter() - t0
-        last_phase_stats["pack_ms"] = pack_s * 1e3
-        metrics.update_kernel_duration("pack", pack_s)
+        with trace.span("vtpu/allocate.pack", "kernel", label="kernel:pack") as sp:
+            if pack_cache is not None and ssn.pack_epoch is not None:
+                # warm path: delta-assemble from the cycle-persistent cache
+                snap = pack_cache.pack(
+                    ordered_tasks,
+                    list(jobs.values()),
+                    nodes,
+                    ssn.pack_epoch,
+                    enforce_pod_count=enforce,
+                )
+                last_phase_stats.update(pack_cache.last_stats)
+                if getattr(ssn.cache, "in_micro_cycle", False):
+                    # a micro-triggered cycle that still had to cold-rebuild
+                    # (registry overflow, axis change, …) paid full-cycle
+                    # cost — attribute the cause so the SLO harness can see
+                    # why the incremental path was unsound
+                    cause = pack_cache.last_stats.get("cold_cause")
+                    if cause is not None:
+                        metrics.register_full_cycle_fallback(cause)
+            else:
+                snap = pack_session(
+                    ordered_tasks,
+                    list(jobs.values()),
+                    nodes,
+                    enforce_pod_count=enforce,
+                )
+        last_phase_stats["pack_ms"] = sp.seconds * 1e3
+        metrics.update_kernel_duration("pack", sp.seconds)
 
         if snap.cache_key is not None and owns_device():
             # attach the device-resident mirror: only dirty rows travel
@@ -284,7 +283,6 @@ class JaxAllocateAction(Action):
                 metrics.register_executor_fallback("device-stage", "host", "error")
                 log.error("device staging failed (%s); numpy planes", e)
 
-        t0 = time.perf_counter()
         # executor indirection: in-process kernels, or the compute-plane
         # sidecar when VTPU_COMPUTE_PLANE is configured (with automatic
         # in-process fallback when the sidecar is down).  explain=True
@@ -294,10 +292,13 @@ class JaxAllocateAction(Action):
         from volcano_tpu.faults.watchdog import CycleDeadlineExceeded
 
         try:
-            assignment = execute_allocate(
-                snap, weights=self.weights, gang_rounds=self.gang_rounds,
-                explain=self.explain,
-            )
+            with trace.span(
+                "vtpu/allocate.dispatch", "kernel", label="kernel:execute"
+            ) as sp:
+                assignment = execute_allocate(
+                    snap, weights=self.weights, gang_rounds=self.gang_rounds,
+                    explain=self.explain,
+                )
         except CycleDeadlineExceeded as e:
             # cycle watchdog: the device phase overran its budget and
             # was abandoned.  Nothing session-side has mutated (the
@@ -311,7 +312,7 @@ class JaxAllocateAction(Action):
                 rec.event("watchdog:device-phase-abandoned", "fault",
                           error=str(e))
             return {}, snap
-        metrics.update_kernel_duration("execute", time.perf_counter() - t0)
+        metrics.update_kernel_duration("execute", sp.seconds)
 
         rec = ssn._trace
         if rec.enabled and rec.should_capture():
@@ -352,32 +353,30 @@ class JaxAllocateAction(Action):
         # the remaining relay is only the (delta-sized) task planes.
         prestaged = False
         if pc is not None and nodes and owns_device():
-            t0 = time.perf_counter()
-            pending = pc.begin_nodes(
-                nodes, epoch, "predicates" in ssn.predicate_fns
-            )
-            if pending is not None:
-                try:
-                    from volcano_tpu.ops.device_stage import get_stager
+            with trace.span("vtpu/allocate.prestage", "kernel") as sp:
+                pending = pc.begin_nodes(
+                    nodes, epoch, "predicates" in ssn.predicate_fns
+                )
+                if pending is not None:
+                    try:
+                        from volcano_tpu.ops.device_stage import get_stager
 
-                    get_stager(pc.key).prestage(
-                        pending["planes"], pending["dirty_pos"], pc.rev + 1
-                    )
-                    prestaged = True
-                except Exception as e:  # noqa: BLE001 — stage() recovers
-                    log.error("node-plane prestage failed: %s", e)
-            last_phase_stats["node_prepack_ms"] = (
-                time.perf_counter() - t0
-            ) * 1e3
+                        get_stager(pc.key).prestage(
+                            pending["planes"], pending["dirty_pos"], pc.rev + 1
+                        )
+                        prestaged = True
+                    except Exception as e:  # noqa: BLE001 — stage() recovers
+                        log.error("node-plane prestage failed: %s", e)
+            last_phase_stats["node_prepack_ms"] = sp.seconds * 1e3
 
-        t0 = time.perf_counter()
-        with ssn._trace.span("jax-allocate:order", "action"):
+        with trace.span(
+            "vtpu/allocate.order", "action", label="jax-allocate:order"
+        ) as sp:
             ordered = compute_task_order(ssn)
-        order_s = time.perf_counter() - t0
-        last_phase_stats["order_ms"] = order_s * 1e3
+        last_phase_stats["order_ms"] = sp.seconds * 1e3
         if prestaged:
             # the window the staged transfer had to overlap host work
-            last_phase_stats["relay_overlap_ms"] = order_s * 1e3
+            last_phase_stats["relay_overlap_ms"] = sp.seconds * 1e3
         if not ordered:
             if self.explain:
                 # nothing pending → nothing to explain; clear the
@@ -416,7 +415,8 @@ class JaxAllocateAction(Action):
                     last_phase_stats["explain_ms"] = explain_ms
 
         try:
-            self._apply(ssn, ordered, proposals, snap, explain_ctx)
+            with trace.span("vtpu/allocate.apply", "action"):
+                self._apply(ssn, ordered, proposals, snap, explain_ctx)
         finally:
             if self.explain:
                 # also clears: a cycle that explained nothing (all
@@ -426,16 +426,35 @@ class JaxAllocateAction(Action):
                 self._publish_explain(ssn, explain_ctx)
 
     def _apply(self, ssn, ordered, proposals, snap, explain_ctx) -> None:
+        """APPLY, counting the path that resolves each task
+        (``metrics.APPLY_PATHS``) into the Prometheus counters and
+        ``last_phase_stats`` (``apply_<path>``, ``apply_rejected``,
+        ``host_sweep_nodes``, ``host_sweep_ms``)."""
+        paths = dict.fromkeys(metrics.APPLY_PATHS, 0)
+        tally = {"apply_rejected": 0, "host_sweep_nodes": 0, "host_sweep_ms": 0.0}
+        try:
+            self._apply_paths(ssn, ordered, proposals, snap, explain_ctx, paths, tally)
+        finally:
+            metrics.register_allocate_apply(paths, tally["apply_rejected"])
+            last_phase_stats.update({f"apply_{p}": n for p, n in paths.items()})
+            last_phase_stats.update(tally)
+
+    def _apply_paths(self, ssn, ordered, proposals, snap, explain_ctx,
+                     paths, tally) -> None:
         # Fully-placed exact sessions commit in bulk (actions/fast_apply);
         # anything outside that envelope runs the loop below.
         if snap is not None:
             from volcano_tpu.actions.fast_apply import try_fast_apply
 
-            if try_fast_apply(ssn, ordered, proposals, snap):
+            with trace.span("vtpu/allocate.fast_apply", "action") as sp:
+                done = try_fast_apply(ssn, ordered, proposals, snap)
+                sp.set(tasks=last_phase_stats.get("fast_apply_tasks", 0))
+            paths["fast_apply"] = last_phase_stats.pop("fast_apply_tasks", 0)
+            if done:
                 return
 
         predicate_fn = make_predicate_fn(ssn)
-        host_choose = host_node_chooser(ssn)
+        host_choose = host_node_chooser(ssn, tally)
 
         def choose_node(task: TaskInfo, job):
             """Kernel proposal with O(1) validation; host path fallback."""
@@ -445,9 +464,11 @@ class JaxAllocateAction(Action):
                 if node is not None:
                     try:
                         predicate_fn(task, node)
+                        paths["proposal"] += 1
                         return node
                     except FitError:
                         pass  # capacity/relational race → host fallback
+                tally["apply_rejected"] += 1
             if explain_ctx is not None:
                 fe = explain_ctx.try_explain(task)
                 if fe is not None:
@@ -455,7 +476,9 @@ class JaxAllocateAction(Action):
                     # FitErrors (the same writeback the host sweep feeds)
                     # and skip the O(N) host predicate scan entirely
                     job.nodes_fit_errors[task.uid] = fe
+                    paths["explained"] += 1
                     return None
+            paths["host_sweep"] += 1
             return host_choose(task, job)
 
         drive_allocate_loop(

@@ -31,6 +31,7 @@ from typing import Tuple
 
 import numpy as np
 
+from volcano_tpu import trace
 from volcano_tpu.actions.preempt import PreemptAction
 from volcano_tpu.api import FitError, TaskStatus
 from volcano_tpu.framework.interface import Action
@@ -71,7 +72,8 @@ class JaxPreemptAction(Action):
         from volcano_tpu.ops.preempt_pack import pack_preempt_session
 
         try:
-            pk = pack_preempt_session(ssn)
+            with trace.span("vtpu/preempt.pack", "action"):
+                pk = pack_preempt_session(ssn)
         except ValueError as e:
             # unsupported preemptable tier configuration → host path
             log.info("preempt pack refused (%s); host fallback", e)
@@ -86,7 +88,8 @@ class JaxPreemptAction(Action):
             PreemptAction().execute(ssn)
             return
 
-        evicted, pipelined = self._device_outcome(pk)
+        with trace.span("vtpu/preempt.device", "kernel"):
+            evicted, pipelined = self._device_outcome(pk)
 
         if not evicted.any() and not (pipelined >= 0).any():
             # nothing to evict — the preemptors stay Pending; explain
@@ -101,6 +104,11 @@ class JaxPreemptAction(Action):
             metrics.register_preemption_attempts()
             return
 
+        with trace.span("vtpu/preempt.apply", "action"):
+            self._apply(ssn, pk, evicted, pipelined)
+
+    def _apply(self, ssn: Session, pk, evicted, pipelined) -> None:
+        """Evict the device's victims, pipeline its preemptors, commit."""
         stmt = ssn.statement()
         try:
             # victims in global (node-major) eviction order

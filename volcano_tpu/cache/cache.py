@@ -22,6 +22,7 @@ from volcano_tpu.api import (
     TaskInfo,
     TaskStatus,
 )
+from volcano_tpu import trace
 from volcano_tpu.api.job_info import get_job_id
 from volcano_tpu.api.queue_info import NamespaceCollection
 from volcano_tpu.apis import core, scheduling, scheme
@@ -335,11 +336,12 @@ class SchedulerCache(Cache):
 
     def flush(self) -> None:
         """Wait for async side effects (test/shutdown aid)."""
-        if self._commit_plane is not None:
-            self._commit_plane.barrier()
-        for f in list(self._pending):
-            f.result()
-        self._pending.clear()
+        with trace.span("vtpu/cache.flush", "cache"):
+            if self._commit_plane is not None:
+                self._commit_plane.barrier()
+            for f in list(self._pending):
+                f.result()
+            self._pending.clear()
 
     def stop_commit_plane(self) -> None:
         """Drain and stop the pipelined commit workers (shutdown aid)."""
@@ -845,6 +847,10 @@ class SchedulerCache(Cache):
     # ---- snapshot (cache.go:712-790) ----
 
     def snapshot(self, scope: str = "full") -> ClusterInfo:
+        with trace.span("vtpu/cache.snapshot", "cache", scope=scope):
+            return self._snapshot(scope)
+
+    def _snapshot(self, scope: str) -> ClusterInfo:
         # ``scope`` is the incremental-session seam:
         #   "full"       — every job (the classic snapshot);
         #   "restricted" — clone ONLY jobs with schedulable work

@@ -36,9 +36,24 @@ def open_session(
     seed into ``ssn.share_seed`` so proportion/DRF can seed the totals
     the excluded jobs would have contributed.  Restricted sessions run
     with ``pack_epoch=None``: the cycle-persistent warm packer's
-    registry must only ever consume full worlds."""
-    rec = trace.get_recorder()
-    open_start = time.perf_counter()
+    registry must only ever consume full worlds.  The session's
+    ``open_seconds`` is the duration of its ``vtpu/session.open`` span."""
+    with trace.span(
+        "vtpu/session.open", "framework", label="open_session"
+    ) as sp:
+        ssn = _open_session(cache, tiers, configurations, snapshot, job_uids)
+        sp.set(jobs=len(ssn.jobs), nodes=len(ssn.nodes), queues=len(ssn.queues))
+    ssn.open_seconds = sp.seconds
+    log.debug(
+        "Open session %s with %d jobs and %d queues",
+        ssn.uid,
+        len(ssn.jobs),
+        len(ssn.queues),
+    )
+    return ssn
+
+
+def _open_session(cache, tiers, configurations, snapshot, job_uids) -> Session:
     ssn = Session(cache)
     ssn.tiers = tiers
     ssn.configurations = configurations
@@ -94,14 +109,12 @@ def open_session(
                 )
 
     for plugin in ssn.plugins.values():
-        start = time.perf_counter()
-        plugin.on_session_open(ssn)
-        plugin_s = time.perf_counter() - start
-        metrics.update_plugin_duration(plugin.name(), plugin_s)
-        if rec.enabled:
-            rec.complete(
-                f"plugin:{plugin.name()}.open", "plugin", start, plugin_s
-            )
+        name = plugin.name()
+        with trace.span(
+            f"vtpu/plugin.{name}.open", "plugin", label=f"plugin:{name}.open"
+        ) as sp:
+            plugin.on_session_open(ssn)
+        metrics.update_plugin_duration(name, sp.seconds)
 
     for job in list(ssn.jobs.values()):
         vr = ssn.job_valid(job)
@@ -122,41 +135,26 @@ def open_session(
                     ),
                 )
             del ssn.jobs[job.uid]
-
-    if rec.enabled:
-        rec.complete(
-            "open_session",
-            "framework",
-            open_start,
-            time.perf_counter() - open_start,
-            jobs=len(ssn.jobs),
-            nodes=len(ssn.nodes),
-            queues=len(ssn.queues),
-        )
-    log.debug(
-        "Open session %s with %d jobs and %d queues",
-        ssn.uid,
-        len(ssn.jobs),
-        len(ssn.queues),
-    )
     return ssn
 
 
 def close_session(ssn: Session) -> None:
     """framework.go:56-66 + session.go closeSession:141-155."""
-    rec = trace.get_recorder()
-    close_start = time.perf_counter()
-    for plugin in ssn.plugins.values():
-        start = time.perf_counter()
-        plugin.on_session_close(ssn)
-        plugin_s = time.perf_counter() - start
-        metrics.update_plugin_duration(plugin.name(), plugin_s)
-        if rec.enabled:
-            rec.complete(
-                f"plugin:{plugin.name()}.close", "plugin", start, plugin_s
-            )
+    with trace.span("vtpu/session.close", "framework", label="close_session"):
+        _close_session(ssn)
 
-    JobUpdater(ssn).update_all()
+
+def _close_session(ssn: Session) -> None:
+    for plugin in ssn.plugins.values():
+        name = plugin.name()
+        with trace.span(
+            f"vtpu/plugin.{name}.close", "plugin", label=f"plugin:{name}.close"
+        ) as sp:
+            plugin.on_session_close(ssn)
+        metrics.update_plugin_duration(name, sp.seconds)
+
+    with trace.span("vtpu/session.job_updater", "framework"):
+        JobUpdater(ssn).update_all()
 
     # hand untouched clones back for reuse by the next snapshot (no-op
     # unless the cache opted into snapshot_reuse) — after plugin closes
@@ -164,12 +162,6 @@ def close_session(ssn: Session) -> None:
     release = getattr(ssn.cache, "release_session_clones", None)
     if release is not None:
         release(ssn.clone_gen, ssn.touched_jobs, ssn.touched_nodes)
-
-    if rec.enabled:
-        rec.complete(
-            "close_session", "framework", close_start,
-            time.perf_counter() - close_start,
-        )
 
     ssn.jobs = {}
     ssn.nodes = {}

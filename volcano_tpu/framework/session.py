@@ -55,6 +55,8 @@ class Session:
         #: when tracing is off, so the emit guards cost one attribute
         #: access per placement.
         self._trace = trace.get_recorder()
+        #: how long open_session took (its vtpu/session.open span)
+        self.open_seconds = 0.0
 
         self.pod_group_status: Dict[str, scheduling.PodGroupStatus] = {}
         #: pod-group PHASE of every job at session open — the attempts

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from typing import List, Tuple, TYPE_CHECKING
 
+from volcano_tpu import trace
 from volcano_tpu.api import TaskInfo, TaskStatus
 from volcano_tpu.utils.logging import get_logger
 
@@ -166,6 +167,10 @@ class Statement:
         self.operations.clear()
 
     def commit(self) -> None:
+        with trace.span("vtpu/statement.commit", "framework", ops=len(self.operations)):
+            self._commit()
+
+    def _commit(self) -> None:
         # consecutive allocates coalesce into one bind_batch (one mutex
         # hold, one commit frame); an interleaved evict flushes first so
         # cache-side effect ordering matches the operation log

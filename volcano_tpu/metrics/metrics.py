@@ -24,6 +24,9 @@ _LATENCY_BUCKETS_MS = [5.0 * (2**k) for k in range(10)]
 # the bucketed range rather than in +Inf.
 _LATENCY_BUCKETS_US = [5.0 * (2**k) for k in range(16)]
 
+# GC pauses: 100µs × 4^k up to ~26 s, in seconds.
+_GC_PAUSE_BUCKETS_S = [1e-4 * (4**k) for k in range(10)]
+
 # Job-level end-to-end latency (creation → first scheduled cycle) is
 # seconds-to-minutes scale: 100ms × 2^k up to ~14 minutes.
 _JOB_LATENCY_BUCKETS_MS = [100.0 * (2**k) for k in range(14)]
@@ -57,6 +60,11 @@ class _Registry:
         #: scrape-config tricks; empty until set_identity() — tests and
         #: library embedders see unchanged output
         self._identity: Tuple[Tuple[str, str], ...] = ()  # guarded-by: self._lock
+        #: histograms observed without the lock, by a caller that may run
+        #: while this thread already holds it (a gc callback fires inside
+        #: any allocation).  Fixed in size once made; reset() zeroes them
+        #: in place, since their observers keep a reference.
+        self._unlocked: Dict[Tuple[str, Tuple[Tuple[str, str], ...]], _Histogram] = {}  # guarded-by: self._lock
 
     def histogram(
         self,
@@ -72,6 +80,31 @@ class _Registry:
                 h = _Histogram(name, help_, buckets or _LATENCY_BUCKETS_MS)
                 self._histograms[key] = h
             return h
+
+    def unlocked_histogram(
+        self, name: str, labels: Dict[str, str], buckets: List[float]
+    ) -> _Histogram:
+        """A histogram whose ``observe`` its one writer calls without the
+        lock (writes must not overlap: gc callbacks never do).  Rendered
+        once it holds an observation."""
+        key = (name, tuple(sorted(labels.items())))
+        with self._lock:
+            h = self._unlocked.get(key)
+            if h is None:
+                h = self._unlocked[key] = _Histogram(name, "", buckets)
+            return h
+
+    @staticmethod
+    def _read(h: _Histogram, unlocked: bool):
+        """(buckets, counts, sum, total) of one histogram; None for an
+        unlocked one never observed.  An unlocked histogram's counts are
+        copied first and its total taken from the copy, so a collection
+        during the read cannot make its buckets and count disagree."""
+        counts = list(h.counts)
+        if not unlocked:
+            return h.buckets, counts, h.sum, h.total
+        total = sum(counts)
+        return (h.buckets, counts, h.sum, total) if total else None
 
     def inc(self, name: str, labels: Dict[str, str], value: float = 1.0) -> None:
         key = (name, tuple(sorted(labels.items())))
@@ -130,16 +163,19 @@ class _Registry:
 
         with self._lock:
             identity = self._identity
-            for (name, labels), h in sorted(self._histograms.items()):
+            views = [(k, self._read(h, False)) for k, h in self._histograms.items()]
+            views += [(k, self._read(h, True)) for k, h in self._unlocked.items()]
+            for (name, labels), view in sorted(v for v in views if v[1] is not None):
+                bounds, counts, sum_, total = view
                 cumulative = 0
-                for bound, c in zip(h.buckets, h.counts):
+                for bound, c in zip(bounds, counts):
                     cumulative += c
                     le = labels + (("le", str(bound)),)
                     lines.append(f"{name}_bucket{fmt_labels(le)} {cumulative}")
                 le = labels + (("le", "+Inf"),)
-                lines.append(f"{name}_bucket{fmt_labels(le)} {h.total}")
-                lines.append(f"{name}_sum{fmt_labels(labels)} {h.sum}")
-                lines.append(f"{name}_count{fmt_labels(labels)} {h.total}")
+                lines.append(f"{name}_bucket{fmt_labels(le)} {total}")
+                lines.append(f"{name}_sum{fmt_labels(labels)} {sum_}")
+                lines.append(f"{name}_count{fmt_labels(labels)} {total}")
             for (name, labels), v in sorted(self._counters.items()):
                 lines.append(f"{name}{fmt_labels(labels)} {v}")
             for (name, labels), v in sorted(self._gauges.items()):
@@ -156,18 +192,27 @@ class _Registry:
         key = (name, tuple(sorted((labels or {}).items())))
         with self._lock:
             h = self._histograms.get(key)
-            if h is None:
-                return None
-            cumulative = 0
-            buckets = []
-            for bound, c in zip(h.buckets, h.counts):
-                cumulative += c
-                buckets.append((str(bound), float(cumulative)))
-            buckets.append(("+Inf", float(h.total)))
-            return {"buckets": buckets, "sum": h.sum, "count": float(h.total)}
+            if h is not None:
+                view = self._read(h, False)
+            else:
+                h = self._unlocked.get(key)
+                view = self._read(h, True) if h is not None else None
+        if view is None:
+            return None
+        bounds, counts, sum_, total = view
+        cumulative = 0
+        buckets = []
+        for bound, c in zip(bounds, counts):
+            cumulative += c
+            buckets.append((str(bound), float(cumulative)))
+        buckets.append(("+Inf", float(total)))
+        return {"buckets": buckets, "sum": sum_, "count": float(total)}
 
     def reset(self) -> None:
         with self._lock:
+            for h in self._unlocked.values():
+                h.counts[:] = [0] * len(h.counts)
+                h.sum, h.total = 0.0, 0
             self._histograms.clear()
             self._counters.clear()
             self._gauges.clear()
@@ -370,15 +415,11 @@ def _well_known_reasons() -> frozenset:
 
 def update_explain_duration(seconds: float) -> None:
     """volcano_explain_latency_milliseconds: cost of the on-device
-    reason-count reduction (ops/explain.run_explain) — the explain-mode
-    overhead bench/prof_explain_overhead.py budgets against action_ms."""
+    reason-count reduction (ops/explain.run_explain), from the
+    duration of its ``vtpu/allocate.explain`` span."""
     registry.histogram(
         f"{_NAMESPACE}_explain_latency_milliseconds", {}
     ).observe(seconds * 1e3)
-    from volcano_tpu import obs
-
-    if obs.enabled():
-        obs.complete("explain", seconds, cat="explain")
 
 
 def update_unschedule_task_count(job_name: str, count: int) -> None:
@@ -917,24 +958,54 @@ def update_capture_boost(active: float) -> None:
 
 def update_kernel_duration(phase: str, seconds: float) -> None:
     """phase ∈ {pack, compile, transfer, execute} for the device session
-    kernel.  The same timing feeds the trace recorder's timeline when a
-    cycle is being recorded (volcano_tpu/trace) — one measurement, two
-    sinks."""
+    kernel, from the duration of the phase's span (volcano_tpu/trace),
+    which feeds the cycle recorder and the flight recorder itself."""
     registry.histogram(
         f"{_NAMESPACE}_tpu_kernel_latency_milliseconds", {"phase": phase}
     ).observe(seconds * 1e3)
-    from volcano_tpu import trace
 
-    rec = trace.get_recorder()
-    if rec.enabled:
-        import time
 
-        rec.complete(
-            f"kernel:{phase}", "kernel", time.perf_counter() - seconds, seconds
+# ---- where jax-allocate's APPLY sends each task ----
+# The device resolves a task in APPLY when its proposal validates, when
+# the bulk commit places it, or when the reason counts prove it fits no
+# node; every other task takes the host predicate sweep over all nodes,
+# the O(tasks × nodes) path.  These say how many fell off the device
+# path in a slow cycle and how much host work that cost.
+
+#: the APPLY paths, in the order the per-task chooser tries them
+APPLY_PATHS = ("fast_apply", "proposal", "explained", "host_sweep")
+
+
+def register_allocate_apply(paths: Dict[str, int], rejected: int) -> None:
+    """volcano_allocate_apply_tasks_total{path}: tasks jax-allocate's
+    APPLY resolved by each path, path ∈ {fast_apply, proposal,
+    explained, host_sweep}; volcano_allocate_proposals_rejected_total:
+    kernel proposals that failed host validation and fell through to
+    the explanation or the sweep."""
+    for path, n in paths.items():
+        if n:
+            registry.inc(
+                f"{_NAMESPACE}_allocate_apply_tasks_total", {"path": path}, n
+            )
+    if rejected:
+        registry.inc(f"{_NAMESPACE}_allocate_proposals_rejected_total", {}, rejected)
+
+
+def register_host_sweep(nodes: int) -> None:
+    """volcano_allocate_host_sweep_nodes_total: nodes whose predicates
+    one host sweep (actions/allocate.host_node_chooser) evaluated."""
+    registry.inc(f"{_NAMESPACE}_allocate_host_sweep_nodes_total", {}, nodes)
+
+
+def gc_pause_histograms() -> List[_Histogram]:
+    """volcano_gc_pause_seconds{generation}, generation 0, 1 and 2: the
+    histograms the gc callback (utils/gcutil.py) adds each collection's
+    pause to.  The callback may fire while this thread holds the registry
+    lock, so they are observed without it."""
+    return [
+        registry.unlocked_histogram(
+            f"{_NAMESPACE}_gc_pause_seconds", {"generation": str(g)},
+            _GC_PAUSE_BUCKETS_S,
         )
-    from volcano_tpu import obs
-
-    if obs.enabled():
-        # third sink: the flight recorder — kernel phases land in the
-        # cross-process waterfall parented to the cycle span
-        obs.complete(f"kernel:{phase}", seconds, cat="kernel")
+        for g in range(3)
+    ]

@@ -31,7 +31,8 @@ instead of showing raw misalignment.
 Zero-cost when disabled: every emission checks the module-level
 exporter first, and :func:`span` returns a shared null context manager
 — instrumented hot paths cost one attribute read with the flight
-recorder off (the ``bench/prof_trace_overhead.py`` gate).
+recorder off.  The scheduling cycle's own layers open their spans
+through ``volcano_tpu.trace.span``, which feeds this recorder too.
 """
 
 from __future__ import annotations
@@ -89,6 +90,9 @@ class _NullSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def end(self, seconds: float, exc_type=None) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -115,10 +119,13 @@ class _DroppedSpan:
         return self
 
     def __exit__(self, *exc) -> bool:
+        self.end(0.0)
+        return False
+
+    def end(self, seconds: float, exc_type=None) -> None:
         stack = _local.stack
         if stack and stack[-1][1] == self.span_id:
             stack.pop()
-        return False
 
 
 class Span:
@@ -150,6 +157,12 @@ class Span:
         return self
 
     def __exit__(self, exc_type, *exc) -> bool:
+        self.end(time.perf_counter() - self._t0, exc_type)
+        return False
+
+    def end(self, seconds: float, exc_type=None) -> None:
+        """Close the span with a duration its caller measured
+        (``trace.span`` times a region once for all its sinks)."""
         stack = _local.stack
         if stack and stack[-1][1] == self.span_id:
             stack.pop()
@@ -164,12 +177,11 @@ class Span:
             "name": self.name,
             "cat": self.cat,
             "ts": self._wall0 * 1e6,
-            "dur": (time.perf_counter() - self._t0) * 1e6,
+            "dur": seconds * 1e6,
             "tid": threading.get_ident(),
             **({"args": args} if args else {}),
             **({"_root": True} if self.rooted else {}),
         })
-        return False
 
 
 # ---- module-level surface (the exporter is installed by obs/channel) ----
@@ -255,8 +267,8 @@ def complete(name: str, seconds: float, cat: str = "span",
              trace_id: Optional[str] = None,
              args: Optional[Dict[str, Any]] = None) -> None:
     """Emit an already-timed region that ended *now* — lets call sites
-    reuse a duration they already measured for metrics (the
-    ``update_kernel_duration`` pattern: one measurement, two sinks)."""
+    reuse a duration they already measured for metrics (one
+    measurement, two sinks)."""
     exp = _exporter
     if exp is None or _local.suppress:
         return

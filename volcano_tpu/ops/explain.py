@@ -30,7 +30,6 @@ Layers on top:
 from __future__ import annotations
 
 import threading
-import time
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -130,15 +129,10 @@ def run_explain(
     conservative, never wrong).
 
     Runs wherever the kernels run (scheduler process or compute-plane
-    sidecar) and observes its own duration into the explain-overhead
-    histogram there."""
-    import jax.numpy as jnp
-
-    from volcano_tpu.metrics import metrics
-    from volcano_tpu.ops.device_stage import device_plane as _dp
-    from volcano_tpu.ops.packing import _bucket
-
+    sidecar) as a ``vtpu/allocate.explain`` span, whose duration feeds
+    the explain-overhead histogram there."""
     from volcano_tpu import trace
+    from volcano_tpu.metrics import metrics
 
     rec = trace.get_recorder()
     if rec.enabled:
@@ -147,9 +141,6 @@ def run_explain(
             tasks=snap.n_tasks, nodes=snap.n_nodes,
             rows=(len(task_rows) if task_rows is not None else snap.n_tasks),
         )
-
-    global last_run_ms
-    t0 = time.perf_counter()
 
     rows = None
     if task_rows is not None:
@@ -161,6 +152,23 @@ def run_explain(
                 np.full((snap.n_tasks, snap.n_nodes), N_EXPLAIN_REASONS,
                         dtype=np.int8) if retain_planes else None,
             )
+    global last_run_ms
+    with trace.span("vtpu/allocate.explain", "explain", label="explain") as sp:
+        result = _reduce_reasons(snap, retain_planes, rows)
+    last_run_ms = sp.seconds * 1e3
+    metrics.update_explain_duration(sp.seconds)
+    return result
+
+
+def _reduce_reasons(
+    snap: PackedSnapshot, retain_planes: bool, rows: Optional[np.ndarray]
+) -> ExplainResult:
+    import jax.numpy as jnp
+
+    from volcano_tpu.ops.device_stage import device_plane as _dp
+    from volcano_tpu.ops.packing import _bucket
+
+    if rows is not None:
         padded = np.zeros(_bucket(len(rows)), dtype=np.int64)
         padded[: len(rows)] = rows
         task_resreq = np.asarray(snap.task_resreq)[padded]
@@ -200,9 +208,6 @@ def run_explain(
                 (snap.n_tasks, snap.n_nodes), N_EXPLAIN_REASONS, dtype=np.int8
             )
             planes_np[rows] = np.asarray(reasons)[: len(rows), : snap.n_nodes]
-    elapsed = time.perf_counter() - t0
-    last_run_ms = elapsed * 1e3
-    metrics.update_explain_duration(elapsed)
     return ExplainResult(counts_np, snap.n_nodes, planes_np)
 
 

@@ -49,6 +49,7 @@ from volcano_tpu.framework import close_session, get_action, open_session
 from volcano_tpu.framework.interface import Action
 from volcano_tpu.incremental import subgraph
 from volcano_tpu.metrics import metrics
+from volcano_tpu.utils import gcutil
 from volcano_tpu.utils.logging import get_logger
 
 log = get_logger(__name__)
@@ -166,6 +167,7 @@ class Scheduler:
         self._default_conf: Optional[SchedulerConf] = None
         if micro_cycles:
             self.attach_cache_events()
+        gcutil.install_gc_hook()
 
     # ---- event wake plumbing ----
 
@@ -296,20 +298,17 @@ class Scheduler:
         self._cycle_seq += 1
         cycle_no = cid if cid >= 0 else self._cycle_seq
         trace.set_current_cycle(cycle_no)
-        # flight-recorder cycle span (volcano_tpu/obs): a process-scope
-        # span that per-pod bind/commit spans parent to, and the ambient
-        # context every VBUS request this cycle issues propagates
-        # (bus/remote.py).  Entered manually so the existing
-        # try/finally journaling structure stays untouched; with the
-        # recorder off this is the shared null span.
-        from volcano_tpu import obs
-
-        obs_span = obs.span(
-            f"cycle:{trigger if micro else 'full'}", cat="scheduler",
-            args={"cycle": cycle_no},
+        # the cycle's span, on the profiler's clock; in the flight
+        # recorder (volcano_tpu/obs) a process-scope span that per-pod
+        # bind/commit spans parent to, and the ambient context every
+        # VBUS request this cycle issues propagates (bus/remote.py).
+        # Entered manually so the try/finally journaling structure
+        # stays untouched.
+        cycle_span = trace.span(
+            "vtpu/cycle", "scheduler",
+            label=f"cycle:{trigger if micro else 'full'}", cycle=cycle_no,
         )
-        obs_span.__enter__()
-        start = time.perf_counter()
+        cycle_span.__enter__()
         ssn = None
         rec_cache = None
         shadow_outcome = None
@@ -345,15 +344,14 @@ class Scheduler:
                     )
                 else:
                     self._restricted_since_shadow += 1
-                t_open = time.perf_counter()
                 rec_cache = subgraph.RecordingCache(self.cache)
                 ssn = open_session(
                     rec_cache, conf.tiers, conf.configurations,
                     snapshot=snap, job_uids=snap.restricted_uids,
                 )
-                # the sampled shadow run between the two stamps is
+                # the sampled shadow run between snapshot and open is
                 # soundness auditing, not steady-state open cost
-                open_s += time.perf_counter() - t_open
+                open_s += ssn.open_seconds
                 if not shadow:
                     self.restricted_open_seconds += open_s
                     self.restricted_open_cycles += 1
@@ -362,25 +360,20 @@ class Scheduler:
                 self.restricted_cycles_run += 1
                 metrics.register_session_scope("restricted")
             else:
-                t_open = time.perf_counter()
                 ssn = open_session(
                     self.cache, conf.tiers, conf.configurations
                 )
-                open_s = time.perf_counter() - t_open
+                open_s = ssn.open_seconds
                 metrics.register_session_scope("full")
             self.session_open_seconds += open_s
             self.sessions_opened += 1
             for action in actions:
-                action_start = time.perf_counter()
-                with obs.span(f"action:{action.name()}", cat="action"):
+                name = action.name()
+                with trace.span(
+                    f"vtpu/action.{name}", "action", label=f"action:{name}"
+                ) as sp:
                     action.execute(ssn)
-                action_s = time.perf_counter() - action_start
-                metrics.update_action_duration(action.name(), action_s)
-                if rec.enabled:
-                    rec.complete(
-                        f"action:{action.name()}", "action",
-                        action_start, action_s,
-                    )
+                metrics.update_action_duration(name, sp.seconds)
             if shadow_outcome is not None:
                 self.shadow_checks_run += 1
                 shadow_binds, shadow_evicts = shadow_outcome
@@ -410,10 +403,11 @@ class Scheduler:
                 if ssn is not None:
                     close_session(ssn)
             finally:
-                # stamp e2e BEFORE the quiesce: the collection pause is
-                # maintenance, not scheduling latency — folding it in
-                # would spike the p99 every Nth cycle
-                elapsed = time.perf_counter() - start
+                # close the cycle BEFORE the quiesce: the collection
+                # pause is maintenance, not scheduling latency — folding
+                # it in would spike the p99 every Nth cycle
+                cycle_span.__exit__(None, None, None)
+                elapsed = cycle_span.seconds
                 # in a finally so persistently-failing cycles (BaseDaemon
                 # retries them) still thaw+collect previously frozen dead
                 # objects instead of pinning them for the failure window
@@ -421,16 +415,13 @@ class Scheduler:
                     self._cycles_since_quiesce += 1
                     if self._cycles_since_quiesce >= self.gc_quiesce_period:
                         self._cycles_since_quiesce = 0
-                        from volcano_tpu.utils.gcutil import gc_quiesce
-
-                        gc_quiesce()
+                        gcutil.gc_quiesce()
                 # journal flush sits outside the e2e latency stamp for
                 # the same reason the gc quiesce does (maintenance I/O),
                 # but in the innermost finally: a cycle that crashes in
                 # session open, an action, OR session close is exactly
                 # the one the forensics journal must not drop
                 rec.end_cycle(duration_s=elapsed)
-                obs_span.__exit__(None, None, None)
                 self.cache.in_micro_cycle = False
         metrics.update_e2e_duration(elapsed)
         counts = getattr(self.cache, "ledger_counts", None)

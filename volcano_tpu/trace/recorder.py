@@ -12,8 +12,8 @@ Zero-cost when disabled: the module-level default is a ``NullRecorder``
 whose methods are empty and whose ``enabled`` flag lets hot paths skip
 argument construction entirely (``if rec.enabled: ...``).  The enabled
 recorder buffers plain dicts in memory — no I/O inside the cycle except
-the sampled snapshot capture — so event-granularity recording stays well
-under the 5% cycle-latency budget (bench/prof_trace_overhead.py).
+the sampled snapshot capture.  The cycle's layers reach it through
+``volcano_tpu.trace.span``, which times each region once for every sink.
 
 Timestamps are ``time.perf_counter`` microseconds relative to the
 recorder's epoch, the unit Chrome's ``trace_event`` format expects
