@@ -18,6 +18,7 @@ import pytest
 
 from volcano_tpu.actions.allocate import AllocateAction
 from volcano_tpu.actions.backfill import BackfillAction
+from volcano_tpu.actions import jax_allocate
 from volcano_tpu.actions.jax_allocate import JaxAllocateAction
 from volcano_tpu.api import FitError
 from volcano_tpu.api import unschedule_info as reasons
@@ -185,15 +186,16 @@ class TestDeviceHostEquivalence:
         host = _run_capture(fresh(), [AllocateAction()], TIERS)
         dev = _run_capture(fresh(), [JaxAllocateAction(explain=True)], TIERS)
         assert host and set(host) == set(dev)
-        synthesized = 0
         for key, (host_msg, _) in host.items():
             dev_msg, dev_synth = dev[key]
             assert dev_msg == host_msg, key
-            synthesized += dev_synth
-        # tasks the ORDER replay pruned (the tiny queue goes overused
-        # mid-replay) aren't in the packed session and correctly take
-        # the host sweep; the in-session ones must have synthesized
-        assert synthesized >= 1
+            assert dev_synth, key
+        # the tasks the ORDER replay pruned (the tiny queue goes overused
+        # mid-replay) are explained by the tail pack, so nothing sweeps
+        stats = jax_allocate.last_phase_stats
+        assert stats["apply_host_sweep"] == 0
+        assert stats["explain_tail_tasks"] > 0
+        assert stats["apply_explained"] == len(host)
 
     def test_explain_off_still_records_via_host_sweep(self):
         fe_map = _run_capture(
@@ -327,6 +329,178 @@ class TestDeviceHostEquivalence:
             + reasons.NODE_TAINT_UNTOLERATED + '"} 1'
         ) in text
         assert "volcano_explain_latency_milliseconds_count" in text
+
+
+# ---- the tail: pending tasks ORDER's share cut leaves out ----
+
+
+def _preemption_basic_mini(extra_nodes=(), extra_pods=()):
+    """kube-scheduler's ``PreemptionBasic`` in miniature: 12 nodes of 4
+    cpu, each holding four Running priority-1 pods of 900m, and 12
+    pending priority-10 pods of 3000m in queue ``default``, every pod a
+    job of its own.  ORDER's every-task-places simulation finds the
+    queue over its deserved 48 cpu after 2 of them (43.2 + 2 × 3), so it
+    lists 2; APPLY, where nothing places, reaches all 12.  Extra pods
+    come after the others, each a job of its own."""
+    nodes = [build_node(f"n{i:02d}", {"cpu": "4", "memory": "32Gi"})
+             for i in range(12)]
+    nodes += list(extra_nodes)
+    pods = []
+    for i in range(12):
+        pods += [
+            build_pod("ns", f"low-{i:02d}-{k}", f"n{i:02d}",
+                      {"cpu": "900m", "memory": "500Mi"}, phase="Running",
+                      group=f"low-{i:02d}-{k}", priority=1)
+            for k in range(4)
+        ]
+    pods += [
+        build_pod("ns", f"high-{i:02d}", "", {"cpu": "3", "memory": "500Mi"},
+                  group=f"high-{i:02d}", priority=10)
+        for i in range(12)
+    ]
+    pods += list(extra_pods)
+    pgs = [build_pod_group("ns", p.metadata.name, 1, queue="default")
+           for p in pods]
+    return nodes, pods, pgs, [build_queue("default", weight=1)]
+
+
+def _tail_counter() -> float:
+    from volcano_tpu.metrics import metrics
+
+    key = ("volcano_allocate_explain_tail_tasks_total", ())
+    with metrics.registry._lock:
+        return metrics.registry._counters.get(key, 0.0)
+
+
+@pytest.fixture
+def recorder():
+    from volcano_tpu import trace
+
+    rec = trace.TraceRecorder()
+    trace.set_recorder(rec)
+    rec.begin_cycle()
+    try:
+        yield rec
+    finally:
+        trace.set_recorder(None)
+
+
+def _tail_spans(rec):
+    rec.end_cycle()
+    return [e for e in rec.last_cycle()["events"]
+            if e["name"] == "vtpu/allocate.explain_tail"]
+
+
+def _host_and_device(objects):
+    """(host fit errors, host binds, device fit errors, device binds)
+    for the same cluster under ``AllocateAction`` and jax-allocate."""
+    host_cache, dev_cache = make_cache(*objects()), make_cache(*objects())
+    host = _run_capture(host_cache, [AllocateAction()], TIERS)
+    dev = _run_capture(dev_cache, [JaxAllocateAction(explain=True)], TIERS)
+    return host, host_cache.binder.binds, dev, dev_cache.binder.binds
+
+
+class TestTailExplanation:
+    def test_share_cut_tail_explained_on_device(self, recorder):
+        """Every preemptor's FitErrors synthesized, byte-equal to the
+        host action's, and not one host sweep: ORDER's 2 rows from the
+        primary pack, the other 10 from the tail's."""
+        before = _tail_counter()
+        host, host_binds, dev, dev_binds = _host_and_device(_preemption_basic_mini)
+        stats = jax_allocate.last_phase_stats
+
+        assert host_binds == dev_binds == {}
+        assert len(host) == 12 and set(host) == set(dev)
+        for key, (host_msg, _) in host.items():
+            assert dev[key] == (host_msg, True), key
+        assert parse_fit_errors(host["ns/high-00"][0]) == (
+            12, {reasons.NODE_RESOURCE_FIT_FAILED: 12})
+        assert {k: stats[k] for k in ("apply_host_sweep", "apply_explained",
+                                      "explain_tail_tasks")} == {
+            "apply_host_sweep": 0, "apply_explained": 12, "explain_tail_tasks": 10}
+        assert stats["explain_tail_ms"] > 0
+        assert _tail_counter() - before == 10
+        (span,) = _tail_spans(recorder)
+        assert span["args"]["tasks"] == 10
+
+    def test_order_lists_every_task_builds_no_tail(self, recorder):
+        """No share cut: every pending task is an ORDER row, so the tail
+        is never built."""
+        before = _tail_counter()
+        host, _, dev, _ = _host_and_device(_mixed_reason_objects)
+        assert dev == {k: (msg, True) for k, (msg, _) in host.items()}
+        stats = jax_allocate.last_phase_stats
+        assert stats["explain_tail_tasks"] == 0 and stats["explain_tail_ms"] == 0.0
+        assert stats["apply_host_sweep"] == 0
+        assert _tail_counter() == before
+        assert _tail_spans(recorder) == []
+
+    def test_placement_ahead_closes_the_tail_gate(self, recorder):
+        """A placeable pod ahead of the stuck ones places first, so the
+        epoch has moved by the first task ORDER did not list: no tail is
+        built, the stuck tasks take the host sweep, and the binds equal
+        the host action's."""
+        def objects():
+            nodes, pods, pgs, queues = _preemption_basic_mini(
+                extra_nodes=[build_node("n-roomy", {"cpu": "4", "memory": "32Gi"})])
+            easy = build_pod("ns", "easy", "", {"cpu": "1", "memory": "500Mi"},
+                             group="easy", priority=10)
+            return nodes, [easy] + pods, [build_pod_group("ns", "easy", 1)] + pgs, queues
+
+        host, host_binds, dev, dev_binds = _host_and_device(objects)
+        stats = jax_allocate.last_phase_stats
+        assert dev_binds == host_binds and "ns/easy" in dev_binds
+        assert set(host) == set(dev)
+        for key, (host_msg, _) in host.items():
+            assert dev[key][0] == host_msg, key
+        assert stats["explain_tail_tasks"] == 0
+        assert stats["apply_host_sweep"] > 0
+        assert _tail_spans(recorder) == []
+
+    def test_tail_task_that_fits_is_swept_and_placed(self):
+        """A task beyond ORDER's cut that fits a node: the tail's counts
+        do not prove it infeasible, so it takes the host sweep and places
+        as under the host action; the tasks after it find the gate
+        closed and are swept too."""
+        def objects():
+            roomy = build_node("n-roomy", {"cpu": "4", "memory": "32Gi"})
+            fillers = [
+                build_pod("ns", f"low-roomy-{k}", "n-roomy",
+                          {"cpu": "900m", "memory": "500Mi"}, phase="Running",
+                          group=f"low-roomy-{k}", priority=1)
+                for k in range(3)
+            ]
+            small = build_pod("ns", "small", "", {"cpu": "500m", "memory": "500Mi"},
+                              group="small", priority=10)
+            return _preemption_basic_mini(extra_nodes=[roomy],
+                                          extra_pods=fillers + [small])
+
+        host, host_binds, dev, dev_binds = _host_and_device(objects)
+        stats = jax_allocate.last_phase_stats
+        assert dev_binds == host_binds == {"ns/small": "n-roomy"}
+        assert set(host) == set(dev)
+        for key, (host_msg, _) in host.items():
+            assert dev[key][0] == host_msg, key
+        assert stats["explain_tail_tasks"] == 10  # 9 preemptors and ``small``
+        assert stats["apply_host_sweep"] >= 1
+        assert stats["apply_explained"] + stats["apply_host_sweep"] == 13
+
+    def test_plane_retention_attributes_tail_nodes(self):
+        """``explain_planes`` with a tail: the /explain surface names
+        every node for the tail's tasks as for ORDER's."""
+        from volcano_tpu.ops.explain import last_explain
+
+        run_actions(
+            make_cache(*_preemption_basic_mini()),
+            [JaxAllocateAction(explain=True, explain_planes=True)],
+            TIERS,
+        )
+        assert jax_allocate.last_phase_stats["explain_tail_tasks"] == 10
+        info = last_explain()
+        assert info is not None and len(info["tasks"]) == 12
+        every_node = {f"n{i:02d}": reasons.NODE_RESOURCE_FIT_FAILED for i in range(12)}
+        for detail in info["tasks"].values():
+            assert detail["nodes"] == every_node
 
 
 # ---- no-victim preempt/reclaim explanations ----

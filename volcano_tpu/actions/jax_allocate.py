@@ -22,7 +22,9 @@ actions/allocate.py (drive_allocate_loop):
    proposed node after an O(1) host validation (plugin predicates + fit on
    that node only); tasks whose proposal fails validation — and tasks the
    kernel cannot score faithfully (preferred-affinity terms) — fall back
-   to the host scoring path for that task alone.
+   to the host scoring path for that task alone.  Unplaced tasks the
+   device proves fit no node skip that path (_ExplainContext), ORDER's
+   rows and the rows it left out alike.
 
 Bindings equivalence: with deterministic tie-break, phase 2's argmax equals
 the host path's SelectBestNode per task, so bindings are identical whenever
@@ -39,6 +41,7 @@ from typing import Dict, List, Optional, Tuple
 from volcano_tpu import trace
 from volcano_tpu.actions.allocate import (
     drive_allocate_loop,
+    eligible_jobs,
     gang_end_job,
     host_node_chooser,
     make_place_task,
@@ -73,6 +76,15 @@ class _ExplainContext:
         placement the host's pop-time first-failure can shift to a
         resource-fit failure the snapshot-time counts predate, so those
         tasks take the host sweep instead.
+
+    APPLY reaches pending tasks ORDER never listed when ORDER's
+    every-task-places simulation used up a queue's share that the real
+    loop never does.  The first such task reached with the gate still
+    open builds the *tail*: every other pending task of the eligible
+    jobs, packed on its own against the same nodes and reduced on the
+    device in one pass, so the rest of a saturated cycle needs no host
+    sweep either.  The tail has no proposals and never meets the
+    assignment kernel, the pack cache or the device stager.
     """
 
     def __init__(self, ssn: Session, snap, counts, ordered, nodes,
@@ -86,15 +98,67 @@ class _ExplainContext:
         self.snap = snap
         self.result = ExplainResult(counts, snap.n_nodes, planes)
         self.index = {t.uid: i for i, t in enumerate(ordered)}
+        self.nodes = nodes
         self.node_names = [n.name for n in nodes]
         self.enabled = session_explain_compatible(ssn)
+        #: planes given → the tail retains its own too
+        self.retain_planes = planes is not None
         #: node-state epoch at pack time — any later mutation (even of a
         #: node some earlier action already touched) advances it, so the
         #: gate below cannot be fooled by repeat mutations the
         #: touched-SET would deduplicate away
         self._epoch0 = ssn.node_state_epoch
+        #: (snapshot, result, uid → row) of the tail, once built
+        self._tail = None
+        #: tasks the tail packed, and the ms its pack and reduction took
+        self.tail_tasks = 0
+        self.tail_ms = 0.0
         #: task uid → reason histogram, for the cycle summary
         self.explained: Dict[str, Dict[str, int]] = {}
+
+    def _row(self, uid: str):
+        """(snapshot, result, row) that holds ``uid``, building the tail
+        on the first task ORDER did not list; None if neither does."""
+        i = self.index.get(uid)
+        if i is not None:
+            return self.snap, self.result, i
+        if self._tail is None:
+            self._tail = self._explain_tail()
+        snap, result, index = self._tail
+        i = index.get(uid)
+        return None if i is None else (snap, result, i)
+
+    def _explain_tail(self):
+        """Pack and reduce every pending task of the eligible jobs that
+        is not an ordered row (the tasks ``drive_allocate_loop`` can
+        still reach), with the primary pack's nodes and pod-count rule
+        and registries of its own."""
+        from volcano_tpu.ops.explain import run_explain
+        from volcano_tpu.ops.packing import pack_session
+
+        with trace.span("vtpu/allocate.explain_tail", "explain") as sp:
+            tasks, jobs = [], []
+            for job in eligible_jobs(self.ssn):
+                pending = sorted(
+                    (t for t in job.task_status_index.get(TaskStatus.Pending, {}).values()
+                     if t.uid not in self.index and not t.resreq.is_empty()),
+                    key=lambda t: t.uid,
+                )
+                if pending:
+                    tasks.extend(pending)
+                    jobs.append(job)
+            snap = result = None
+            if tasks:
+                snap = pack_session(
+                    tasks, jobs, self.nodes,
+                    enforce_pod_count="predicates" in self.ssn.predicate_fns,
+                )
+                result = run_explain(snap, retain_planes=self.retain_planes)
+            sp.set(tasks=len(tasks))
+        self.tail_tasks = len(tasks)
+        self.tail_ms = sp.seconds * 1e3
+        metrics.register_explain_tail(len(tasks))
+        return snap, result, {t.uid: i for i, t in enumerate(tasks)}
 
     def try_explain(self, task: TaskInfo):
         """FitErrors for ``task`` when the device counts prove it
@@ -104,18 +168,27 @@ class _ExplainContext:
 
         if not self.enabled or self.ssn.node_state_epoch != self._epoch0:
             return None
-        i = self.index.get(task.uid)
-        if i is None or i >= len(self.result.counts):
+        found = self._row(task.uid)
+        if found is None:
             return None
-        if not task_exactly_encoded(self.snap, i):
+        snap, result, i = found
+        if i >= len(result.counts):
             return None
-        if not self.result.all_infeasible(i):
+        if not task_exactly_encoded(snap, i):
             return None
-        hist = self.result.histogram(i)
+        if not result.all_infeasible(i):
+            return None
+        hist = result.histogram(i)
         self.explained[task.uid] = hist
         for reason in hist:
             metrics.register_unschedulable_reason(reason)
-        return self.result.fit_errors(i)
+        return result.fit_errors(i)
+
+    def node_reasons(self, uid: str) -> Dict[str, str]:
+        """node name → failing reason for an explained task, from the
+        planes of whichever pack holds it (empty without planes)."""
+        _snap, result, i = self._row(uid)
+        return result.node_reasons(i, self.node_names)
 
     def summary(self) -> Dict[str, int]:
         """Aggregate reason → node-count histogram over the explained
@@ -429,7 +502,8 @@ class JaxAllocateAction(Action):
         """APPLY, counting the path that resolves each task
         (``metrics.APPLY_PATHS``) into the Prometheus counters and
         ``last_phase_stats`` (``apply_<path>``, ``apply_rejected``,
-        ``host_sweep_nodes``, ``host_sweep_ms``)."""
+        ``host_sweep_nodes``, ``host_sweep_ms``, and the tail
+        explanation's ``explain_tail_tasks`` and ``explain_tail_ms``)."""
         paths = dict.fromkeys(metrics.APPLY_PATHS, 0)
         tally = {"apply_rejected": 0, "host_sweep_nodes": 0, "host_sweep_ms": 0.0}
         try:
@@ -438,6 +512,8 @@ class JaxAllocateAction(Action):
             metrics.register_allocate_apply(paths, tally["apply_rejected"])
             last_phase_stats.update({f"apply_{p}": n for p, n in paths.items()})
             last_phase_stats.update(tally)
+            last_phase_stats["explain_tail_tasks"] = explain_ctx.tail_tasks if explain_ctx else 0
+            last_phase_stats["explain_tail_ms"] = explain_ctx.tail_ms if explain_ctx else 0.0
 
     def _apply_paths(self, ssn, ordered, proposals, snap, explain_ctx,
                      paths, tally) -> None:
@@ -509,11 +585,8 @@ class JaxAllocateAction(Action):
         from volcano_tpu import trace as _trace
 
         detail = {}
-        if ctx.result.reasons is not None:
-            detail = {
-                uid: ctx.result.node_reasons(ctx.index[uid], ctx.node_names)
-                for uid in ctx.explained
-            }
+        if ctx.retain_planes:
+            detail = {uid: ctx.node_reasons(uid) for uid in ctx.explained}
         set_last_explain(
             {
                 "cycle": _trace.current_cycle(),

@@ -991,6 +991,14 @@ def register_allocate_apply(paths: Dict[str, int], rejected: int) -> None:
         registry.inc(f"{_NAMESPACE}_allocate_proposals_rejected_total", {}, rejected)
 
 
+def register_explain_tail(tasks: int) -> None:
+    """volcano_allocate_explain_tail_tasks_total: pending tasks ORDER did
+    not list that APPLY packed and explained on the device in one pass
+    (the tail explanation of actions/jax_allocate) instead of sweeping
+    each over every node on the host."""
+    registry.inc(f"{_NAMESPACE}_allocate_explain_tail_tasks_total", {}, tasks)
+
+
 def register_host_sweep(nodes: int) -> None:
     """volcano_allocate_host_sweep_nodes_total: nodes whose predicates
     one host sweep (actions/allocate.host_node_chooser) evaluated."""

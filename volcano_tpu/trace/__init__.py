@@ -120,6 +120,7 @@ SPAN_NAMES = (
     "vtpu/allocate.explain",  # the reason-count reduction (ops/explain)
     "vtpu/allocate.apply",  # jax-allocate APPLY
     "vtpu/allocate.fast_apply",  # the bulk commit inside APPLY
+    "vtpu/allocate.explain_tail",  # APPLY's pack and reduction of the tasks ORDER left out
     "vtpu/allocate.host_sweep",  # one host predicate sweep, one task
     "vtpu/preempt.pack",
     "vtpu/preempt.device",  # jax-preempt's kernel outcome
