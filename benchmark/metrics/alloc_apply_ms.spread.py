@@ -1,0 +1,9 @@
+"""Host time of jax-allocate's APPLY (``JaxAllocateAction._apply``: here
+the bulk commit of the 2,000 placements, with the session's spread
+counts kept in step) per cycle, from the harness's annotation on the
+profiler's clock, in ms."""
+from harness.view import span_ms_per_cycle
+
+
+def read(run):
+    return span_ms_per_cycle(run, "allocate:apply")

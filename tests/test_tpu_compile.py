@@ -63,15 +63,16 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile_allocate(one_chip, T: int, C: int, NS: int, R: int = 2, J: int = 8192):
+def _compile_allocate(one_chip, T: int, C: int, NS: int, R: int = 2, J: int = 8192,
+                      K: int = 0):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     jax.clear_caches()  # the scoped-VMEM limit is read at trace time
     return schedule_session_pallas.lower(
-        spec((T, R + 2), jnp.float32),
+        spec((T, R + 2 + 2 * K), jnp.float32),
         spec((C, NS, LANES), jnp.uint8),
-        spec((3 * R + 2, NS, LANES), jnp.float32),
+        spec((3 * R + 2 + 3 * K, NS, LANES), jnp.float32),
         spec((1, R), jnp.float32),
         spec((T,), jnp.int32),
         spec((J,), jnp.int32),
@@ -97,6 +98,20 @@ def test_allocate_kernel_at_admission_edge(one_chip, C, R):
     while kernel_vmem_bytes(C, (NS + 1) * LANES, R) <= VMEM_BUDGET:
         NS += 1
     compiled = _compile_allocate(one_chip, 50176, C, NS, R)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("K", [1, 8])
+def test_allocate_kernel_with_spread_classes(one_chip, K):
+    """Hard topology spread: K classes' planes, count scratch and
+    per-step minimum, at the 2,000 x 5,000 shape of scheduler_perf's
+    TopologySpreading and at the widest node axis admitted for C=1."""
+    compiled = _compile_allocate(one_chip, 2048, 1, 40, K=K)
+    assert "tpu_custom_call" in compiled.as_text()
+    NS = 1
+    while kernel_vmem_bytes(1, (NS + 1) * LANES, 2, K=K) <= VMEM_BUDGET:
+        NS += 1
+    compiled = _compile_allocate(one_chip, 2048, 1, NS, K=K)
     assert "tpu_custom_call" in compiled.as_text()
 
 

@@ -371,6 +371,11 @@ def try_fast_apply(
             tn[t.uid] = t.node_name
         # anti-affinity sets: gate guarantees no pod (anti-)affinity terms
         # (needs_host_validation would be set), so nothing to maintain.
+        # Topology spread counts, once a check built them, move as the
+        # per-task allocate events would have moved them.
+        if pl.spread.active:
+            for t in bulk:
+                pl.spread.update(t, t.node_name)
 
     import time
 

@@ -38,6 +38,8 @@ from __future__ import annotations
 import os
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+
 from volcano_tpu import trace
 from volcano_tpu.actions.allocate import (
     drive_allocate_loop,
@@ -249,6 +251,18 @@ def compute_task_order_replay(ssn: Session) -> List[TaskInfo]:
     return order
 
 
+def _spread_cut(snap) -> int:
+    """Ordered rows whose proposals stand: all of them, or those before
+    the first row with a hard topology spread constraint the pack could
+    not encode.  The kernel placed that row with no mask, and the counts
+    it moved there steer every later proposal, so from it on each task
+    takes the host chooser — the host action's own choices."""
+    host = snap.task_spread_host
+    if host is None or not host[: snap.n_tasks].any():
+        return snap.n_tasks
+    return int(np.argmax(host[: snap.n_tasks]))
+
+
 #: phase timings of the most recent execute() — read by bench.py right
 #: after the call, same single-threaded discipline as dispatch state
 last_phase_stats: Dict[str, float] = {}
@@ -343,6 +357,13 @@ class JaxAllocateAction(Action):
                 )
         last_phase_stats["pack_ms"] = sp.seconds * 1e3
         metrics.update_kernel_duration("pack", sp.seconds)
+        if snap.spread_stats is not None:
+            # spread_rows_device / _host, spread_classes, spread_pack_ms
+            last_phase_stats.update(snap.spread_stats)
+            metrics.register_spread_rows(
+                snap.spread_stats["spread_rows_device"],
+                snap.spread_stats["spread_rows_host"],
+            )
 
         if snap.cache_key is not None and owns_device():
             # attach the device-resident mirror: only dirty rows travel
@@ -386,6 +407,12 @@ class JaxAllocateAction(Action):
                           error=str(e))
             return {}, snap
         metrics.update_kernel_duration("execute", sp.seconds)
+        # the shape the kernel ran at, for work counts per dispatch
+        last_phase_stats.update(
+            kernel_tasks=snap.n_tasks,
+            kernel_nodes=snap.n_nodes,
+            kernel_resources=int(snap.task_resreq.shape[1]),
+        )
 
         rec = ssn._trace
         if rec.enabled and rec.should_capture():
@@ -407,7 +434,7 @@ class JaxAllocateAction(Action):
             )
 
         proposals = {}
-        for i, task in enumerate(ordered_tasks):
+        for i, task in enumerate(ordered_tasks[: _spread_cut(snap)]):
             if assignment[i] >= 0 and not snap.task_has_preferences[i]:
                 proposals[task.uid] = nodes[assignment[i]].name
         return proposals, snap

@@ -19,6 +19,11 @@ NODE_PORT_CONFLICT = "node(s) didn't have free ports for the requested pod ports
 NODE_UNSCHEDULABLE = "node(s) were unschedulable"
 NODE_NOT_READY = "node(s) were not ready"
 POD_AFFINITY_MISMATCH = "node(s) didn't match pod affinity/anti-affinity"
+# PodTopologySpread's two filter reasons (k8s v1.30 podtopologyspread)
+SPREAD_CONSTRAINTS_MISMATCH = "node(s) didn't match pod topology spread constraints"
+SPREAD_LABEL_MISSING = (
+    "node(s) didn't match pod topology spread constraints (missing required label)"
+)
 
 
 class FitError(Exception):

@@ -1,9 +1,10 @@
 """Core Kubernetes-style objects used by the framework.
 
 Only the fields the reference framework actually reads/writes are modeled
-(e.g. Pod: requests/ports/selector/affinity/tolerations/priority; Node:
-allocatable/capacity/taints/labels/conditions).  Affinity is kept as the
-k8s dict schema and interpreted by the predicate/score layers.
+(e.g. Pod: requests/ports/selector/affinity/tolerations/priority/topology
+spread constraints; Node: allocatable/capacity/taints/labels/conditions).
+Affinity and label selectors are kept as the k8s dict schema and
+interpreted by the predicate/score layers.
 """
 
 from __future__ import annotations
@@ -144,6 +145,24 @@ class Volume:
 
 
 @dataclass
+class TopologySpreadConstraint:
+    """k8s ``TopologySpreadConstraint``.  ``label_selector`` keeps the
+    metav1.LabelSelector dict schema (``matchLabels`` /
+    ``matchExpressions``); the two inclusion policies are ``Honor`` or
+    ``Ignore``, and empty means the k8s default (node affinity
+    ``Honor``, taints ``Ignore``)."""
+
+    max_skew: int = 1
+    topology_key: str = ""
+    when_unsatisfiable: str = "DoNotSchedule"  # DoNotSchedule | ScheduleAnyway
+    label_selector: Optional[Dict[str, object]] = None
+    min_domains: Optional[int] = None
+    node_affinity_policy: str = ""
+    node_taints_policy: str = ""
+    match_label_keys: List[str] = field(default_factory=list)
+
+
+@dataclass
 class PodSpec:
     containers: List[Container] = field(default_factory=list)
     init_containers: List[Container] = field(default_factory=list)
@@ -160,6 +179,9 @@ class PodSpec:
     subdomain: str = ""
     service_account_name: str = ""
     volumes: List[Volume] = field(default_factory=list)
+    topology_spread_constraints: List[TopologySpreadConstraint] = field(
+        default_factory=list
+    )
 
 
 @dataclass

@@ -96,6 +96,7 @@ def _task_pack_relevant_changed(old_pod: core.Pod, new_pod: core.Pod) -> bool:
                 so.node_selector != sn.node_selector
                 or so.affinity != sn.affinity
                 or so.tolerations != sn.tolerations
+                or so.topology_spread_constraints != sn.topology_spread_constraints
             ):
                 return True
         mo, mn = old_pod.metadata, new_pod.metadata

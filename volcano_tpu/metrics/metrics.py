@@ -999,6 +999,20 @@ def register_explain_tail(tasks: int) -> None:
     registry.inc(f"{_NAMESPACE}_allocate_explain_tail_tasks_total", {}, tasks)
 
 
+def register_spread_rows(device: int, host: int) -> None:
+    """volcano_allocate_spread_rows_total{path}: pending tasks with
+    topology spread constraints that jax-allocate packed, by where their
+    constraints are decided, path ∈ {device, host}: ``device`` when the
+    kernel encodes every hard constraint of the row exactly, ``host``
+    when the row keeps host validation or host scoring (ScheduleAnyway,
+    minDomains, matchLabelKeys, an empty selector, class overflow)."""
+    for path, n in (("device", device), ("host", host)):
+        if n:
+            registry.inc(
+                f"{_NAMESPACE}_allocate_spread_rows_total", {"path": path}, n
+            )
+
+
 def register_host_sweep(nodes: int) -> None:
     """volcano_allocate_host_sweep_nodes_total: nodes whose predicates
     one host sweep (actions/allocate.host_node_chooser) evaluated."""

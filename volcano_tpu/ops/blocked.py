@@ -41,6 +41,7 @@ from volcano_tpu.ops.kernels import (
     f32_lr_exact,
     MAX_PRIORITY,
     node_scores,
+    run_packed,
     ScoreWeights,
     step_delta_ext,
 )
@@ -415,7 +416,16 @@ def run_packed_blocked(
     discard_unstable: bool = False,
 ) -> np.ndarray:
     """Host wrapper with the adaptive gang fixpoint (same protocol as
-    kernels.run_packed) on the blocked pass."""
+    kernels.run_packed) on the blocked pass.
+
+    Sessions with hard topology spread run the plain scan instead: the
+    blocked pass keeps each block's top-K candidates valid on the
+    premise that a placement changes one node, and a placement there
+    moves the counts of a whole domain (the dispatcher never picks this
+    executor for them; this covers the Pallas rung's fallback)."""
+    if snap.has_spread:
+        return run_packed(snap, weights=weights, gang_rounds=gang_rounds,
+                          discard_unstable=discard_unstable)
     if not f32_lr_exact(snap):
         weights = weights._replace(lr_int_exact=True)
 

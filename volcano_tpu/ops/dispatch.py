@@ -115,10 +115,16 @@ def select_executor(
     coping the reference does with 16-way goroutines + subsampling,
     scheduler_helper.go:42-117): sessions too big for one chip's VMEM —
     or beyond the single-chip node-width threshold — shard the node axis
-    over the mesh when ≥2 devices exist; single-chip otherwise."""
+    over the mesh when ≥2 devices exist; single-chip otherwise.
+
+    Hard topology spread is encoded by the Pallas kernel and the plain
+    scan only: such a session takes ``pallas`` where it may, else
+    ``xla-scan`` — never ``native`` or the blocked formulations, whose
+    candidate tracking assumes a placement changes one node."""
     area = max(snap.n_tasks, 1) * max(snap.n_nodes, 1)
+    spread = snap.has_spread
     if area < _SMALL_AREA:
-        if weights == DEFAULT_WEIGHTS:
+        if weights == DEFAULT_WEIGHTS and not spread:
             from volcano_tpu import native
 
             if native.load() is not None:
@@ -129,6 +135,8 @@ def select_executor(
 
         if pallas_vmem_bytes(snap) <= VMEM_BUDGET:
             return "pallas"
+    if spread:
+        return "xla-scan"
     if _device_count() >= 2 and snap.n_nodes >= _SHARD_MIN_NODES:
         return "sharded"
     return "blocked"

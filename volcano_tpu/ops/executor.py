@@ -199,6 +199,8 @@ def execute_allocate(
         remote is not None
         and weights == DEFAULT_WEIGHTS
         and gang_rounds == 3
+        # nor does it carry the topology spread planes
+        and not snap.has_spread
         and remote.usable()
     ):
         try:

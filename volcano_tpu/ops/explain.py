@@ -24,7 +24,8 @@ Layers on top:
     endpoint and the trace journal's per-cycle reason summary.
   * full per-pair reason planes (node-level attribution, [T, N]) are
     retained only when asked (``retain_planes``) — the hot path ships
-    one [T, P] matrix back, P = 5.
+    one [T, P] matrix back, P = 5, or 7 in a session with hard topology
+    spread constraints (their two reasons).
 """
 
 from __future__ import annotations
@@ -41,6 +42,8 @@ from volcano_tpu.api.unschedule_info import (
     NODE_SELECTOR_MISMATCH,
     NODE_TAINT_UNTOLERATED,
     NODE_UNSCHEDULABLE,
+    SPREAD_CONSTRAINTS_MISMATCH,
+    SPREAD_LABEL_MISSING,
 )
 from volcano_tpu.ops.kernels import explain_counts, N_EXPLAIN_REASONS
 from volcano_tpu.ops.packing import PackedSnapshot
@@ -53,6 +56,8 @@ EXPLAIN_REASONS = (
     NODE_UNSCHEDULABLE,
     NODE_SELECTOR_MISMATCH,
     NODE_TAINT_UNTOLERATED,
+    SPREAD_LABEL_MISSING,
+    SPREAD_CONSTRAINTS_MISMATCH,
 )
 
 assert len(EXPLAIN_REASONS) == N_EXPLAIN_REASONS
@@ -166,8 +171,9 @@ def _reduce_reasons(
     import jax.numpy as jnp
 
     from volcano_tpu.ops.device_stage import device_plane as _dp
-    from volcano_tpu.ops.packing import _bucket
+    from volcano_tpu.ops.packing import _bucket, spread_node_planes, spread_task_columns
 
+    padded = None
     if rows is not None:
         padded = np.zeros(_bucket(len(rows)), dtype=np.int64)
         padded[: len(rows)] = rows
@@ -178,6 +184,13 @@ def _reduce_reasons(
         task_resreq = _dp(snap, "task_resreq")
         task_sel = _dp(snap, "task_sel_bits")
         task_tol = _dp(snap, "task_tol_bits")
+    spread = None
+    if snap.has_spread:
+        thr, _inc = spread_task_columns(snap, snap.task_resreq.shape[0])
+        spread = tuple(jnp.asarray(a) for a in (
+            thr if padded is None else thr[padded],
+            *spread_node_planes(snap, snap.node_idle.shape[0]),
+        ))
 
     reasons, counts = explain_counts(
         jnp.asarray(task_resreq),
@@ -191,6 +204,7 @@ def _reduce_reasons(
         jnp.asarray(_dp(snap, "node_max_tasks")),
         jnp.asarray(_dp(snap, "tolerance")),
         jnp.int32(snap.n_nodes),
+        spread=spread,
     )
     if rows is None:
         counts_np = np.asarray(counts)[: snap.n_tasks]
@@ -200,8 +214,9 @@ def _reduce_reasons(
             else None
         )
     else:
-        counts_np = np.zeros((snap.n_tasks, N_EXPLAIN_REASONS), dtype=np.int32)
-        counts_np[rows] = np.asarray(counts)[: len(rows)]
+        counts = np.asarray(counts)
+        counts_np = np.zeros((snap.n_tasks, counts.shape[1]), dtype=np.int32)
+        counts_np[rows] = counts[: len(rows)]
         planes_np = None
         if retain_planes:
             planes_np = np.full(
@@ -214,9 +229,13 @@ def _reduce_reasons(
 def task_exactly_encoded(snap: PackedSnapshot, i: int) -> bool:
     """May device counts for row ``i`` be trusted as the host truth?
     Requires the row's predicates to be bitset-exact (no rich affinity),
-    no registry overflow (every row suspect then), and MiB-exact memory
-    lanes (the fit plane rounds otherwise)."""
+    its hard topology spread constraints encoded, no registry overflow
+    (every row suspect then), and MiB-exact memory lanes (the fit plane
+    rounds otherwise)."""
     if getattr(snap, "registry_overflow", False) or not snap.memory_exact:
+        return False
+    spread_host = getattr(snap, "task_spread_host", None)
+    if spread_host is not None and spread_host[i]:
         return False
     needs_host = getattr(snap, "task_needs_host", None)
     if needs_host is None:

@@ -134,11 +134,13 @@ def predicate_mask(
 #: reason-plane order = the HOST first-failure precedence: the resource
 #: fit check prepended by actions/allocate.make_predicate_fn, then the
 #: predicates plugin's own order (pod count, unschedulable, selector,
-#: taints — plugins/predicates.py:48-95).  Within a session every node
-#: passed ready() at snapshot time (cache.snapshot skips unready nodes),
-#: so the packed ¬node_ok is exactly "unschedulable".
-N_EXPLAIN_REASONS = 5
-R_FIT, R_ROOM, R_UNSCHED, R_SEL, R_TOL = range(N_EXPLAIN_REASONS)
+#: taints, topology spread — plugins/predicates.py).  Within a session
+#: every node passed ready() at snapshot time (cache.snapshot skips
+#: unready nodes), so the packed ¬node_ok is exactly "unschedulable".
+#: Spread fails either on a node without the constraint's key or on
+#: the skew, in the order of the pod's constraints.
+N_EXPLAIN_REASONS = 7
+R_FIT, R_ROOM, R_UNSCHED, R_SEL, R_TOL, R_SPREAD_LABEL, R_SPREAD = range(N_EXPLAIN_REASONS)
 
 
 @jax.jit
@@ -154,8 +156,10 @@ def explain_counts(
     node_max_tasks: jnp.ndarray,  # [N] i32
     tolerance: jnp.ndarray,  # [R]
     n_nodes: jnp.ndarray,  # i32 scalar — valid node rows (rest padding)
+    spread=None,  # (thr [T, K], dom, elig, count [K, N]) or None
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(reason[T, N] i8, counts[T, P] i32).
+    """(reason[T, N] i8, counts[T, P] i32), P = 5 without ``spread``
+    and 7 with it (the two spread reasons).
 
     ``reason[t, n]`` is the index of the FIRST predicate the pair fails
     in host order, or ``N_EXPLAIN_REASONS`` when the node is feasible
@@ -184,13 +188,22 @@ def explain_counts(
             ),
         ),
     )
+    planes = R_SPREAD_LABEL
+    if spread is not None:
+        thr, dom, elig, count = spread
+        label_fail, skew_fail = spread_ok(dom, elig, count, thr)  # [T, K, N]
+        first = jnp.full(reason.shape, feasible)
+        for k in reversed(range(thr.shape[1])):
+            first = jnp.where(
+                label_fail[:, k], jnp.int8(R_SPREAD_LABEL),
+                jnp.where(skew_fail[:, k], jnp.int8(R_SPREAD), first),
+            )
+        reason = jnp.where(reason == feasible, first, reason)
+        planes = N_EXPLAIN_REASONS
     valid = jnp.arange(reason.shape[1]) < n_nodes
     reason = jnp.where(valid[None, :], reason, feasible)
     counts = jnp.stack(
-        [
-            jnp.sum(reason == jnp.int8(p), axis=1, dtype=jnp.int32)
-            for p in range(N_EXPLAIN_REASONS)
-        ],
+        [jnp.sum(reason == jnp.int8(p), axis=1, dtype=jnp.int32) for p in range(planes)],
         axis=1,
     )
     return reason, counts
@@ -305,6 +318,37 @@ class _ScanState(NamedTuple):
     # updates both (scatters are the dominant per-step cost at large N).
     used_ext: jnp.ndarray  # [N, R+1]
     job_assigned: jnp.ndarray  # [J]
+    #: [K, N] each node's domain count per spread class, or None
+    spread_count: object = None
+
+
+#: stands in for "no eligible domain" in the spread minimum: every
+#: count less it passes any maxSkew, as k8s's MaxInt32 minimum does
+SPREAD_BIG = float(2**30)
+
+
+def spread_ok(dom, elig, count, thr):
+    """Hard topology spread on every node — the kernels' form of
+    PodTopologySpread.Filter.  ``dom``, ``elig``, ``count`` are [K, N]
+    planes (ops.packing.spread_node_planes); ``thr`` is [..., K], the
+    row's maxSkew less its self-match, negative where the class does not
+    constrain it.  A node passes class k when it carries the key and its
+    domain's count less the minimum over eligible nodes is at most
+    ``thr``.  Returns ([..., K, N] per-class failures of the label,
+    of the skew), both False where the class does not constrain."""
+    mn = jnp.min(jnp.where(elig > 0.0, count, SPREAD_BIG), axis=-1, keepdims=True)
+    on = (thr >= 0.0)[..., None]
+    label_fail = on & (dom < 0.0)
+    skew_fail = on & (dom >= 0.0) & (count - mn > thr[..., None])
+    return label_fail, skew_fail
+
+
+def spread_update(dom, elig, count, inc, best, ok):
+    """Counts after a placement at node ``best``: every class the
+    placed row counts toward (``inc`` [K]) gains one in the chosen
+    node's domain, when that node is eligible for the class."""
+    add = inc * elig[:, best] * jnp.where(ok, 1.0, 0.0)
+    return count + jnp.where(dom == dom[:, best][:, None], add[:, None], 0.0)
 
 
 def step_feasible_score(
@@ -351,27 +395,40 @@ def _assign_step(
     node_max_tasks,
     state: _ScanState,
     task: Tuple,
+    spread_nodes=None,
 ):
     """One task: mask → score → argmax → tentative allocate.
 
     Mirrors the per-task body of allocate.go:177-230 with the
     resource-fit + plugin predicates folded into the mask and
-    SelectBestNode's tie-break made deterministic (first index)."""
-    resreq, sel_tol_row, job_idx, active = task
-    used_ext, job_assigned = state
+    SelectBestNode's tie-break made deterministic (first index).
+    ``spread_nodes`` = (dom, elig) [K, N] planes when the session has
+    hard spread constraints; the task then carries its (thr, inc)
+    columns and the state its running domain counts."""
+    resreq, sel_tol_row, job_idx, active = task[:4]
+    used_ext, job_assigned, spread_count = state
 
     feasible, score = step_feasible_score(
         weights, tolerance, base, node_alloc, node_max_tasks,
         used_ext, resreq, sel_tol_row, active,
     )
+    if spread_nodes is not None:
+        thr, inc = task[4:]
+        dom, elig = spread_nodes
+        label_fail, skew_fail = spread_ok(dom, elig, spread_count, thr)
+        passes = ~jnp.any(label_fail | skew_fail, axis=0)
+        feasible = feasible & passes
+        score = jnp.where(passes, score, -jnp.inf)
     best = jnp.argmax(score)  # first max index — deterministic tie-break
     ok = feasible[best]
 
     used_ext = used_ext.at[best].add(step_delta_ext(resreq, ok))
     job_assigned = job_assigned.at[job_idx].add(jnp.where(ok, 1, 0))
+    if spread_nodes is not None:
+        spread_count = spread_update(dom, elig, spread_count, inc, best, ok)
 
     chosen = jnp.where(ok, best, -1)
-    return _ScanState(used_ext, job_assigned), chosen
+    return _ScanState(used_ext, job_assigned, spread_count), chosen
 
 
 @functools.partial(jax.jit, static_argnames=("weights", "gang_rounds"))
@@ -419,7 +476,7 @@ def schedule_session(
     used_ext0 = jnp.concatenate(
         [node_used, node_task_count.astype(node_used.dtype)[:, None]], axis=1
     )
-    init = _ScanState(used_ext0, jnp.zeros_like(job_min_available))
+    init = _ScanState(used_ext0, jnp.zeros_like(job_min_available), None)
 
     def one_pass(active):
         def step(state, task):
@@ -472,12 +529,15 @@ def schedule_pass(
     job_min_available: jnp.ndarray,
     tolerance: jnp.ndarray,
     active: jnp.ndarray,
+    spread=None,  # (thr [T, K], inc [T, K], dom, elig, count0 [K, N]) or None
     weights: ScoreWeights = DEFAULT_WEIGHTS,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One greedy pass → (chosen[T], job_assigned[J]).  The host loop in
     run_packed applies the gang commit/discard between passes — typical
     sessions converge after one pass instead of paying gang_rounds fixed
-    device rounds.
+    device rounds.  ``spread`` adds the hard topology spread mask, the
+    per-class domain counts carried through the scan (ops.packing's
+    spread_task_columns and spread_node_planes).
 
     Static feasibility (labels/taints/readiness) is evaluated per distinct
     bitset signature class, not per task: the scan gathers a [N] row from
@@ -496,8 +556,15 @@ def schedule_pass(
         [node_used, node_task_count.astype(node_used.dtype)[:, None]], axis=1
     )
 
+    xs = (task_resreq, task_feas_class, task_job, active)
+    spread_nodes = count0 = None
+    if spread is not None:
+        thr, inc, dom, elig, count0 = spread
+        xs = xs + (thr, inc)
+        spread_nodes = (dom, elig)
+
     def step(state, task):
-        resreq, feas_cls, job_idx, act = task
+        resreq, feas_cls, job_idx, act = task[:4]
         return _assign_step(
             weights,
             tolerance,
@@ -505,13 +572,12 @@ def schedule_pass(
             node_alloc,
             node_max_tasks,
             state,
-            (resreq, class_feasible[feas_cls], job_idx, act),
+            (resreq, class_feasible[feas_cls], job_idx, act) + task[4:],
+            spread_nodes,
         )
 
-    init = _ScanState(used_ext0, jnp.zeros_like(job_min_available))
-    final, chosen = jax.lax.scan(
-        step, init, (task_resreq, task_feas_class, task_job, active)
-    )
+    init = _ScanState(used_ext0, jnp.zeros_like(job_min_available), count0)
+    final, chosen = jax.lax.scan(step, init, xs)
     return chosen, final.job_assigned
 
 
@@ -554,6 +620,19 @@ def _feasibility_classes(snap: PackedSnapshot):
     )
     snap._feas_classes_cache = result
     return result
+
+
+def spread_arrays(snap: PackedSnapshot, rows: int, width: int):
+    """schedule_pass's ``spread`` operand for ``rows`` task rows and
+    ``width`` node columns, or None when the session has no hard spread
+    constraint."""
+    if not snap.has_spread:
+        return None
+    from volcano_tpu.ops.packing import spread_node_planes, spread_task_columns
+
+    thr, inc = spread_task_columns(snap, rows)
+    dom, elig, count = spread_node_planes(snap, width)
+    return tuple(jnp.asarray(a) for a in (thr, inc, dom, elig, count))
 
 
 def run_packed(
@@ -608,6 +687,7 @@ def run_packed(
             _dp(snap, "tolerance"),
         )
     ]
+    spread = spread_arrays(snap, T, snap.node_idle.shape[0])
     task_job = snap.task_job
     min_avail = snap.job_min_available.astype(np.int64)
     ready_count = snap.job_ready_count.astype(np.int64)
@@ -616,7 +696,9 @@ def run_packed(
     committed = np.zeros(T, dtype=bool)
     rounds = 0
     while True:
-        chosen, job_assigned = schedule_pass(*dev, jnp.asarray(active), weights=weights)
+        chosen, job_assigned = schedule_pass(
+            *dev, jnp.asarray(active), spread=spread, weights=weights
+        )
         chosen_np = np.asarray(chosen)
         ready = np.asarray(job_assigned, dtype=np.int64) + ready_count >= min_avail
         committed = ready[task_job] & (chosen_np >= 0)

@@ -22,6 +22,11 @@ the cache's event handlers dirtied:
     a cold ``pack_session`` seeded with the same registries
     (tests/test_pack_cache.py property test).
 
+Topology spread planes (``packing.pack_spread``) are not carried: every
+pack, warm or cold, derives them afresh from where the session's pods sit,
+an O(nodes + pods) pass that no delta could shorten much, since one
+placement moves the counts of a whole domain.
+
 Wholesale invalidation (everything rebuilt, registries kept): node set
 or ready-set change (topology revision / node list mismatch), resource
 axis change, pad-bucket change, ``enforce_pod_count`` flip (plugin-set
@@ -61,6 +66,7 @@ from volcano_tpu.ops.packing import (
     MIB,
     pack_node_row,
     pack_session,
+    pack_spread,
     pack_task_bits,
     PackedSnapshot,
     resolve_exists_tolerations,
@@ -457,6 +463,7 @@ class PackCache:
         self._consumed_rev = epoch.rev
         if self.cache is not None:
             self.cache.clear_dirty_through(epoch)
+        pack_spread(snap, tasks_list, nodes)
         self.last_stats = {
             "mode": "micro",
             "repacked_tasks": T,
@@ -757,6 +764,7 @@ class PackCache:
         self._consumed_rev = epoch.rev
         if self.cache is not None:
             self.cache.clear_dirty_through(epoch)
+        pack_spread(snap, tasks_list, nodes)
         self.last_stats = {
             "mode": "warm",
             "repacked_tasks": int(repack_rows.size),

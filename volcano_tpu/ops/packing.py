@@ -21,6 +21,15 @@ goldens.  Non-MiB-aligned byte values round and are flagged):
   node_task_count[N]  i32 / node_max_tasks[N] i32
   job_min_available[J]i32 / job_ready_count[J] i32
 
+Hard topology spread (PodTopologySpread ``DoNotSchedule``) packs as K
+constraint classes — distinct (namespace, selector, topology key,
+maxSkew, node eligibility) — by :func:`pack_spread`:
+  spread_key_dom[Kk, N]  i32  domain index of each node per topology key
+  spread_node_elig[K, N] bool nodes that count toward the class's domains
+  spread_dom_count[K, D] i32  matching pods per domain at pack time
+  task_spread_on[T, K]   bool the classes each task row is constrained by
+  task_spread_self[T, K] bool the classes each task row counts toward
+
 Label/taint relational predicates become pointwise bitset ops (SURVEY §7
 "predicate expressiveness"): W words of 32 bits each; the registry assigns a
 bit per distinct (key,value) label pair / taint referenced in the session.
@@ -43,6 +52,10 @@ DEFAULT_BIT_WORDS = 2
 
 #: Memory lane quantization (bytes per MiB).
 MIB = float(1 << 20)
+
+#: Spread constraint classes one pack encodes for the kernels; a row
+#: whose constraints need more keeps host validation.
+SPREAD_MAX_CLASSES = 8
 
 
 class BitRegistry:
@@ -152,6 +165,32 @@ class PackedSnapshot:
     #: numpy planes when absent
     device_planes: Optional[Dict[str, object]] = None
 
+    # ---- hard topology spread (pack_spread; None when no row has any) ----
+    #: [Kk, N_pad] i32 — each node's domain under each topology key, -1
+    #: where the node lacks the label
+    spread_key_dom: Optional[np.ndarray] = None
+    #: [K] i32 — the topology key row of each class
+    spread_class_key: Optional[np.ndarray] = None
+    #: [K] i32 — maxSkew of each class
+    spread_max_skew: Optional[np.ndarray] = None
+    #: [K, N_pad] bool — nodes that count toward the class's domains
+    spread_node_elig: Optional[np.ndarray] = None
+    #: [K, D_pad] i32 — matching pods per domain when packed
+    spread_dom_count: Optional[np.ndarray] = None
+    #: [T_pad, K] bool — rows each class constrains
+    task_spread_on: Optional[np.ndarray] = None
+    #: [T_pad, K] bool — rows that count toward each class once placed
+    task_spread_self: Optional[np.ndarray] = None
+    #: [T_pad] bool — rows with hard constraints the pack did not
+    #: encode (host validation); recomputed by every pack, never carried
+    task_spread_host: Optional[np.ndarray] = None
+    #: what the last pack_spread counted (rows, classes, ms)
+    spread_stats: Optional[Dict[str, float]] = None
+
+    @property
+    def has_spread(self) -> bool:
+        return self.spread_max_skew is not None and self.spread_max_skew.size > 0
+
     @property
     def shape_key(self) -> Tuple[int, int, int, int, int]:
         return (
@@ -183,6 +222,14 @@ _SNAPSHOT_ARRAYS = (
     "job_min_available",
     "job_ready_count",
     "task_has_preferences",
+    "spread_key_dom",
+    "spread_class_key",
+    "spread_max_skew",
+    "spread_node_elig",
+    "spread_dom_count",
+    "task_spread_on",
+    "task_spread_self",
+    "task_spread_host",
 )
 
 #: scalar/list fields carried in the JSON meta record
@@ -368,11 +415,19 @@ def pack_task_bits(
     ) or (aff.get("podAntiAffinity") or {}).get(
         "preferredDuringSchedulingIgnoredDuringExecution"
     )
-    if node_pref or pod_pref:
-        # Preference terms contribute to host scoring (nodeorder.py);
-        # the kernel has no lanes for them — route to host path.
+    if node_pref or pod_pref or _has_soft_spread(pod):
+        # Preference terms (and ScheduleAnyway spread) are scoring the
+        # kernel has no lanes for — route to the host path.
         snap.task_has_preferences[i] = True
     return needs_host
+
+
+def _has_soft_spread(pod) -> bool:
+    if not pod.spec.topology_spread_constraints:
+        return False
+    from volcano_tpu.plugins.util import has_soft_spread
+
+    return has_soft_spread(pod)
 
 
 def task_exists_tolerations(t: TaskInfo) -> Tuple[Tuple[str, str], ...]:
@@ -485,6 +540,7 @@ def pack_session(
     enforce_pod_count: bool = True,
     label_registry: Optional[BitRegistry] = None,
     taint_registry: Optional[BitRegistry] = None,
+    spread: bool = True,
 ) -> PackedSnapshot:
     """Pack pending tasks (in processing order), their jobs and all nodes.
 
@@ -507,6 +563,9 @@ def pack_session(
     for relay overlap), so equivalence is defined against a cold pack
     seeded with the resulting registry; bindings are invariant under bit
     permutation either way.
+
+    ``spread=False`` packs for a kernel that models no topology spread
+    (see :func:`pack_spread`).
     """
     snap = PackedSnapshot()
     names, tol = _resource_axis(tasks, nodes)
@@ -588,4 +647,214 @@ def pack_session(
         snap.needs_host_validation = True
         snap.registry_overflow = True
 
+    pack_spread(snap, tasks, nodes, encode=spread)
     return snap
+
+
+def spread_row_encodable(hard) -> bool:
+    """Do the kernels encode these hard constraints exactly?  Not
+    ``minDomains`` above 1 (the minimum would depend on the domain
+    count), not ``matchLabelKeys``, not an empty or nil selector (which
+    counts nothing but still self-matches an empty one), and not a
+    maxSkew below 1 (which k8s validation refuses)."""
+    for c in hard:
+        if c.max_skew < 1:
+            return False
+        if c.min_domains is not None and c.min_domains > 1:
+            return False
+        if c.match_label_keys:
+            return False
+        sel = c.label_selector
+        if not sel or not (sel.get("matchLabels") or sel.get("matchExpressions")):
+            return False
+    return True
+
+
+def pack_spread(snap: "PackedSnapshot", tasks, nodes, encode: bool = True) -> None:
+    """Encode the tasks' hard topology spread constraints into ``snap``
+    (the planes in the module docstring), from the pods the session's
+    nodes hold now.  Runs after every pack, cold or warm: the planes
+    depend on where pods sit, so none is carried between packs.
+
+    A row the kernels cannot encode exactly — ``spread_row_encodable``
+    refuses it, its classes overflow :data:`SPREAD_MAX_CLASSES`, or its
+    constraints map to classes out of their order (the explanation's
+    first failure follows the pod's order) — is marked in
+    ``task_spread_host`` and sets ``needs_host_validation``;
+    ``encode=False`` (the preempt and reclaim packs, whose kernels model
+    no spread) marks every row with a hard constraint so."""
+    import contextlib
+    import time
+
+    from volcano_tpu import trace
+    from volcano_tpu.plugins import util as putil
+
+    t0 = time.perf_counter()
+    T_pad = snap.task_resreq.shape[0]
+    N_pad = snap.node_idle.shape[0]
+    host = np.zeros(T_pad, dtype=bool)
+    class_index: Dict[Tuple, int] = {}
+    reps: List[Tuple] = []  # (pod, constraint, class key) of each class
+    rows: List[Tuple[int, List[int]]] = []
+    device_rows = host_rows = 0
+    span = trace.span("vtpu/allocate.spread_pack", "kernel") if encode else contextlib.nullcontext()
+    with span:
+        for i, t in enumerate(tasks):
+            pod = t.pod
+            if pod is None or not pod.spec.topology_spread_constraints:
+                continue
+            hard = putil.hard_spread_constraints(pod)
+            ids: Optional[List[int]] = None
+            if hard and encode and spread_row_encodable(hard):
+                ids = []
+                for c in hard:
+                    key = putil.spread_class_key(pod, c)
+                    k = class_index.get(key)
+                    if k is None and len(reps) < SPREAD_MAX_CLASSES:
+                        k = class_index[key] = len(reps)
+                        reps.append((pod, c, key))
+                    if k is None or (ids and k < ids[-1]):
+                        ids = None
+                        break
+                    ids.append(k)
+            if hard and ids is None:
+                host[i] = True
+            if ids is not None:
+                rows.append((i, ids))
+            if ids is not None and not snap.task_has_preferences[i]:
+                device_rows += 1
+            else:
+                host_rows += 1
+        snap.task_spread_host = host
+        if reps:
+            _spread_planes(snap, tasks, nodes, reps, rows, N_pad, T_pad, putil)
+        else:
+            for name in ("spread_key_dom", "spread_class_key", "spread_max_skew",
+                         "spread_node_elig", "spread_dom_count", "task_spread_on",
+                         "task_spread_self"):
+                setattr(snap, name, None)
+        if host[: len(tasks)].any():
+            snap.needs_host_validation = True
+    snap.spread_stats = {
+        "spread_rows_device": device_rows,
+        "spread_rows_host": host_rows,
+        "spread_classes": len(reps),
+        "spread_pack_ms": (time.perf_counter() - t0) * 1e3,
+    }
+
+
+def _spread_planes(snap, tasks, nodes, reps, rows, N_pad, T_pad, putil) -> None:
+    """The planes of :func:`pack_spread` for its K registered classes."""
+    K = len(reps)
+    node_objs = [n.node for n in nodes]
+    keys = list(dict.fromkeys(c.topology_key for _pod, c, _key in reps))
+    key_dom = np.full((len(keys), N_pad), -1, dtype=np.int32)
+    n_values = []
+    for r, tk in enumerate(keys):
+        values = sorted({
+            obj.metadata.labels[tk] for obj in node_objs
+            if obj is not None and tk in (obj.metadata.labels or {})
+        })
+        index = {v: d for d, v in enumerate(values)}
+        n_values.append(len(values))
+        for j, obj in enumerate(node_objs):
+            v = (obj.metadata.labels or {}).get(tk) if obj is not None else None
+            if v is not None:
+                key_dom[r, j] = index[v]
+    key_row = {tk: r for r, tk in enumerate(keys)}
+    class_key = np.array([key_row[c.topology_key] for _p, c, _k in reps], dtype=np.int32)
+
+    # eligibility depends only on the class key's node-side half
+    # (every topology key, affinity and taint signatures)
+    elig = np.zeros((K, N_pad), dtype=bool)
+    by_sig: Dict[Tuple, np.ndarray] = {}
+    for k, (pod, c, key) in enumerate(reps):
+        sig = key[4:]
+        row = by_sig.get(sig)
+        if row is None:
+            row = by_sig[sig] = np.array(putil.spread_eligible(pod, c, node_objs), dtype=bool)
+        elig[k, : len(node_objs)] = row
+
+    # the classes a pod counts toward depend on its namespace and labels
+    # alone: one selector test per (namespace, selector) and label set
+    groups: Dict[Tuple, List[int]] = {}
+    for k, (pod, c, key) in enumerate(reps):
+        groups.setdefault(key[:2], []).append(k)
+    group_list = [
+        (ks, reps[ks[0]][0].metadata.namespace,
+         putil.spread_selector(reps[ks[0]][0], reps[ks[0]][1]))
+        for ks in groups.values()
+    ]
+    memo: Dict[Tuple, List[int]] = {}
+
+    def matches(pod) -> List[int]:
+        labels = pod.metadata.labels or {}
+        mkey = (pod.metadata.namespace, pod.metadata.deletion_timestamp is None,
+                tuple(labels.items()))
+        hit = memo.get(mkey)
+        if hit is None:
+            hit = memo[mkey] = [
+                k for ks, ns, sel in group_list
+                if putil.spread_counts_pod(sel, ns, pod) for k in ks
+            ]
+        return hit
+
+    dom_count = np.zeros((K, max(max(n_values), 1)), dtype=np.int32)
+    dom_of = key_dom[class_key].tolist()  # [K][N_pad]
+    elig_of = elig.tolist()
+    for j, n in enumerate(nodes):
+        for _uid, pod in putil.counted_pods(n):
+            for k in matches(pod):
+                if elig_of[k][j]:
+                    dom_count[k, dom_of[k][j]] += 1
+
+    on = np.zeros((T_pad, K), dtype=bool)
+    for i, ids in rows:
+        on[i, ids] = True
+    self_ = np.zeros((T_pad, K), dtype=bool)
+    for i, t in enumerate(tasks):
+        if t.pod is not None:
+            self_[i, matches(t.pod)] = True
+
+    snap.spread_key_dom = key_dom
+    snap.spread_class_key = class_key
+    snap.spread_max_skew = np.array([int(c.max_skew) for _p, c, _k in reps], dtype=np.int32)
+    snap.spread_node_elig = elig
+    snap.spread_dom_count = dom_count
+    snap.task_spread_on = on
+    snap.task_spread_self = self_
+
+
+def spread_node_planes(snap: "PackedSnapshot", width: int):
+    """(dom, elig, count) [K, width] f32 node planes of the kernels:
+    each node's domain under its class's key (-1 without the label),
+    whether it counts toward the class, and the matching pods of its
+    domain when packed (0 without the label)."""
+    K = snap.spread_max_skew.shape[0]
+    n = min(width, snap.spread_key_dom.shape[1])
+    dom = np.full((K, width), -1.0, dtype=np.float32)
+    elig = np.zeros((K, width), dtype=np.float32)
+    count = np.zeros((K, width), dtype=np.float32)
+    d = snap.spread_key_dom[snap.spread_class_key][:, :n]
+    dom[:, :n] = d
+    elig[:, :n] = snap.spread_node_elig[:, :n]
+    got = np.take_along_axis(snap.spread_dom_count, np.maximum(d, 0), axis=1)
+    count[:, :n] = np.where(d >= 0, got, 0)
+    return dom, elig, count
+
+
+def spread_task_columns(snap: "PackedSnapshot", rows: int):
+    """(thr, inc) [rows, K] f32 task columns of the kernels: ``thr`` is
+    the most a node's domain count may exceed the minimum by — maxSkew
+    less the row's self-match — or -1 where the class does not
+    constrain the row; ``inc`` is 1 where the row counts toward the
+    class once placed."""
+    K = snap.spread_max_skew.shape[0]
+    n = min(rows, snap.task_spread_on.shape[0])
+    thr = np.full((rows, K), -1.0, dtype=np.float32)
+    inc = np.zeros((rows, K), dtype=np.float32)
+    on = snap.task_spread_on[:n]
+    self_ = snap.task_spread_self[:n].astype(np.float32)
+    thr[:n] = np.where(on, snap.spread_max_skew[None, :] - self_, -1.0)
+    inc[:n] = self_
+    return thr, inc

@@ -26,6 +26,14 @@ is stable, so the whole session pays exactly ONE host→device→host round
 trip regardless of how many gang rounds it takes — through a
 high-latency device link each avoided round trip is worth far more than
 the kernel time itself.
+
+Hard topology spread (K constraint classes, ops/packing.pack_spread)
+rides the same layout: each task row gains K thresholds and K count
+flags, the node array gains per-class domain, eligibility and
+initial-count planes, and a VMEM scratch carries the running domain
+counts.  Each step masks the scores by every constraining class — one
+cross-node minimum per class — and a placement adds one to the chosen
+node's whole domain (kernels.spread_ok / spread_update, op for op).
 """
 
 from __future__ import annotations
@@ -47,8 +55,13 @@ from volcano_tpu.ops.kernels import (
     f32_lr_exact,
     MAX_PRIORITY,
     ScoreWeights,
+    SPREAD_BIG,
 )
-from volcano_tpu.ops.packing import PackedSnapshot
+from volcano_tpu.ops.packing import (
+    PackedSnapshot,
+    spread_node_planes,
+    spread_task_columns,
+)
 
 LANES = 128
 INT_BIG = np.int32(2**31 - 1)
@@ -121,9 +134,9 @@ def score_planes(
     return s_bp + jnp.float32(w_lr) * s_lr + jnp.float32(w_bal) * s_bal
 
 
-def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights):
+def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights, K: int = 0):
     """Kernel factory — R resource lanes, TB tasks per grid step, NS node
-    sublanes (nodes = NS*128), static plugin weights.
+    sublanes (nodes = NS*128), static plugin weights, K spread classes.
 
     Incremental repeated-row fast path: a placement at step k-1 changes
     node state at ONE node, so when task k's row (resreq lanes + class +
@@ -134,15 +147,19 @@ def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights):
     (job.go:43-60: one PodTemplate per task group), so at gang_size g,
     (g-1)/g of all steps take the fast path.  Every recomputation uses
     the same elementwise formulas, so results stay bit-identical to the
-    full per-step recompute (and to kernels.py schedule_pass)."""
+    full per-step recompute (and to kernels.py schedule_pass).  The
+    spread mask, which a placement moves across a whole domain, is kept
+    out of that plane: it is applied to it afresh every step."""
 
     TBS = TB // LANES
+    RC = R + 2 + 2 * K  # task row width
+    SP = 3 * R + 2  # first spread plane of nd
 
     def kernel(
         tol_ref,  # SMEM [1, R]
-        task_ref,  # VMEM [TB, R+2] — resreq lanes, feas class, active
+        task_ref,  # VMEM [TB, RC] — resreq lanes, feas class, active, thr[K], inc[K]
         cf_ref,  # VMEM [C, NS, 128] f32 class feasibility (incl. node_ok)
-        nd_ref,  # VMEM [3R+2, NS, 128] — base | alloc | used0 | count0, maxt
+        nd_ref,  # VMEM [3R+2+3K, NS, 128] — base | alloc | used0 | count0, maxt | dom[K] | elig[K] | scount0[K]
         maxal_ref,  # VMEM [R, NS, 128] max(alloc, 1)
         allocpos_ref,  # VMEM [R, NS, 128] f32 (alloc > 0)
         chosen_ref,  # out VMEM [1, TBS, 128] i32
@@ -150,8 +167,9 @@ def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights):
         cnt_s,  # scratch VMEM [1, NS, 128]
         chosen_s,  # scratch VMEM [TBS, 128] i32
         masked_s,  # scratch VMEM [NS, 128] f32 — masked scores, kept current
-        prev_s,  # scratch VMEM [1, R+2] f32 — previous task row
+        prev_s,  # scratch VMEM [1, RC] f32 — previous task row
         ctrl_s,  # scratch SMEM [2] i32 — have_prev, prev_best (-1 = none)
+        *spread_s,  # K > 0: scratch VMEM [K, NS, 128] f32 — domain counts
     ):
         i = pl.program_id(0)
 
@@ -159,6 +177,8 @@ def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights):
         def _():
             used_s[:] = nd_ref[2 * R : 3 * R]
             cnt_s[:] = nd_ref[3 * R : 3 * R + 1]
+            if K:
+                spread_s[0][:] = nd_ref[SP + 2 * K : SP + 3 * K]
             ctrl_s[0] = 0
             ctrl_s[1] = -1
 
@@ -168,7 +188,7 @@ def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights):
         )
         # scalar extraction one-hots over the task row (no SMEM scalar
         # loads — Mosaic would relocate the whole buffer into SMEM)
-        row_lane = jax.lax.broadcasted_iota(jnp.int32, (1, R + 2), 1)
+        row_lane = jax.lax.broadcasted_iota(jnp.int32, (1, RC), 1)
         # chosen-plane write mask coordinates
         csub = jax.lax.broadcasted_iota(jnp.int32, (TBS, LANES), 0)
         clane = jax.lax.broadcasted_iota(jnp.int32, (TBS, LANES), 1)
@@ -232,8 +252,17 @@ def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights):
                     lambda ref, p: ref[p, pl.ds(bq, 1), :]
                 )
 
-            # --- lowest-index argmax + row-sliced state update ---
+            # --- hard topology spread (kernels.spread_ok) ---
             masked = masked_s[:]
+            for c in range(K):
+                thr = col(R + 2 + c)
+                count = spread_s[0][c]
+                dom = nd_ref[SP + c]
+                mn = jnp.min(jnp.where(nd_ref[SP + K + c] > 0.0, count, SPREAD_BIG))
+                passes = (thr < 0.0) | ((dom >= 0.0) & (count - mn <= thr))
+                masked = jnp.where(passes, masked, -jnp.inf)
+
+            # --- lowest-index argmax + row-sliced state update ---
             m = jnp.max(masked)
             ok = jnp.isfinite(m)
             best = jnp.min(jnp.where(masked == m, idxp, INT_BIG))
@@ -249,6 +278,14 @@ def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights):
                 cnt_s[0, pl.ds(bq, 1), :] = cnt_s[0, pl.ds(bq, 1), :] + jnp.where(
                     selr, 1.0, 0.0
                 )
+                # kernels.spread_update: the chosen node's whole domain
+                for c in range(K):
+                    dom_b = jnp.sum(jnp.where(selr, nd_ref[SP + c, pl.ds(bq, 1), :], 0.0))
+                    elig_b = jnp.sum(jnp.where(selr, nd_ref[SP + K + c, pl.ds(bq, 1), :], 0.0))
+                    add = col(R + 2 + K + c) * elig_b
+                    spread_s[0][c] = spread_s[0][c] + jnp.where(
+                        nd_ref[SP + c] == dom_b, add, 0.0
+                    )
 
             kmask = (csub == k // LANES) & (clane == k % LANES)
             chosen_s[:] = jnp.where(
@@ -272,12 +309,15 @@ def _pass_call(
     All operands already device-resident/derived; traceable inside
     lax.while_loop (the kernel is a plain XLA custom call)."""
     T_act, RC = taskrow.shape
-    R = RC - 2
+    R = tol.shape[1]
+    K = (RC - R - 2) // 2
     C, NS, _ = cf.shape
+    ND = nd.shape[0]
+    assert ND == 3 * R + 2 + 3 * K
     TB = block_size
     assert TB % LANES == 0 and T_act % TB == 0
     TBS = TB // LANES
-    kernel = _make_kernel(R, TB, NS, weights)
+    kernel = _make_kernel(R, TB, NS, weights, K)
     G = T_act // TB
 
     full = lambda *shape: pl.BlockSpec(
@@ -288,9 +328,9 @@ def _pass_call(
         grid=(G,),
         in_specs=[
             pl.BlockSpec((1, R), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((TB, R + 2), lambda i: (i, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((TB, RC), lambda i: (i, 0), memory_space=pltpu.VMEM),
             full(C, NS, LANES),
-            full(3 * R + 2, NS, LANES),
+            full(ND, NS, LANES),
             full(R, NS, LANES),
             full(R, NS, LANES),
         ],
@@ -303,9 +343,9 @@ def _pass_call(
             pltpu.VMEM((1, NS, LANES), jnp.float32),
             pltpu.VMEM((TBS, LANES), jnp.int32),
             pltpu.VMEM((NS, LANES), jnp.float32),
-            pltpu.VMEM((1, R + 2), jnp.float32),
+            pltpu.VMEM((1, RC), jnp.float32),
             pltpu.SMEM((2,), jnp.int32),
-        ],
+        ] + ([pltpu.VMEM((K, NS, LANES), jnp.float32)] if K else []),
         compiler_params=pallas_compiler_params(),
         interpret=interpret,
     )(tol, taskrow, cf, nd, maxal, allocpos)
@@ -317,16 +357,16 @@ def _pass_call(
     static_argnames=("weights", "block_size", "interpret"),
 )
 def schedule_pass_pallas(
-    taskrow: jnp.ndarray,  # [T_act, R+2] f32 — resreq lanes, class, active
+    taskrow: jnp.ndarray,  # [T_act, R+2+2K] f32 — resreq lanes, class, active, spread
     cf_u8: jnp.ndarray,  # [C, NS, 128] u8 class feasibility (incl. node_ok)
-    nd: jnp.ndarray,  # [3R+2, NS, 128] — base | alloc | used0 | count0, maxt
+    nd: jnp.ndarray,  # [3R+2+3K, NS, 128] — base | alloc | used0 | count0, maxt | spread
     tol: jnp.ndarray,  # [1, R]
     weights: ScoreWeights = DEFAULT_WEIGHTS,
     block_size: int = 256,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """One greedy pass on TPU → chosen[T_act] (node index or -1)."""
-    R = taskrow.shape[1] - 2
+    R = tol.shape[1]
     # Device-side derivations (XLA, outside the kernel) — keeps the
     # host→device transfer to taskrow + u8 feasibility + one node array.
     cf = cf_u8.astype(jnp.float32)
@@ -343,9 +383,9 @@ def schedule_pass_pallas(
     static_argnames=("weights", "block_size", "gang_rounds", "interpret"),
 )
 def schedule_session_pallas(
-    taskrow: jnp.ndarray,  # [T_act, R+2] f32 (active column ignored)
+    taskrow: jnp.ndarray,  # [T_act, R+2+2K] f32 (active column ignored)
     cf_u8: jnp.ndarray,  # [C, NS, 128] u8
-    nd: jnp.ndarray,  # [3R+2, NS, 128]
+    nd: jnp.ndarray,  # [3R+2+3K, NS, 128]
     tol: jnp.ndarray,  # [1, R]
     task_job: jnp.ndarray,  # [T_act] i32 → job row
     job_min_avail: jnp.ndarray,  # [J_pad] i32
@@ -366,7 +406,7 @@ def schedule_session_pallas(
     ``gang_rounds`` rounds (an unsettled fixpoint ships the last round's
     commits — always individually valid placements).  One fused program
     ⇒ one host→device→host round trip per session."""
-    R = taskrow.shape[1] - 2
+    R = tol.shape[1]
     J = job_min_avail.shape[0]
     cf = cf_u8.astype(jnp.float32)
     alloc = nd[R : 2 * R]
@@ -413,9 +453,9 @@ def schedule_session_pallas(
     static_argnames=("weights", "block_size", "gang_rounds", "interpret"),
 )
 def schedule_session_pallas_packed(
-    taskrow_ext: jnp.ndarray,  # [T_act, R+3] — resreq, class, active0, job
+    taskrow_ext: jnp.ndarray,  # [T_act, R+3+2K] — resreq, class, active0, spread, job
     cf_u8: jnp.ndarray,  # [C, NS, 128] u8
-    nd: jnp.ndarray,  # [3R+2, NS, 128]
+    nd: jnp.ndarray,  # [3R+2+3K, NS, 128]
     tol: jnp.ndarray,  # [1, R]
     jobs2: jnp.ndarray,  # [2, J_pad] i32 — min_available | ready_count
     weights: ScoreWeights = DEFAULT_WEIGHTS,
@@ -429,10 +469,10 @@ def schedule_session_pallas_packed(
     eight — each extra transfer pays the device-link round trip.
     Semantics identical to schedule_session_pallas (device-side
     unpack + delegation)."""
-    R = taskrow_ext.shape[1] - 3
-    taskrow = taskrow_ext[:, : R + 2]
+    R = tol.shape[1]
+    taskrow = taskrow_ext[:, :-1]
     active0 = taskrow_ext[:, R + 1] > 0.0
-    task_job = taskrow_ext[:, R + 2].astype(jnp.int32)
+    task_job = taskrow_ext[:, -1].astype(jnp.int32)
     return schedule_session_pallas(
         taskrow,
         cf_u8,
@@ -462,7 +502,9 @@ def _node_planes(arr: np.ndarray, NK: int) -> np.ndarray:
 def prepare_pallas_arrays(
     snap: PackedSnapshot, block_size: int = 256
 ) -> Tuple[dict, int, int]:
-    """Host-side packing into the kernel's plane layout.
+    """Host-side packing into the kernel's plane layout (with K spread
+    classes: task rows gain thr[K] | inc[K] after the active column, the
+    node array dom[K] | elig[K] | count0[K] after maxt).
 
     Nodes are cut to NK = ceil(n_nodes/128)*128 (instead of the pow2
     padded width) — every per-step op is O(NK), so the tighter width is a
@@ -490,11 +532,18 @@ def prepare_pallas_arrays(
     cf = np.zeros((C, NK), dtype=np.float32)
     cf[:, :NV] = sel_ok & tol_ok & snap.node_ok[None, :NV]
 
-    taskrow = np.zeros((T_act, R + 2), dtype=np.float32)
+    K = snap.spread_max_skew.shape[0] if snap.has_spread else 0
+    taskrow = np.zeros((T_act, R + 2 + 2 * K), dtype=np.float32)
     n_copy = min(T_act, T_pad)
     taskrow[:n_copy, :R] = snap.task_resreq[:n_copy]
     taskrow[:n_copy, R] = task_cls[:n_copy].astype(np.float32)
     # active column filled per gang round by the caller
+    spread_planes = []
+    if K:
+        thr, inc = spread_task_columns(snap, T_act)
+        taskrow[:, R + 2 : R + 2 + K] = thr
+        taskrow[:, R + 2 + K :] = inc
+        spread_planes = [p.reshape(K, NS, LANES) for p in spread_node_planes(snap, NK)]
 
     # One stacked node array: base | alloc | used0 | count0, maxt — a
     # single host→device transfer (u8 feasibility likewise shrinks its
@@ -514,6 +563,7 @@ def prepare_pallas_arrays(
                 ),
                 NK,
             ),
+            *spread_planes,
         ]
     )
     arrays = dict(
@@ -535,25 +585,28 @@ def pallas_vmem_bytes(snap: PackedSnapshot, block_size: int = 256) -> int:
     R = snap.task_resreq.shape[1]
     NK = max(LANES, -(-max(snap.n_nodes, 1) // LANES) * LANES)
     _, class_sel, _ = _feasibility_classes(snap)
-    return kernel_vmem_bytes(class_sel.shape[0], NK, R, block_size)
+    K = snap.spread_max_skew.shape[0] if snap.has_spread else 0
+    return kernel_vmem_bytes(class_sel.shape[0], NK, R, block_size, K)
 
 
-def kernel_vmem_bytes(C: int, NK: int, R: int, block_size: int = 256) -> int:
+def kernel_vmem_bytes(C: int, NK: int, R: int, block_size: int = 256, K: int = 0) -> int:
     """:func:`pallas_vmem_bytes` from the kernel's shape alone."""
     # cf + nd + maxal/allocpos + scratch (used, cnt, masked-score plane)
     # + Mosaic's temporaries for the full-width recompute.  Compiling for
     # v5e at R=2..8, C=1 and 64, the scoped allocation ran to about
     # C + 7R + 17 planes against the C + 14R + 6 estimated here
-    # (tests/test_tpu_compile.py pins the admitted edge at R=2..4)
-    n_planes = C + (3 * R + 2) + 2 * R + (R + 2) + (8 * R + 2)
-    # task block streams as [TB, R+2] → tiled to 128 lanes, double-buffered
+    # (tests/test_tpu_compile.py pins the admitted edge at R=2..4).  Each
+    # spread class adds three node planes, its count scratch and about
+    # four temporaries of the per-step mask.
+    n_planes = C + (3 * R + 2) + 2 * R + (R + 2) + (8 * R + 2) + 8 * K
+    # task block streams as [TB, R+2+2K] → tiled to 128 lanes, double-buffered
     return n_planes * NK * 4 + 2 * block_size * LANES * 4
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "T_rows", "R", "U", "C", "ND", "NS", "JP",
+        "T_rows", "R", "U", "C", "ND", "NS", "JP", "K",
         "weights", "block_size", "gang_rounds", "interpret",
     ),
 )
@@ -562,6 +615,7 @@ def schedule_session_pallas_buf(
     #                            row_id(u16) | job(u16) | jobs2(i32)
     cluster_buf: jnp.ndarray,  # uint8 — cf_u8 | nd(f32)
     T_rows: int, R: int, U: int, C: int, ND: int, NS: int, JP: int,
+    K: int = 0,
     weights: ScoreWeights = DEFAULT_WEIGHTS,
     block_size: int = 256,
     gang_rounds: int = 3,
@@ -582,9 +636,10 @@ def schedule_session_pallas_buf(
     )
     n_act = hdr[0]
     o += 4
+    W = R + 1 + 2 * K  # template row: resreq lanes, class, spread columns
     tol_b = jax.lax.dynamic_slice_in_dim(session_buf, o, R * 4); o += R * 4
-    tpl_b = jax.lax.dynamic_slice_in_dim(session_buf, o, U * (R + 1) * 4)
-    o += U * (R + 1) * 4
+    tpl_b = jax.lax.dynamic_slice_in_dim(session_buf, o, U * W * 4)
+    o += U * W * 4
     rid_b = jax.lax.dynamic_slice_in_dim(session_buf, o, T_rows * 2)
     o += T_rows * 2
     tj_b = jax.lax.dynamic_slice_in_dim(session_buf, o, T_rows * 2)
@@ -594,7 +649,7 @@ def schedule_session_pallas_buf(
     tol = jax.lax.bitcast_convert_type(tol_b.reshape(-1, 4), jnp.float32).reshape(1, R)
     templates = jax.lax.bitcast_convert_type(
         tpl_b.reshape(-1, 4), jnp.float32
-    ).reshape(U, R + 1)
+    ).reshape(U, W)
     row_id = jax.lax.bitcast_convert_type(
         rid_b.reshape(-1, 2), jnp.uint16
     ).astype(jnp.int32)
@@ -607,10 +662,11 @@ def schedule_session_pallas_buf(
 
     # reconstruct the full task rows device-side: template gather +
     # active column (first n_act tasks) + job column
-    rows = templates[row_id]  # [T_rows, R+1]
+    rows = templates[row_id]  # [T_rows, W]
     active = (jnp.arange(T_rows) < n_act).astype(jnp.float32)
     taskrow_ext = jnp.concatenate(
-        [rows, active[:, None], task_job.astype(jnp.float32)[:, None]], axis=1
+        [rows[:, : R + 1], active[:, None], rows[:, R + 1 :],
+         task_job.astype(jnp.float32)[:, None]], axis=1
     )
 
     cf_u8 = jax.lax.dynamic_slice_in_dim(cluster_buf, 0, C * NS * LANES).reshape(
@@ -701,18 +757,21 @@ def pallas_session_payload_bytes(snap: PackedSnapshot, block_size: int = 256) ->
     T_rows = max(TB, -(-max(snap.n_tasks, 1) // TB) * TB)
     R = snap.task_resreq.shape[1]
     task_cls, _, _ = _feasibility_classes(snap)
-    rows = np.zeros((T_rows, R + 1), dtype=np.float32)
+    K = snap.spread_max_skew.shape[0] if snap.has_spread else 0
+    rows = np.zeros((T_rows, R + 1 + 2 * K), dtype=np.float32)
     n_copy = min(T_rows, T_pad)
     rows[:n_copy, :R] = snap.task_resreq[:n_copy]
     rows[:n_copy, R] = task_cls[:n_copy].astype(np.float32)
+    if K:
+        rows[:, R + 1 :] = np.concatenate(spread_task_columns(snap, T_rows), axis=1)
     first_idx, _ = _template_rows(rows)
     U = int(first_idx.shape[0])
     JP = snap.job_min_available.shape[0]
     n_tj = min(T_rows, snap.task_job.shape[0])
     if U >= 2**16 or JP >= 2**16 or int(snap.task_job[:n_tj].max(initial=0)) >= 2**16:
         # degenerate diversity: full f32 rows ship (5-transfer path)
-        return T_rows * (R + 3) * 4 + R * 4 + 2 * JP * 4
-    return 4 + R * 4 + _u_pad(U) * (R + 1) * 4 + T_rows * 4 + 2 * JP * 4
+        return T_rows * (R + 3 + 2 * K) * 4 + R * 4 + 2 * JP * 4
+    return 4 + R * 4 + _u_pad(U) * (R + 1 + 2 * K) * 4 + T_rows * 4 + 2 * JP * 4
 
 
 def make_session_dispatch(
@@ -738,7 +797,8 @@ def make_session_dispatch(
     arrays, T_act, _ = prepare_pallas_arrays(snap, block_size)
 
     T_rows = arrays["taskrow"].shape[0]
-    R = arrays["taskrow"].shape[1] - 2
+    R = arrays["tol"].shape[1]
+    K = (arrays["taskrow"].shape[1] - R - 2) // 2
     n_act = min(snap.n_tasks, T_act)
     jobs2 = np.stack(
         [
@@ -748,8 +808,10 @@ def make_session_dispatch(
     )
     JP = jobs2.shape[1]
 
-    # deduplicate (resreq lanes, class) rows into templates + u16 ids
-    rows = np.ascontiguousarray(arrays["taskrow"][:, : R + 1])
+    # deduplicate (resreq lanes, class, spread columns) rows — every
+    # column but the active one — into templates + u16 ids
+    tr = arrays["taskrow"]
+    rows = np.ascontiguousarray(np.concatenate([tr[:, : R + 1], tr[:, R + 2 :]], axis=1))
     first_idx, inv = _template_rows(rows)
     U = int(first_idx.shape[0])
 
@@ -757,10 +819,11 @@ def make_session_dispatch(
     n_tj = min(T_act, snap.task_job.shape[0])
     if U >= 2**16 or JP >= 2**16 or int(snap.task_job[:n_tj].max(initial=0)) >= 2**16:
         # degenerate row diversity — ship full rows the old 5-transfer way
-        taskrow_ext = np.zeros((T_rows, R + 3), np.float32)
-        taskrow_ext[:, : R + 1] = rows
+        taskrow_ext = np.zeros((T_rows, R + 3 + 2 * K), np.float32)
+        taskrow_ext[:, : R + 1] = rows[:, : R + 1]
+        taskrow_ext[:, R + 2 : -1] = rows[:, R + 1 :]
         taskrow_ext[:n_act, R + 1] = 1.0
-        taskrow_ext[:n_tj, R + 2] = snap.task_job[:n_tj].astype(np.float32)
+        taskrow_ext[:n_tj, -1] = snap.task_job[:n_tj].astype(np.float32)
         args5 = (taskrow_ext, arrays["cf_u8"], arrays["nd"],
                  arrays["tol"], jobs2)
         if prestage:
@@ -779,7 +842,7 @@ def make_session_dispatch(
         # whenever the distinct-row count drifts between sessions (zero
         # template rows are inert — no row_id points at them)
         U_pad = _u_pad(U)
-        templates = np.zeros((U_pad, R + 1), dtype=np.float32)
+        templates = np.zeros((U_pad, R + 1 + 2 * K), dtype=np.float32)
         templates[:U] = rows[first_idx]
         session_buf = np.concatenate([
             np.array([n_act], dtype=np.int32).view(np.uint8),
@@ -794,7 +857,7 @@ def make_session_dispatch(
             session_buf = jax.device_put(jnp.asarray(session_buf))
         kw = dict(
             T_rows=T_rows, R=R, U=U_pad, C=arrays["cf_u8"].shape[0],
-            ND=arrays["nd"].shape[0], NS=arrays["nd"].shape[1], JP=JP,
+            ND=arrays["nd"].shape[0], NS=arrays["nd"].shape[1], JP=JP, K=K,
             weights=weights, block_size=block_size,
             gang_rounds=gang_rounds, interpret=interpret,
         )

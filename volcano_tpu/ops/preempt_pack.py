@@ -256,6 +256,9 @@ def pack_preempt_session(ssn) -> PreemptPacked:
         jobs,
         nodes,
         enforce_pod_count="predicates" in ssn.predicate_fns,
+        # the kernel models no topology spread: such tasks set
+        # needs_host_validation and the action runs on the host
+        spread=False,
     )
 
     pk = PreemptPacked(base=base)

@@ -49,6 +49,7 @@ from volcano_tpu.ops.blocked import (
     gang_fixpoint,
     INT_BIG,
     make_inner_step,
+    run_packed_blocked,
     task_block_padding,
 )
 from volcano_tpu.ops.kernels import (
@@ -363,7 +364,11 @@ def run_packed_sharded(
 ) -> np.ndarray:
     """Host wrapper: PackedSnapshot → assignment[T] on a device mesh,
     with the adaptive gang fixpoint (same protocol as run_packed_blocked)
-    around the sharded blocked pass."""
+    around the sharded blocked pass.  Sessions with hard topology spread
+    take run_packed_blocked's way to the plain scan."""
+    if snap.has_spread:
+        return run_packed_blocked(snap, weights=weights, gang_rounds=gang_rounds,
+                                  discard_unstable=discard_unstable)
     n_dev = mesh.devices.size
     if not f32_lr_exact(snap):
         weights = weights._replace(lr_int_exact=True)

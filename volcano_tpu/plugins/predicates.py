@@ -4,7 +4,7 @@ Reference: pkg/scheduler/plugins/predicates/predicates.go, with the used
 subset of the vendored k8s predicate algorithms implemented natively:
 pod count, node condition/unschedulable, node selector + required node
 affinity, host ports, taints/tolerations, optional memory/disk/pid
-pressure, pod (anti-)affinity.
+pressure, pod topology spread (hard constraints), pod (anti-)affinity.
 """
 
 from __future__ import annotations
@@ -97,6 +97,13 @@ class PredicatesPlugin(Plugin):
             # HostPorts.
             if not putil.fits_host_ports(pod, pl.pods_on_node(node)):
                 raise FitError(task, node, reasons.NODE_PORT_CONFLICT)
+
+            # PodTopologySpread, DoNotSchedule constraints: O(constraints)
+            # against the session's per-domain counts (util.TopologySpread).
+            if pod.spec.topology_spread_constraints:
+                reason = pl.spread.filter(task, node)
+                if reason is not None:
+                    raise FitError(task, node, reason)
 
             # Pod (anti-)affinity (predicates.go:280-298).
             if pod.spec.affinity and (

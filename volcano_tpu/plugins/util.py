@@ -1,4 +1,5 @@
-"""Shared predicate/score helpers: label selectors, affinity terms, taints.
+"""Shared predicate/score helpers: label selectors, affinity terms, taints,
+topology spread.
 
 Reference: pkg/scheduler/plugins/util/util.go (listers) and the used subset
 of the vendored k8s predicate algorithms
@@ -9,9 +10,10 @@ bitmask lanes (volcano_tpu.ops.predicates).
 
 from __future__ import annotations
 
-from typing import Dict, Iterable, List, Optional
+from typing import Dict, Iterable, List, Optional, Tuple
 
-from volcano_tpu.api import NodeInfo, TaskInfo
+from volcano_tpu.api import NodeInfo, TaskInfo, TaskStatus
+from volcano_tpu.api import unschedule_info as reasons
 from volcano_tpu.apis import core
 
 DEFAULT_TOPOLOGY_KEY = "kubernetes.io/hostname"
@@ -206,6 +208,275 @@ def _same_topology(
     return va is not None and va == vb
 
 
+# ---- pod topology spread (k8s v1.30 plugins/podtopologyspread) ----
+
+HARD_SPREAD = "DoNotSchedule"
+SOFT_SPREAD = "ScheduleAnyway"
+
+
+def hard_spread_constraints(pod: core.Pod) -> List[core.TopologySpreadConstraint]:
+    """The constraints the Filter enforces: ``whenUnsatisfiable:
+    DoNotSchedule``, in the pod's order."""
+    return [
+        c for c in pod.spec.topology_spread_constraints or []
+        if c.when_unsatisfiable == HARD_SPREAD
+    ]
+
+
+def has_soft_spread(pod: core.Pod) -> bool:
+    """``ScheduleAnyway`` constraints are scoring, not filtering."""
+    return any(
+        c.when_unsatisfiable == SOFT_SPREAD
+        for c in pod.spec.topology_spread_constraints or []
+    )
+
+
+def spread_selector(pod: core.Pod, c: core.TopologySpreadConstraint) -> Optional[dict]:
+    """The constraint's selector with ``matchLabelKeys`` merged in: one
+    ``In`` requirement per key the incoming pod carries, at its value
+    (filterTopologySpreadConstraints).  None is k8s's nil selector,
+    which matches nothing."""
+    sel = c.label_selector
+    if sel is None or not c.match_label_keys:
+        return sel
+    labels = pod.metadata.labels or {}
+    extra = [
+        {"key": k, "operator": "In", "values": [labels[k]]}
+        for k in c.match_label_keys if k in labels
+    ]
+    if not extra:
+        return sel
+    return {**sel, "matchExpressions": list(sel.get("matchExpressions") or []) + extra}
+
+
+def spread_self_match(sel: Optional[dict], pod: core.Pod) -> int:
+    """The Filter's self-match term: 1 when the selector matches the
+    incoming pod's own labels.  A nil selector matches nothing, an
+    empty one everything."""
+    if sel is None:
+        return 0
+    return int(match_label_selector(pod.metadata.labels or {}, sel))
+
+
+def spread_counts_pod(sel: Optional[dict], namespace: str, pod: Optional[core.Pod]) -> bool:
+    """countPodsMatchSelector for one pod: an empty (or nil) selector
+    counts nothing; a pod counts when it lives in the incoming pod's
+    namespace, is not terminating, and matches."""
+    if pod is None or not sel or not (sel.get("matchLabels") or sel.get("matchExpressions")):
+        return False
+    if pod.metadata.namespace != namespace or pod.metadata.deletion_timestamp is not None:
+        return False
+    return match_label_selector(pod.metadata.labels or {}, sel)
+
+
+def _selector_key(sel: Optional[dict]):
+    """A hashable form of a label selector (None stays None)."""
+    if sel is None:
+        return None
+    return (
+        tuple(sorted((sel.get("matchLabels") or {}).items())),
+        tuple((e.get("key"), e.get("operator", "In"), tuple(e.get("values") or ()))
+              for e in sel.get("matchExpressions") or ()),
+    )
+
+
+def _freeze(obj):
+    """A hashable copy of a dict/list tree, dict keys sorted (node affinity)."""
+    if isinstance(obj, dict):
+        return tuple(sorted((k, _freeze(v)) for k, v in obj.items()))
+    if isinstance(obj, (list, tuple)):
+        return tuple(_freeze(v) for v in obj)
+    return obj
+
+
+def spread_class_key(
+    pod: core.Pod, c: core.TopologySpreadConstraint
+) -> Tuple:
+    """What two hard constraints must share to share their per-domain
+    counts and their verdict on every node: the namespace and selector
+    that count pods, the topology key, maxSkew, and what makes a node
+    eligible to count — every topology key of the pod's hard
+    constraints, and the pod's node selector and required affinity or
+    tolerations where the constraint's inclusion policies honour them."""
+    keys = tuple(sorted({h.topology_key for h in hard_spread_constraints(pod)}))
+    affinity = taints = None
+    if (c.node_affinity_policy or "Honor") == "Honor" and (
+        pod.spec.node_selector or pod.spec.affinity
+    ):
+        affinity = _freeze([
+            pod.spec.node_selector or {},
+            ((pod.spec.affinity or {}).get("nodeAffinity") or {}).get(
+                "requiredDuringSchedulingIgnoredDuringExecution"),
+        ])
+    if (c.node_taints_policy or "Ignore") == "Honor":
+        taints = tuple((t.key, t.operator, t.value, t.effect) for t in pod.spec.tolerations or [])
+    return (
+        pod.metadata.namespace, _selector_key(spread_selector(pod, c)), c.topology_key,
+        int(c.max_skew), keys, affinity, taints,
+    )
+
+
+def spread_eligible(
+    pod: core.Pod, c: core.TopologySpreadConstraint, nodes: List[Optional[core.Node]]
+) -> List[bool]:
+    """Which of ``nodes`` count toward the constraint's domains
+    (calPreFilterState)?  A node must carry every topology key of the
+    pod's hard constraints and pass the inclusion policies: the pod's
+    node selector and required affinity under ``nodeAffinityPolicy:
+    Honor`` (the default), its tolerations under ``nodeTaintsPolicy:
+    Honor``."""
+    keys = {h.topology_key for h in hard_spread_constraints(pod)}
+    affinity = (c.node_affinity_policy or "Honor") == "Honor" and bool(
+        pod.spec.node_selector or pod.spec.affinity
+    )
+    taints = (c.node_taints_policy or "Ignore") == "Honor"
+    return [
+        node is not None
+        and keys.issubset(node.metadata.labels or {})
+        and not (affinity and not pod_matches_node_selector(pod, node))
+        and not (taints and not pod_tolerates_node_taints(pod, node))
+        for node in nodes
+    ]
+
+
+def counted_pods(node: NodeInfo):
+    """The pods on a session node that spread counting sees: every task
+    the node holds except those being released (a Releasing task is
+    a pod on its way out, which k8s skips by its deletion timestamp)."""
+    for t in node.tasks.values():
+        if t.pod is not None and t.status != TaskStatus.Releasing:
+            yield t.uid, t.pod
+
+
+class SpreadClass:
+    """One (namespace, selector, topology key, maxSkew, eligibility)
+    class: the matching pods per eligible domain, and their minimum,
+    kept in O(1) per update by a histogram of the domain counts."""
+
+    __slots__ = ("namespace", "selector", "key", "domain_of", "counts", "_hist", "_min")
+
+    def __init__(self, namespace: str, selector: Optional[dict], key: str,
+                 domain_of: Dict[str, str]):
+        self.namespace = namespace
+        self.selector = selector
+        self.key = key
+        #: eligible node name → its domain (the node's value of ``key``)
+        self.domain_of = domain_of
+        self.counts: Dict[str, int] = dict.fromkeys(domain_of.values(), 0)
+        self._hist: Dict[int, int] = {0: len(self.counts)} if self.counts else {}
+        self._min = 0
+
+    def _move(self, domain: str, step: int) -> None:
+        v = self.counts[domain]
+        self.counts[domain] = v + step
+        self._hist[v] -= 1
+        self._hist[v + step] = self._hist.get(v + step, 0) + 1
+        if step < 0 and v + step < self._min:
+            self._min = v + step
+        elif v == self._min and not self._hist[v]:
+            self._min = v + 1
+
+    def add(self, pod: core.Pod, node_name: str, step: int = 1) -> None:
+        domain = self.domain_of.get(node_name)
+        if domain is not None and spread_counts_pod(self.selector, self.namespace, pod):
+            self._move(domain, step)
+
+    def min_match(self, min_domains: Optional[int]) -> float:
+        """The global minimum over eligible domains; 0 while fewer
+        domains than ``minDomains`` exist, unbounded with none."""
+        if not self.counts:
+            return float("inf")
+        if min_domains is not None and len(self.counts) < min_domains:
+            return 0
+        return self._min
+
+
+class TopologySpread:
+    """Per-session PreFilter state of PodTopologySpread: one
+    :class:`SpreadClass` per distinct hard constraint the session has
+    checked, built on its first check from the session's nodes and kept
+    in step by the allocate/deallocate events, so a (task, node) check
+    costs O(constraints) rather than a scan of every pod."""
+
+    def __init__(self, session):
+        self.session = session
+        self._classes: Dict[Tuple, SpreadClass] = {}
+        #: task uid → [(constraint, class, self-match)] of its hard constraints
+        self._task_classes: Dict[str, list] = {}
+        #: uid → (node name, pod) of every counted pod; None until a class exists
+        self._where: Optional[Dict[str, Tuple[str, core.Pod]]] = None
+
+    @property
+    def active(self) -> bool:
+        return self._where is not None
+
+    def update(self, task: TaskInfo, node_name: str) -> None:
+        """``task`` now sits on ``node_name`` ("" when it left its node
+        or is being released)."""
+        if self._where is None:
+            return
+        old = self._where.get(task.uid)
+        if old is not None and old[0] == node_name:
+            return
+        if old is not None:
+            del self._where[task.uid]
+            for cls in self._classes.values():
+                cls.add(old[1], old[0], -1)
+        if node_name and task.pod is not None:
+            self._where[task.uid] = (node_name, task.pod)
+            for cls in self._classes.values():
+                cls.add(task.pod, node_name)
+
+    def _class(self, pod: core.Pod, c: core.TopologySpreadConstraint) -> SpreadClass:
+        key = spread_class_key(pod, c)
+        cls = self._classes.get(key)
+        if cls is not None:
+            return cls
+        if self._where is None:
+            self._where = {
+                uid: (node.name, p)
+                for node in self.session.nodes.values()
+                for uid, p in counted_pods(node)
+            }
+        nodes = list(self.session.nodes.values())
+        domain_of = {
+            node.name: node.node.metadata.labels[c.topology_key]
+            for node, ok in zip(nodes, spread_eligible(pod, c, [n.node for n in nodes]))
+            if ok
+        }
+        cls = SpreadClass(pod.metadata.namespace, spread_selector(pod, c),
+                          c.topology_key, domain_of)
+        for node_name, p in self._where.values():
+            cls.add(p, node_name)
+        self._classes[key] = cls
+        return cls
+
+    def filter(self, task: TaskInfo, node: NodeInfo) -> Optional[str]:
+        """None when ``task`` may land on ``node`` under every hard
+        constraint, else the Filter's reason (PodTopologySpread.Filter):
+        a node without a constraint's key fails, and otherwise the skew
+        — matching pods in the node's domain, plus one when the pod
+        matches its own selector, less the global minimum — may not
+        pass maxSkew."""
+        entries = self._task_classes.get(task.uid)
+        if entries is None:
+            pod = task.pod
+            entries = [
+                (c, self._class(pod, c), spread_self_match(spread_selector(pod, c), pod))
+                for c in hard_spread_constraints(pod)
+            ]
+            self._task_classes[task.uid] = entries
+        labels = node.node.metadata.labels if node.node is not None else {}
+        for c, cls, self_match in entries:
+            domain = labels.get(c.topology_key)
+            if domain is None:
+                return reasons.SPREAD_LABEL_MISSING
+            skew = cls.counts.get(domain, 0) + self_match - cls.min_match(c.min_domains)
+            if skew > c.max_skew:
+                return reasons.SPREAD_CONSTRAINTS_MISMATCH
+        return None
+
+
 class PodLister:
     """Session-wide pod view for relational predicates.
 
@@ -223,6 +494,8 @@ class PodLister:
         # a session's predicate validation O(tasks²)).
         self._assigned_anti_affinity: set = set()
         self._has_anti_affinity: set = set()
+        #: hard topology spread counts, kept in step with the same events
+        self.spread = TopologySpread(session)
         for job in session.jobs.values():
             for task in job.tasks.values():
                 if task.pod is not None:
@@ -234,6 +507,7 @@ class PodLister:
 
     def update_task(self, task: TaskInfo, node_name: str) -> None:
         self._task_nodes[task.uid] = node_name
+        self.spread.update(task, node_name)
         if task.uid in self._has_anti_affinity:
             if node_name:
                 self._assigned_anti_affinity.add(task.uid)
