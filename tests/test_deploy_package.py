@@ -297,7 +297,7 @@ def test_static_manifest_commands_parse():
                 known = {"--bus", "--listen-host", "--listen-port",
                          "--leader-elect", "--leader-elect-id",
                          "--scheduler-conf", "--schedule-period",
-                         "--scheduler-name", "--gc-quiesce-period",
+                         "--scheduler-name",
                          "--snapshot-reuse", "--warmup",
                          "--micro-cycles", "--micro-debounce-ms",
                          "--percentage-nodes-to-find",

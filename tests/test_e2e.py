@@ -721,25 +721,6 @@ class TestE2EDistributedWorkloads:
         assert cluster.vc.get_job("default", "big-mpi").status.state.phase == batch.JOB_PENDING
 
 
-def test_scheduler_gc_quiesce_period():
-    """--gc-quiesce-period N: every N cycles the loop thaws, collects,
-    and freezes survivors; scheduling results are unaffected."""
-    import gc
-
-    cluster = Cluster()
-    cluster.scheduler.gc_quiesce_period = 2
-    submit(cluster)
-    frozen_before = gc.get_freeze_count()
-    try:
-        cluster.tick(rounds=4)  # ≥2 quiesce points
-        assert gc.get_freeze_count() > frozen_before
-        pods = cluster.kube.list_pods("default")
-        assert pods and all(p.spec.node_name for p in pods)
-    finally:
-        # leave no frozen state behind for other tests
-        gc.unfreeze()
-
-
 def test_job_delete_cascades_to_pods_and_podgroup():
     """Deleting a Job must take its Pods and PodGroup with it (the k8s
     owner-reference GC the reference relies on) and release the

@@ -454,6 +454,12 @@ class SchedulerCache(Cache):
                 self.share_ledger.schedulable_count,
             )
 
+    def resident_objects(self) -> int:
+        """Pods + nodes resident in the cache: the size the collector
+        policy (utils/gcutil.py) gates its first freeze on."""
+        with self._mutex:
+            return len(self.nodes) + sum(len(j.tasks) for j in self.jobs.values())
+
     @staticmethod
     def _classify_pod_update(old_ti: TaskInfo, new_ti: TaskInfo,
                              spec_changed: bool) -> Optional[str]:

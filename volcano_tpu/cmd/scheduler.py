@@ -39,7 +39,6 @@ class SchedulerDaemon(BaseDaemon):
         scheduler_conf: str = "",
         schedule_period: float = 1.0,
         scheduler_name: str = "volcano-tpu",
-        gc_quiesce_period: int = 0,
         snapshot_reuse: bool = False,
         cycle_deadline_ms=None,
         pipelined_commit: bool = False,
@@ -96,8 +95,6 @@ class SchedulerDaemon(BaseDaemon):
                 from volcano_tpu.faults import watchdog
 
                 watchdog.configure_deadline(cycle_deadline_ms)
-            if gc_quiesce_period:
-                self.scheduler.gc_quiesce_period = gc_quiesce_period
             return
         self.cache = SchedulerCache(
             client=SchedulerClient(api),
@@ -107,7 +104,7 @@ class SchedulerDaemon(BaseDaemon):
         )
         self.scheduler = Scheduler(
             self.cache, scheduler_conf_path=scheduler_conf,
-            period=schedule_period, gc_quiesce_period=gc_quiesce_period,
+            period=schedule_period,
             cycle_deadline_ms=cycle_deadline_ms,
             micro_cycles=micro_cycles,
             micro_debounce_ms=micro_debounce_ms,
@@ -220,12 +217,6 @@ def main(argv=None) -> int:
     parser.add_argument("--scheduler-conf", default="")
     parser.add_argument("--schedule-period", type=float, default=1.0)
     parser.add_argument("--scheduler-name", default="volcano-tpu")
-    parser.add_argument(
-        "--gc-quiesce-period", type=int, default=0,
-        help="every N cycles, gc-collect and freeze survivors so "
-        "sessions stop re-traversing the long-lived cache graph "
-        "(0 = off)",
-    )
     parser.add_argument(
         "--snapshot-reuse", action="store_true",
         help="reuse the previous session's untouched clones at session "
@@ -426,7 +417,6 @@ def main(argv=None) -> int:
             scheduler_conf=args.scheduler_conf,
             schedule_period=args.schedule_period,
             scheduler_name=args.scheduler_name,
-            gc_quiesce_period=args.gc_quiesce_period,
             snapshot_reuse=args.snapshot_reuse,
             cycle_deadline_ms=args.cycle_deadline_ms or None,
             pipelined_commit=args.pipelined_commit,

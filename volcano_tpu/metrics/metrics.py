@@ -1031,3 +1031,11 @@ def gc_pause_histograms() -> List[_Histogram]:
         )
         for g in range(3)
     ]
+
+
+def register_gc_quiesce(reason: str, frozen: int) -> None:
+    """volcano_gc_quiesce_total{reason}: quiesces the collector policy
+    (utils/gcutil.py) ran at a cycle boundary, reason ∈ {first, growth};
+    volcano_gc_frozen_objects: the objects the last one froze."""
+    registry.inc(f"{_NAMESPACE}_gc_quiesce_total", {"reason": reason})
+    registry.set_gauge(f"{_NAMESPACE}_gc_frozen_objects", {}, frozen)

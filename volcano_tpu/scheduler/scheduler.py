@@ -69,7 +69,6 @@ class Scheduler:
         cache: Cache,
         scheduler_conf_path: str = "",
         period: float = DEFAULT_SCHEDULE_PERIOD,
-        gc_quiesce_period: int = 0,
         cycle_deadline_ms: Optional[float] = None,
         micro_cycles: bool = False,
         micro_debounce_ms: float = 5.0,
@@ -90,16 +89,6 @@ class Scheduler:
             watchdog.configure_deadline(cycle_deadline_ms)
         self.scheduler_conf_path = scheduler_conf_path
         self.period = period
-        #: every N cycles, collect + freeze gen-2 survivors so steady-state
-        #: sessions stop re-traversing the long-lived cache graph (at 50k
-        #: pods the cache holds millions of objects; a gen-2 collection
-        #: mid-session costs hundreds of ms).  0 = off.  Each quiesce
-        #: thaws first, so cyclic garbage frozen earlier is reclaimed —
-        #: delayed by at most N cycles, never leaked.  Opt-in because the
-        #: win only materializes on large long-lived caches; small
-        #: deployments just pay the periodic full collection.
-        self.gc_quiesce_period = gc_quiesce_period
-        self._cycles_since_quiesce = 0
         self._stopped = False
         #: monotonically increasing cycle sequence — the cross-process
         #: correlation id when no trace recorder assigns one
@@ -405,17 +394,16 @@ class Scheduler:
             finally:
                 # close the cycle BEFORE the quiesce: the collection
                 # pause is maintenance, not scheduling latency — folding
-                # it in would spike the p99 every Nth cycle
+                # it in would spike the cycle that quiesces
                 cycle_span.__exit__(None, None, None)
                 elapsed = cycle_span.seconds
-                # in a finally so persistently-failing cycles (BaseDaemon
-                # retries them) still thaw+collect previously frozen dead
-                # objects instead of pinning them for the failure window
-                if self.gc_quiesce_period > 0:
-                    self._cycles_since_quiesce += 1
-                    if self._cycles_since_quiesce >= self.gc_quiesce_period:
-                        self._cycles_since_quiesce = 0
-                        gcutil.gc_quiesce()
+                # the collector policy: freeze the long-lived cache graph
+                # out of full collections.  In a finally so persistently
+                # failing cycles (BaseDaemon retries them) still thaw and
+                # collect previously frozen dead objects
+                gcutil.after_cycle(
+                    getattr(self.cache, "resident_objects", lambda: 0)
+                )
                 # journal flush sits outside the e2e latency stamp for
                 # the same reason the gc quiesce does (maintenance I/O),
                 # but in the innermost finally: a cycle that crashes in

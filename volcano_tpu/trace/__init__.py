@@ -129,6 +129,7 @@ SPAN_NAMES = (
     "vtpu/statement.commit",  # Statement.commit
     "vtpu/cache.flush",  # SchedulerCache.flush
     "vtpu/gc",  # a generation-2 collection (utils/gcutil.py)
+    "vtpu/gc.quiesce",  # the collector policy's thaw, collect and freeze, after vtpu/cycle
 )
 
 #: ``jax.profiler.TraceAnnotation`` once this process has imported JAX;
