@@ -8,7 +8,7 @@ The last class pins the whole-tree contract the CI `analysis` job
 enforces: `python -m volcano_tpu.analysis` over this repo exits 0 —
 which also pins every genuine violation this PR fixed (unlocked
 guarded-attribute accesses in trace/recorder, bus/remote,
-client/apiserver, serving/compute_plane, cache/cache; the serde
+client/apiserver, cache/cache; the serde
 round-trip registry) against regression: reverting any fix re-raises
 its finding and fails the suite.
 """

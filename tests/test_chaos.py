@@ -2,11 +2,11 @@
 
 The proof for the fault plane (ISSUE 5): the full control loop — store
 → BusServer → RemoteAPIServer informers → SchedulerCache → jax-allocate
-→ compute-plane sidecar → bind effects — runs for many cycles while the
-seeded plane fires faults at every seam at once (bus drops/partitions/
-relist storms, sidecar crashes/corrupt frames/forced session loss,
-device lowering failures, bind-failure bursts feeding the resync
-queue), and the run must end with
+→ the dispatch's degradation ladder → bind effects — runs for many
+cycles while the seeded plane fires faults at every seam at once (bus
+drops/partitions/relist storms, device lowering failures and corrupt
+kernel outputs, bind-failure bursts feeding the resync queue), and the
+run must end with
 
   * zero duplicate binds (no pod ever re-bound at the store),
   * zero lost jobs (every pod bound + running once faults stop),
@@ -38,7 +38,6 @@ from volcano_tpu.client import (
     VolcanoClient,
 )
 from volcano_tpu.scheduler.scheduler import Scheduler
-from volcano_tpu.serving.compute_plane import ComputePlaneServer
 
 from tests.builders import build_node, build_pod, build_pod_group, build_queue
 
@@ -66,9 +65,6 @@ def _clean_faults():
     faults.configure(None)
     faults.reset_breakers()
     faults.configure_deadline(None)
-    from volcano_tpu.ops import executor
-
-    executor.configure(None)
     trace.disable()
 
 
@@ -83,13 +79,12 @@ def _wait(pred, timeout=30.0, interval=0.02):
 
 class ChaosCluster:
     """The full control loop in one harness, every seam real: informers
-    over a TCP bus, kernels behind the compute-plane socket, binds
-    through the bus client.  The store-side audit watch records every
+    over a TCP bus, kernels behind the dispatch's degradation ladder,
+    binds through the bus client.  The store-side audit watch records every
     bind transition from API truth (it runs on the in-process server,
     outside fault injection)."""
 
-    def __init__(self, tmp_path, name, n_nodes=8, node_cpu="4",
-                 compute_plane=True):
+    def __init__(self, tmp_path, name, n_nodes=8, node_cpu="4"):
         self.api = APIServer()
         self.bus = BusServer(self.api).start()
         self.remote = RemoteAPIServer(
@@ -157,15 +152,6 @@ class ChaosCluster:
         conf = tmp_path / f"{name}-conf.yaml"
         conf.write_text(CONF)
         self.scheduler = Scheduler(self.cache, scheduler_conf_path=str(conf))
-        self.cp_path = str(tmp_path / f"{name}-cp.sock")
-        self.cp = None
-        from volcano_tpu.ops import executor
-
-        if compute_plane:
-            self.cp = ComputePlaneServer(self.cp_path).start()
-            executor.configure(self.cp_path)
-        else:
-            executor.configure(None)
         self.cache.run()
         self.cycle_errors = 0
 
@@ -275,19 +261,14 @@ class ChaosCluster:
         return dict(self.bound)
 
     def close(self):
-        from volcano_tpu.ops import executor
-
-        executor.configure(None)
         self.cache.stop_commit_plane()
-        if self.cp is not None:
-            self.cp.stop()
         self.remote.close()
         self.bus.stop()
 
 
-#: the mixed schedule of the acceptance criterion: bus drops + sidecar
-#: crash + device failures + bind bursts, all bounded by count so the
-#: settle phase converges
+#: the mixed schedule of the acceptance criterion: bus drops + device
+#: lowering failures + corrupt kernel outputs + bind bursts, all bounded
+#: by count so the settle phase converges
 MIXED_FAULTS = (
     "seed={seed};"
     "bus.disconnect=0.03:count=4;"
@@ -295,11 +276,8 @@ MIXED_FAULTS = (
     "bus.force_relist=0.3:count=4;"
     "bus.delay=0.05:count=6:ms=5;"
     "bus.client_drop=0.03:count=3;"
-    "compute.crash=0.12:count=3;"
-    "compute.corrupt=0.1:count=2;"
-    "compute.need_full=0.2:count=3;"
-    "compute.timeout=0.08:count=2;"
-    "device.lowering=0.1:count=2;"
+    "device.lowering=0.12:count=3;"
+    "device.nan=0.1:count=2;"
     "cache.bind_fail=0.12:count=5;"
     "cache.resync_fail=0.3:count=3;"
     "commit.fail=0.15:count=4;"
@@ -376,7 +354,7 @@ class TestChaosSmoke:
         try:
             cluster.submit("j0", replicas=2)
             faults.configure(
-                "seed=7;cache.bind_fail=1:count=2;compute.crash=1:count=1"
+                "seed=7;cache.bind_fail=1:count=2;device.lowering=1:count=1"
             )
             cluster.run_cycles(6)
             faults.configure(None)
@@ -394,58 +372,10 @@ class TestChaosSmoke:
                 if e["name"].startswith("fault:")
             ]
         assert any(e == "fault:cache.bind_fail" for e in fault_events)
-        assert any(e == "fault:compute.crash" for e in fault_events)
+        assert any(e == "fault:device.lowering" for e in fault_events)
 
 
 class TestKillRecovery:
-    def test_kill_sidecar_mid_run_recovers_within_a_cycle(self, tmp_path):
-        """Acceptance: kill-the-sidecar mid-cycle → the very next device
-        phase completes in-process, with the demotion visible in
-        /healthz (degraded), metrics, and the breaker; a restarted
-        sidecar is promoted back by the health re-probe."""
-        from volcano_tpu.metrics import metrics
-        from volcano_tpu.ops import executor
-
-        cluster = ChaosCluster(tmp_path, "sidecar-kill")
-        try:
-            cluster.submit("k0", replicas=3)
-            assert _wait(
-                lambda: (cluster.cycle() or True) and cluster.all_placed(),
-                timeout=30.0, interval=0.05,
-            )
-            # SIGKILL equivalent: the listener goes away AND every
-            # established connection dies with the process (stop() only
-            # closes the listener; a crash severs the accepted sockets
-            # too, which is what the client actually observes)
-            remote = executor._get_remote()
-            cluster.cp.stop()
-            if remote.client._sock is not None:
-                remote.client._sock.close()
-            cluster.submit("k1", replicas=3)
-            assert _wait(
-                lambda: (cluster.cycle() or True) and cluster.all_placed(),
-                timeout=30.0, interval=0.05,
-            )
-            cluster.assert_no_duplicate_binds()
-            br = faults.get_breaker("compute-plane")
-            assert br.open
-            assert any("compute-plane" in r for r in faults.degraded_reasons())
-            key = ("volcano_executor_fallbacks_total",
-                   (("cause", "error"), ("from", "remote"), ("to", "local")))
-            assert metrics.registry._counters.get(key, 0) >= 1
-            # restart on the same socket; collapse the probe window
-            cluster.cp = ComputePlaneServer(cluster.cp_path).start()
-            executor._get_remote().last_probe = 0.0
-            cluster.submit("k2", replicas=2)
-            assert _wait(
-                lambda: (cluster.cycle() or True) and cluster.all_placed(),
-                timeout=30.0, interval=0.05,
-            )
-            assert executor._last_route == "remote"
-            assert not br.open
-        finally:
-            cluster.close()
-
     def test_kill_apiserver_mid_watch_recovers(self, tmp_path):
         """Acceptance: kill-the-apiserver mid-watch → the bus client
         redials the restarted incarnation, relists (new epoch), and the
@@ -485,7 +415,7 @@ class TestKillRecovery:
         still schedule, the demotion is counted."""
         from volcano_tpu.metrics import metrics
 
-        cluster = ChaosCluster(tmp_path, "watchdog", compute_plane=False)
+        cluster = ChaosCluster(tmp_path, "watchdog")
         try:
             faults.configure_deadline(250.0)
             # the device phase sleeps past the whole budget every time

@@ -9,6 +9,7 @@ topology (vtpu-apiserver + scheduler + controllers + admission, all
 wired with --bus).
 """
 
+import pytest
 import yaml
 
 from volcano_tpu.deploy.package import (
@@ -121,33 +122,72 @@ def test_configmap_inlines_custom_conf_file(tmp_path):
     assert cm["data"]["volcano-scheduler.conf"] == conf.read_text()
 
 
-def test_compute_plane_sidecar_wiring():
-    manifests = render(DEFAULT_VALUES)
-    spec = _deployment(manifests, "scheduler")["spec"]["template"]["spec"]
-    sched, sidecar = spec["containers"]
-    socket = "/run/vtpu/compute-plane.sock"
-    # the scheduler points at the socket; sidecar serves it; both mount
-    # the shared emptyDir volume
-    assert {"name": "VTPU_COMPUTE_PLANE", "value": socket} in sched["env"]
-    assert sidecar["command"][:3] == ["vtpu-compute-plane", "--socket", socket]
-    assert "--warmup" in sidecar["command"]
-    assert sidecar["resources"]["limits"]["google.com/tpu"] == "8"
-    mounts = {v["name"] for v in spec["volumes"]}
-    assert "compute-plane-socket" in mounts
-    for c in (sched, sidecar):
-        assert any(m["name"] == "compute-plane-socket" for m in c["volumeMounts"])
+def _scheduler_pods(values):
+    return [m["spec"]["template"]["spec"] for _, m in render(values)
+            if m["kind"] == "Deployment"
+            and m["metadata"]["name"].startswith("volcano-tpu-scheduler")]
 
 
-def test_compute_plane_disabled():
-    values = merge_values(DEFAULT_VALUES, {"compute_plane": {"enabled": False}})
-    manifests = render(values)
-    spec = _deployment(manifests, "scheduler")["spec"]["template"]["spec"]
-    assert [c["name"] for c in spec["containers"]] == ["scheduler"]
-    assert "env" not in spec["containers"][0]
-    assert all(v["name"] != "compute-plane-socket" for v in spec["volumes"])
-    # in-process kernels still need the device: the TPU limit moves onto
-    # the scheduler container instead of vanishing with the sidecar
-    assert spec["containers"][0]["resources"]["limits"]["google.com/tpu"] == "8"
+@pytest.mark.parametrize("overrides", [
+    [],
+    ["scheduler.replicas=2"],
+    ["scheduler.shards=3"],
+], ids=["single", "replicas-2", "shards-3"])
+def test_scheduler_pod_holds_the_chip(overrides):
+    """Every scheduler pod is one container that runs the kernels in its
+    own process: it carries the TPU request and --warmup, and mounts
+    nothing but its policy ConfigMap."""
+    values = DEFAULT_VALUES
+    for assignment in overrides:
+        values = apply_set(values, assignment)
+    pods = _scheduler_pods(values)
+    assert len(pods) == (3 if "scheduler.shards=3" in overrides else 1)
+    for spec in pods:
+        (sched,) = spec["containers"]
+        assert sched["name"] == "scheduler"
+        assert sched["resources"] == {"limits": {"google.com/tpu": "8"}}
+        assert "--warmup" in sched["command"]
+        assert "env" not in sched
+        assert [v["name"] for v in spec["volumes"]] == ["scheduler-config"]
+        assert [m["name"] for m in sched["volumeMounts"]] == [
+            "scheduler-config"]
+
+
+def test_static_manifest_scheduler_holds_the_tpu_limit():
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..",
+                        "deploy", "kubernetes", "volcano-tpu.yaml")
+    with open(path, "r", encoding="utf-8") as fh:
+        docs = [d for d in yaml.safe_load_all(fh) if d]
+    dep = next(d for d in docs if d["kind"] == "Deployment"
+               and d["metadata"]["name"] == "volcano-tpu-scheduler")
+    (sched,) = dep["spec"]["template"]["spec"]["containers"]
+    assert sched["command"][0] == "vtpu-scheduler"
+    assert sched["resources"]["limits"] == {"google.com/tpu": "8"}
+    assert "--warmup" in sched["command"]
+
+
+def test_leftover_compute_plane_values_file_refused():
+    # an operator's old values file must not silently lose its chip
+    # count: the removed section is named in the error
+    with pytest.raises(ValueError, match="compute_plane"):
+        load_values("compute_plane:\n  tpu_chips: 4\n")
+
+
+def test_leftover_compute_plane_set_refused():
+    with pytest.raises(ValueError, match="compute_plane"):
+        apply_set(DEFAULT_VALUES, "compute_plane.tpu_chips=4")
+    # the CLI reports it as a one-line user error
+    import contextlib
+    import io
+
+    from volcano_tpu.cmd.package import main
+
+    err = io.StringIO()
+    with contextlib.redirect_stderr(err):
+        assert main(["template", "--set", "compute_plane.enabled=false"]) == 2
+    assert "compute_plane" in err.getvalue()
 
 
 def test_null_scalar_keeps_default():
@@ -188,7 +228,7 @@ def test_set_overrides_with_coercion():
     for assignment in ("scheduler.port=9090",
                        "bus.port=7777",
                        "prometheus.scrape=false",
-                       "compute_plane.tpu_chips=4",
+                       "scheduler.tpu_chips=4",
                        "basic.image_tag_version=nightly"):
         values = apply_set(values, assignment)
     assert values["scheduler"]["port"] == 9090
@@ -201,8 +241,7 @@ def test_set_overrides_with_coercion():
     assert sched["livenessProbe"]["httpGet"]["port"] == 9090
     cmd = sched["command"]
     assert cmd[cmd.index("--bus") + 1].endswith(":7777")
-    sidecar = _container(manifests, "scheduler", "compute-plane")
-    assert sidecar["resources"]["limits"]["google.com/tpu"] == "4"
+    assert sched["resources"]["limits"]["google.com/tpu"] == "4"
 
 
 def test_set_rejects_malformed():
@@ -261,10 +300,10 @@ def test_render_yaml_stream_parses():
 
 
 def test_empty_section_header_keeps_defaults():
-    # "compute_plane:" with nothing under it parses as null; the merge
+    # "scheduler:" with nothing under it parses as null; the merge
     # must keep the section's defaults, not crash render()
-    values = load_values("compute_plane:\nbasic:\n  release_name: x\n")
-    assert values["compute_plane"] == DEFAULT_VALUES["compute_plane"]
+    values = load_values("scheduler:\nbasic:\n  release_name: x\n")
+    assert values["scheduler"] == DEFAULT_VALUES["scheduler"]
     assert values["basic"]["release_name"] == "x"
     render(values)
 
@@ -312,8 +351,6 @@ def test_static_manifest_commands_parse():
                 known = {"--bus", "--listen-host", "--listen-port",
                          "--leader-elect", "--leader-elect-id",
                          "--gate-pods", "--enable-debug-stacks"}
-            elif binary == "vtpu-compute-plane":
-                continue
             else:
                 raise AssertionError(f"unexpected binary {binary}")
             flags = {a for a in c["command"][1:] if a.startswith("--")}
@@ -365,9 +402,11 @@ def test_rendered_scheduler_command_parses():
     parser.add_argument("--scheduler-conf", default="")
     parser.add_argument("--schedule-period", type=float, default=1.0)
     parser.add_argument("--micro-cycles", action="store_true")
+    parser.add_argument("--warmup", action="store_true")
     add_common_args(parser)
     args = parser.parse_args(cmd[1:])
     assert args.micro_cycles is True  # the deployed default is event-driven
+    assert args.warmup is True  # the kernels compile before cycle one
     assert args.bus == BUS_URL
     assert args.listen_host == "0.0.0.0"
     assert args.listen_port == 8080
@@ -398,10 +437,10 @@ class TestShardedFederationRendering:
                 == f"volcano-tpu-scheduler-{i}"
             )
             assert "--shard-lease-duration" in cmd
-            # every member still carries the compute-plane sidecar
+            # every member is one container holding its own chip
             names = [c["name"] for c in
                      dep["spec"]["template"]["spec"]["containers"]]
-            assert names == ["scheduler", "compute-plane"]
+            assert names == ["scheduler"]
 
     def test_shard_member_commands_parse(self):
         import argparse
@@ -414,6 +453,7 @@ class TestShardedFederationRendering:
         parser = argparse.ArgumentParser()
         parser.add_argument("--scheduler-conf", default="")
         parser.add_argument("--micro-cycles", action="store_true")
+        parser.add_argument("--warmup", action="store_true")
         parser.add_argument("--shards", type=int, default=0)
         parser.add_argument("--shard-identity", default="")
         parser.add_argument("--shard-lease-duration", type=float,

@@ -706,7 +706,7 @@ class TestBackfillReasons:
             close_session(ssn)
 
 
-# ---- executor / compute-plane plumbing ----
+# ---- dispatch plumbing ----
 
 
 class TestExplainPlumbing:
@@ -718,45 +718,24 @@ class TestExplainPlumbing:
         return snap
 
     def test_executor_counts_lazy(self):
-        from volcano_tpu.ops import executor
+        from volcano_tpu.ops import dispatch
         from volcano_tpu.ops.synthetic import generate_snapshot
 
-        executor.configure(None)
         placed = generate_snapshot(n_tasks=16, n_nodes=8, gang_size=4, seed=0)
-        executor.execute_allocate(placed, explain=True)
-        assert executor.last_explain_counts() is None  # everything placed
+        dispatch.run_allocate(placed, explain=True)
+        assert dispatch.last_explain_counts() is None  # everything placed
+        assert dispatch.last_explain_ms() is None
 
         snap = self._stuck_snapshot()
-        executor.execute_allocate(snap, explain=True)
-        counts = executor.last_explain_counts()
+        dispatch.run_allocate(snap, explain=True)
+        counts = dispatch.last_explain_counts()
         assert counts is not None and counts.shape == (snap.n_tasks, 5)
         assert (counts.sum(axis=1) == snap.n_nodes).all()
+        assert dispatch.last_explain_ms() >= 0.0
 
-    def test_compute_plane_returns_reason_counts(self, tmp_path):
-        from volcano_tpu.ops.explain import run_explain
-        from volcano_tpu.serving.compute_plane import (
-            ComputePlaneClient,
-            ComputePlaneServer,
-        )
-
-        path = str(tmp_path / "cp.sock")
-        server = ComputePlaneServer(path).start()
-        try:
-            client = ComputePlaneClient(path, timeout=60)
-            snap = self._stuck_snapshot()
-            assignment = client.allocate(snap, explain=True)
-            assert (assignment[: snap.n_tasks] < 0).all()
-            remote_counts = client.last_reason_counts
-            assert remote_counts is not None
-            unplaced = np.arange(snap.n_tasks)
-            local = run_explain(snap, task_rows=unplaced).counts
-            assert np.array_equal(remote_counts, local)
-
-            # without the flag the response carries no counts
-            client.allocate(snap, explain=False)
-            assert client.last_reason_counts is None
-        finally:
-            server.stop()
+        # without the flag nothing is reduced, and nothing stale is kept
+        dispatch.run_allocate(snap, explain=False)
+        assert dispatch.last_explain_counts() is None
 
     def test_task_row_subset_matches_full(self):
         from volcano_tpu.ops.explain import run_explain

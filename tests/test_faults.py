@@ -1,7 +1,7 @@
 """Fault plane + graceful degradation units: spec parser round-trip,
 deterministic firing streams, circuit-breaker state machine, cycle
-watchdog, executor degradation ladder, compute-plane session-loss
-recovery, /healthz degraded reporting, and the bounded resync queue's
+watchdog, executor degradation ladder with half-open re-promotion,
+/healthz degraded reporting, and the bounded resync queue's
 poison-task quarantine.  The multi-seam integration runs live in
 tests/test_chaos.py."""
 
@@ -35,9 +35,6 @@ def _clean_faults():
     faults.configure(None)
     faults.reset_breakers()
     faults.configure_deadline(None)
-    from volcano_tpu.ops import executor
-
-    executor.configure(None)
 
 
 def _counter(name, **labels):
@@ -51,12 +48,12 @@ def _counter(name, **labels):
 class TestFaultSpec:
     def test_round_trip(self):
         spec = faults.parse_faults(
-            "seed=42;bus.disconnect=0.05;compute.crash=0.1:count=2;"
+            "seed=42;bus.disconnect=0.05;device.nan=0.1:count=2;"
             "device.slow=1:ms=50:after=3"
         )
         assert spec.seed == 42
         assert spec.rules["bus.disconnect"].probability == 0.05
-        assert spec.rules["compute.crash"].count == 2
+        assert spec.rules["device.nan"].count == 2
         assert spec.rules["device.slow"].ms == 50.0
         assert spec.rules["device.slow"].after == 3
         assert faults.parse_faults(spec.format()) == spec
@@ -233,6 +230,55 @@ def _small_snapshot():
     return _tiny_snapshot()
 
 
+def _small_preempt():
+    from volcano_tpu.ops.synthetic import generate_preempt_packed
+
+    return generate_preempt_packed(n_victims=64, n_nodes=16, n_preemptors=16)
+
+
+class _Rung:
+    """One dispatch entry forced onto an upper rung over a tiny session:
+    ``run()`` dispatches, ``reference`` is the exact answer, ``breaker``
+    guards the rung and ``floor`` is the rung it degrades to.  The
+    preempt Pallas kernel is stood in for by the dense formulation —
+    these tests are about the ladder, not the kernel — and counts the
+    calls that reach it in ``calls``."""
+
+    def __init__(self, entry, rung, monkeypatch):
+        from volcano_tpu.ops import dispatch, preempt_pallas
+        from volcano_tpu.ops.kernels import run_packed
+        from volcano_tpu.ops.preempt_pack import preempt_dense
+
+        self.calls = 0
+        if entry == "allocate":
+            snap = _small_snapshot()
+            monkeypatch.setattr(
+                dispatch, "select_executor", lambda snap, weights=None: rung
+            )
+            self.run = lambda: dispatch.run_packed_auto(snap)
+            self.reference = run_packed(snap)
+            self.breaker = rung
+            self.floor = dispatch._FALLBACK[rung]
+            return
+        pk = _small_preempt()
+
+        def stand_in(pk, weights=None):
+            self.calls += 1
+            return preempt_dense(pk)
+
+        monkeypatch.setattr(dispatch, "select_preempt_executor", lambda pk: rung)
+        monkeypatch.setattr(preempt_pallas, "run_preempt_pallas", stand_in)
+        self.run = lambda: dispatch.run_preempt_auto(pk)
+        self.reference = preempt_dense(pk)
+        self.breaker = "preempt-pallas"
+        self.floor = "dense"
+
+    def fallbacks(self, cause):
+        return _counter("executor_fallbacks_total",
+                        **{"from": self.breaker, "to": self.floor,
+                           "cause": cause})
+
+
 class TestDispatchDegradation:
     def _force_pallas(self, monkeypatch):
         from volcano_tpu.ops import dispatch
@@ -241,45 +287,67 @@ class TestDispatchDegradation:
             dispatch, "select_executor", lambda snap, weights=None: "pallas"
         )
 
-    def test_injected_lowering_failure_degrades_exactly(self, monkeypatch):
+    @pytest.mark.parametrize("entry", ["allocate", "preempt"])
+    def test_injected_lowering_failure_degrades_exactly(self, entry,
+                                                        monkeypatch):
         from volcano_tpu.ops import dispatch
-        from volcano_tpu.ops.kernels import run_packed
 
-        snap = _small_snapshot()
-        reference = run_packed(snap)
-        self._force_pallas(monkeypatch)
+        rung = _Rung(entry, "pallas", monkeypatch)
         faults.configure("seed=1;device.lowering=1:count=1")
-        before = _counter("executor_fallbacks_total",
-                          **{"from": "pallas", "to": "blocked",
-                             "cause": "error"})
-        out = dispatch.run_packed_auto(snap)
-        np.testing.assert_array_equal(out, reference)
-        assert dispatch.last_executor() == "blocked"
-        assert _counter("executor_fallbacks_total",
-                        **{"from": "pallas", "to": "blocked",
-                           "cause": "error"}) == before + 1
-        assert faults.get_breaker("pallas").state == CLOSED  # 1 < threshold
+        before = rung.fallbacks("error")
+        np.testing.assert_equal(rung.run(), rung.reference)
+        if entry == "allocate":
+            assert dispatch.last_executor() == "blocked"
+        assert rung.fallbacks("error") == before + 1
+        # 1 < threshold
+        assert faults.get_breaker(rung.breaker).state == CLOSED
 
-    def test_breaker_trips_and_skips_the_broken_rung(self, monkeypatch):
-        from volcano_tpu.ops import dispatch
-
-        snap = _small_snapshot()
-        self._force_pallas(monkeypatch)
+    @pytest.mark.parametrize("entry", ["allocate", "preempt"])
+    def test_breaker_trips_and_skips_the_broken_rung(self, entry,
+                                                     monkeypatch):
+        rung = _Rung(entry, "pallas", monkeypatch)
         faults.configure("seed=1;device.lowering=1:count=3")
         for _ in range(3):
-            dispatch.run_packed_auto(snap)
-        assert faults.get_breaker("pallas").state == OPEN
+            rung.run()
+        assert faults.get_breaker(rung.breaker).state == OPEN
         # 4th call: the rung is skipped WITHOUT attempting (the
         # injection budget is exhausted, so an attempt would succeed —
         # the circuit-open fallback proves it was never tried)
-        before = _counter("executor_fallbacks_total",
-                          **{"from": "pallas", "to": "blocked",
-                             "cause": "circuit-open"})
-        dispatch.run_packed_auto(snap)
-        assert _counter("executor_fallbacks_total",
-                        **{"from": "pallas", "to": "blocked",
-                           "cause": "circuit-open"}) == before + 1
+        before = rung.fallbacks("circuit-open")
+        np.testing.assert_equal(rung.run(), rung.reference)
+        assert rung.fallbacks("circuit-open") == before + 1
+        assert rung.calls == 0
         assert faults.degraded_reasons()  # visible to /healthz
+
+    @pytest.mark.parametrize("entry, upper", [
+        ("allocate", "native"), ("preempt", "pallas"),
+    ])
+    def test_half_open_probe_repromotes_the_rung(self, entry, upper,
+                                                 monkeypatch):
+        """A tripped rung stays skipped through its cooldown, then one
+        half-open probe runs it again and, on success, closes the
+        breaker: the ladder recovers with no operator action."""
+        from volcano_tpu.ops import dispatch
+
+        rung = _Rung(entry, upper, monkeypatch)
+        # first fetch fixes the breaker's parameters: a short cooldown
+        br = faults.get_breaker(rung.breaker, failure_threshold=3,
+                                cooldown_s=0.05)
+        faults.configure("seed=1;device.lowering=1:count=3")
+        for _ in range(3):
+            np.testing.assert_equal(rung.run(), rung.reference)
+        assert br.state == OPEN
+        before = rung.fallbacks("circuit-open")
+        np.testing.assert_equal(rung.run(), rung.reference)
+        assert rung.fallbacks("circuit-open") == before + 1
+        time.sleep(0.06)
+        np.testing.assert_equal(rung.run(), rung.reference)
+        assert br.state == CLOSED
+        assert not faults.degraded_reasons()
+        if entry == "allocate":
+            assert dispatch.last_executor() == upper
+        else:
+            assert rung.calls == 1
 
     def test_corrupt_output_caught_by_validity_gate(self, monkeypatch):
         from volcano_tpu.ops import dispatch, pallas_session
@@ -361,119 +429,6 @@ class TestDispatchDegradation:
         out = dispatch.run_packed_auto(snap)
         assert time.monotonic() - t0 >= 0.12
         np.testing.assert_array_equal(out, baseline)
-
-
-# ---- compute-plane session loss + recovery ----
-
-
-class TestComputePlaneRecovery:
-    @pytest.fixture()
-    def plane(self, tmp_path):
-        from volcano_tpu.ops import executor
-        from volcano_tpu.serving.compute_plane import ComputePlaneServer
-
-        path = str(tmp_path / "cp.sock")
-        server = ComputePlaneServer(path).start()
-        executor.configure(path)
-        yield server, path
-        server.stop()
-        executor.configure(None)
-
-    def test_sidecar_crash_falls_back_and_recovers(self, plane):
-        from volcano_tpu.ops import executor
-
-        server, path = plane
-        snap = _small_snapshot()
-        reference = executor.execute_allocate(snap)
-        assert executor._last_route == "remote"
-
-        # crash the sidecar for exactly one request
-        faults.configure("seed=1;compute.crash=1:count=1")
-        out = executor.execute_allocate(snap)
-        np.testing.assert_array_equal(out, reference)
-        assert executor._last_route == "local"
-        br = faults.get_breaker("compute-plane")
-        assert br.state == OPEN
-        assert faults.degraded_reasons()
-
-        # recovery: force the next-session probe window open and watch
-        # the route promote back (kill-the-sidecar recovers within one
-        # probe period — here collapsed for the test)
-        faults.configure(None)
-        remote = executor._get_remote()
-        remote.last_probe = 0.0
-        out = executor.execute_allocate(snap)
-        np.testing.assert_array_equal(out, reference)
-        assert executor._last_route == "remote"
-        assert br.state == CLOSED
-        assert not faults.degraded_reasons()
-
-    def test_corrupt_frame_and_timeout_degrade(self, plane):
-        from volcano_tpu.ops import executor
-
-        server, path = plane
-        snap = _small_snapshot()
-        reference = executor.execute_allocate(snap)
-        for spec in ("seed=1;compute.corrupt=1:count=1",
-                     "seed=1;compute.timeout=1:count=1"):
-            faults.configure(spec)
-            out = executor.execute_allocate(snap)
-            np.testing.assert_array_equal(out, reference)
-            assert executor._last_route == "local"
-            faults.configure(None)
-            executor._get_remote().last_probe = 0.0
-            out = executor.execute_allocate(snap)
-            assert executor._last_route == "remote"
-            np.testing.assert_array_equal(out, reference)
-
-    def test_session_loss_clears_acked_revisions(self, plane):
-        from volcano_tpu.ops import executor
-
-        server, path = plane
-        remote = executor._get_remote()
-        remote.client._acked["some-key"] = 7
-        remote.mark_unhealthy("test")
-        # a restarted sidecar shares no session state: the client must
-        # re-handshake with a full frame, not trust dead acks
-        assert remote.client._acked == {}
-
-    def test_stale_ack_after_close_is_discarded(self, plane):
-        """An allocate() abandoned by the watchdog may complete AFTER a
-        close() cleared the acks; its late write must not resurrect a
-        session the restarted sidecar does not hold."""
-        from volcano_tpu.ops import executor
-
-        client = executor._get_remote().client
-        gen = client._session_gen
-        client.close()
-        client._ack(gen, "k", 5)  # the abandoned worker's late write
-        assert client._acked == {}
-        client._ack(client._session_gen, "k", 5)  # a live round trip acks
-        assert client._acked == {"k": 5}
-
-    def test_forced_need_full_reseeds(self, plane):
-        """compute.need_full answers a delta frame with T_NEED_FULL; the
-        client transparently re-sends the full snapshot — same
-        assignment, session store re-seeded."""
-        from volcano_tpu.ops import executor
-        from volcano_tpu.ops.pack_cache import PackDelta
-
-        server, path = plane
-        snap = _small_snapshot()
-        snap.cache_key = "chaos-key"
-        snap.rev = 1
-        snap.delta = None
-        first = executor.execute_allocate(snap)
-        assert executor._last_route == "remote"
-        # second session: a delta frame against rev 1
-        snap2 = _small_snapshot()
-        snap2.cache_key = "chaos-key"
-        snap2.rev = 2
-        snap2.delta = PackDelta(base_rev=1, planes={})
-        faults.configure("seed=1;compute.need_full=1:count=1")
-        out = executor.execute_allocate(snap2)
-        np.testing.assert_array_equal(out, first)
-        assert executor._last_route == "remote"
 
 
 # ---- /healthz degraded ----

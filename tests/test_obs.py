@@ -644,8 +644,7 @@ class TestTelemetryUnderFaults:
                        "cache.bind_fail=0.1:count=3"),
             ("clean", None),
         ):
-            cluster = ChaosCluster(tmp_path, f"obs-{label}",
-                                   compute_plane=False)
+            cluster = ChaosCluster(tmp_path, f"obs-{label}")
             try:
                 # the recorder rides the REMOTE client — exactly the
                 # path the bus faults hit

@@ -1067,8 +1067,7 @@ class TestChaosTwinWithTail:
                        "cache.bind_fail=0.1:count=3"),
             ("clean", None),
         ):
-            cluster = ChaosCluster(tmp_path, f"tail-{label}",
-                                   compute_plane=False)
+            cluster = ChaosCluster(tmp_path, f"tail-{label}")
             try:
                 obs.enable(cluster.remote, identity=f"sched-{label}",
                            flush_interval=0.05, sample=0.05, tail=True)

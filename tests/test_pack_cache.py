@@ -266,8 +266,7 @@ def test_pack_cache_warm_reuses_rows_after_bind_churn():
 
 def test_delta_reconstructs_snapshot():
     """prev snapshot + PackDelta rows == new snapshot, plane by plane —
-    the contract the device stager and the sidecar delta frames rely
-    on."""
+    the contract the device stager relies on."""
     rng = np.random.RandomState(11)
     cache = make_cache(**_base_cluster(rng))
     pc = PackCache(cache)
@@ -453,6 +452,49 @@ def test_kernels_identical_with_staged_planes():
     np.testing.assert_array_equal(run_packed_blocked(warm), plain_blocked)
 
 
+def test_device_stage_failure_falls_back_to_numpy_planes(monkeypatch):
+    """A device stager that fails mid-cycle leaves jax-allocate on the
+    numpy planes: the demotion counts as device-stage → host, and the
+    binds equal those of a cycle whose staging worked."""
+    from volcano_tpu.metrics import metrics
+    from volcano_tpu.ops import device_stage
+
+    key = ("volcano_executor_fallbacks_total",
+           (("cause", "error"), ("from", "device-stage"), ("to", "host")))
+    real = device_stage.get_stager
+    staged = []
+
+    class Broken:
+        def __init__(self, cache_key):
+            self.inner = real(cache_key)
+
+        def prestage(self, *args):
+            return self.inner.prestage(*args)
+
+        def stage(self, snap):
+            staged.append(snap.cache_key)
+            raise RuntimeError("injected staging failure")
+
+    def binds():
+        rng = np.random.RandomState(21)
+        cache = make_cache(**_base_cluster(rng, n_jobs=6, gang=3, n_nodes=8))
+        ssn = open_session(cache, STANDARD(), [])
+        try:
+            assert ssn.pack_epoch is not None
+            JaxAllocateAction().execute(ssn)
+        finally:
+            close_session(ssn)
+        return dict(cache.binder.binds)
+
+    want = binds()
+    before = metrics.registry._counters.get(key, 0)
+    monkeypatch.setattr(device_stage, "get_stager", Broken)
+    got = binds()
+    assert staged  # the stager was reached, and failed
+    assert metrics.registry._counters.get(key, 0) == before + 1
+    assert got and got == want
+
+
 def test_new_label_pair_back_patches_clean_nodes():
     """A dirty task registering a NEW selector pair must set the bit on
     every CLEAN node carrying that label — the cold pack's task-pass →
@@ -480,7 +522,7 @@ def test_new_label_pair_back_patches_clean_nodes():
     assert ssd_rows and all(
         warm.node_label_bits[i, word] & bit for i in ssd_rows
     )
-    # and the patch is visible in the delta so device/sidecar copies heal
+    # and the patch is visible in the delta so device copies heal
     assert warm.delta is not None
     rows = warm.delta.planes.get("node_label_bits")
     assert rows is None or set(ssd_rows) <= set(rows.tolist())

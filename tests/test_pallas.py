@@ -102,7 +102,7 @@ def test_make_session_dispatch_prestaged_matches_wrapper():
 
 
 def test_warmup_kernels_runs_auto_path():
-    # the --warmup flag on vtpu-scheduler / vtpu-compute-plane: compiles
+    # the --warmup flag on vtpu-scheduler: compiles
     # whatever executor auto-dispatch selects for the bucket, and returns
     # its name
     from volcano_tpu.ops.dispatch import select_executor, warmup_kernels
@@ -111,3 +111,26 @@ def test_warmup_kernels_runs_auto_path():
     executor = warmup_kernels(n_tasks=256, n_nodes=64, gang_size=4)
     snap = generate_snapshot(n_tasks=256, n_nodes=64, gang_size=4)
     assert executor == select_executor(snap)
+
+
+def test_scheduler_warmup_flag_compiles_in_process(monkeypatch):
+    """vtpu-scheduler --warmup compiles the kernels in its own process
+    before the daemon starts; without the flag nothing compiles."""
+    from volcano_tpu.cmd import scheduler as scheduler_cmd
+    from volcano_tpu.ops import dispatch
+    from volcano_tpu.scheduler import util as sched_util
+
+    calls = []
+    monkeypatch.setattr(dispatch, "warmup_kernels",
+                        lambda: calls.append("warmup"))
+    monkeypatch.setattr(scheduler_cmd, "SchedulerDaemon",
+                        lambda *a, **kw: calls.append("daemon"))
+    monkeypatch.setattr(scheduler_cmd, "serve_forever", lambda daemon: 0)
+    # main() installs the subsampling options process-wide
+    monkeypatch.setattr(sched_util, "server_opts", sched_util.server_opts)
+
+    assert scheduler_cmd.main(["--warmup"]) == 0
+    assert calls == ["warmup", "daemon"]
+    calls.clear()
+    assert scheduler_cmd.main([]) == 0
+    assert calls == ["daemon"]

@@ -577,27 +577,6 @@ def test_drf_critical_victims_participate_in_subtraction():
     assert dense_pipe == host_pipe
 
 
-def test_drf_preempt_wire_roundtrip():
-    """DRF sessions crossing the compute-plane boundary must carry their
-    filter flags and share state."""
-    from volcano_tpu.ops.preempt_pack import preempt_dense
-    from volcano_tpu.serving.compute_plane import (
-        deserialize_preempt,
-        serialize_preempt,
-    )
-
-    cache = _case_drf_imbalance(seed=3)
-    ssn = open_session(cache, DRF_TIERS, [])
-    pk = pack_preempt_session(ssn)
-    close_session(ssn)
-    back = deserialize_preempt(serialize_preempt(pk))
-    assert back.use_drf and not back.use_prio
-    ev_a, pipe_a = preempt_dense(pk)
-    ev_b, pipe_b = preempt_dense(back)
-    np.testing.assert_array_equal(ev_a, ev_b)
-    np.testing.assert_array_equal(pipe_a, pipe_b)
-
-
 def test_make_preempt_dispatch_prestaged_matches_wrapper():
     # bench compute probe path: prestaged dispatch ≡ run_preempt_pallas
     import numpy as np

@@ -207,10 +207,40 @@ def test_crashed_open_session_cycle_is_journaled(tmp_path, monkeypatch):
     assert Journal(str(tmp_path)).cycles() == [0]
 
 
-def test_snapshot_npz_roundtrip(tmp_path):
-    snap = generate_snapshot(n_tasks=64, n_nodes=16, gang_size=4, seed=3)
+def _spread_snapshot():
+    """A packed session carrying hard topology spread planes."""
+    from tests.test_topology_spread import TIERS, cluster
+    from volcano_tpu.actions.jax_allocate import compute_task_order
+    from volcano_tpu.framework import close_session, open_session
+    from volcano_tpu.ops.packing import pack_session
+
+    ssn = open_session(make_cache(**cluster(1)), TIERS(), [])
+    try:
+        order = compute_task_order(ssn)
+        nodes = [ssn.nodes[n] for n in sorted(ssn.nodes)]
+        jobs = list({t.job: ssn.jobs[t.job] for t in order}.values())
+        snap = pack_session(order, jobs, nodes)
+    finally:
+        close_session(ssn)
+    assert snap.has_spread
+    return snap
+
+
+@pytest.mark.parametrize("kind", ["plain", "spread"])
+def test_snapshot_npz_roundtrip(tmp_path, kind):
+    """The journal is the one serialized form of a PackedSnapshot: every
+    array, the spread planes included, comes back exactly and replays
+    to the same assignment."""
+    from volcano_tpu.ops.kernels import run_packed
+    from volcano_tpu.ops.packing import _SNAPSHOT_ARRAYS
+
+    if kind == "plain":
+        snap = generate_snapshot(n_tasks=64, n_nodes=16, gang_size=4, seed=3)
+    else:
+        snap = _spread_snapshot()
     path = str(tmp_path / "snap.npz")
-    save_snapshot(snap, path, assignment=np.arange(64, dtype=np.int32))
+    assignment = np.arange(snap.n_tasks, dtype=np.int32)
+    save_snapshot(snap, path, assignment=assignment)
     loaded, extras = load_snapshot(path)
 
     assert loaded.n_tasks == snap.n_tasks
@@ -220,10 +250,15 @@ def test_snapshot_npz_roundtrip(tmp_path):
     assert loaded.task_uids == snap.task_uids
     assert loaded.node_names == snap.node_names
     assert loaded.memory_exact == snap.memory_exact
-    np.testing.assert_array_equal(loaded.task_resreq, snap.task_resreq)
-    np.testing.assert_array_equal(loaded.node_idle, snap.node_idle)
-    np.testing.assert_array_equal(loaded.job_min_available, snap.job_min_available)
-    np.testing.assert_array_equal(extras["assignment"], np.arange(64))
+    for name in _SNAPSHOT_ARRAYS:
+        want, got = getattr(snap, name), getattr(loaded, name)
+        if want is None:
+            assert got is None, name
+        else:
+            np.testing.assert_array_equal(got, want, err_msg=name)
+    assert loaded.has_spread == snap.has_spread
+    np.testing.assert_array_equal(extras["assignment"], assignment)
+    np.testing.assert_array_equal(run_packed(loaded), run_packed(snap))
 
 
 # ---- replay ----

@@ -53,7 +53,6 @@ from volcano_tpu.api import FitError, TaskInfo, TaskStatus
 from volcano_tpu.framework.interface import Action
 from volcano_tpu.framework.session import Session
 from volcano_tpu.metrics import metrics
-from volcano_tpu.ops.executor import owns_device
 from volcano_tpu.utils.logging import get_logger
 
 log = get_logger(__name__)
@@ -62,7 +61,7 @@ log = get_logger(__name__)
 class _ExplainContext:
     """Device-derived unschedulability explanations for one session.
 
-    Built from the reason-count matrix the executor returned alongside
+    Built from the reason-count matrix the dispatch returned alongside
     the assignment (ops/explain).  ``try_explain`` replaces the host
     fallback's O(N) predicate sweep for a task the device PROVED fits no
     node — synthesizing the reference-identical FitErrors from the
@@ -315,7 +314,7 @@ class JaxAllocateAction(Action):
         to the host chooser.  Relational predicates the packer could not
         encode (needs_host_validation) are safe regardless: phase 3
         validates every proposal against the full host predicate set."""
-        from volcano_tpu.ops.executor import execute_allocate
+        from volcano_tpu.ops import dispatch
         from volcano_tpu.ops.packing import pack_session
 
         jobs = {}
@@ -365,7 +364,7 @@ class JaxAllocateAction(Action):
                 snap.spread_stats["spread_rows_host"],
             )
 
-        if snap.cache_key is not None and owns_device():
+        if snap.cache_key is not None:
             # attach the device-resident mirror: only dirty rows travel
             try:
                 from volcano_tpu.ops.device_stage import get_stager
@@ -377,19 +376,16 @@ class JaxAllocateAction(Action):
                 metrics.register_executor_fallback("device-stage", "host", "error")
                 log.error("device staging failed (%s); numpy planes", e)
 
-        # executor indirection: in-process kernels, or the compute-plane
-        # sidecar when VTPU_COMPUTE_PLANE is configured (with automatic
-        # in-process fallback when the sidecar is down).  explain=True
-        # makes the executor return the reason-count matrix alongside
-        # the assignment when tasks went unplaced (lazy — a fully-placed
-        # session computes nothing extra).
+        # explain=True makes the dispatch reduce the reason-count matrix
+        # alongside the assignment when tasks went unplaced (lazy — a
+        # fully-placed session computes nothing extra).
         from volcano_tpu.faults.watchdog import CycleDeadlineExceeded
 
         try:
             with trace.span(
                 "vtpu/allocate.dispatch", "kernel", label="kernel:execute"
             ) as sp:
-                assignment = execute_allocate(
+                assignment = dispatch.run_allocate(
                     snap, weights=self.weights, gang_rounds=self.gang_rounds,
                     explain=self.explain,
                 )
@@ -420,15 +416,13 @@ class JaxAllocateAction(Action):
             # assignment + the kernel parameters, the replayable tuple
             # trace.replay.verify diffs.  The label is the executor that
             # actually produced the assignment (including mid-session
-            # degradations; 'auto' when the compute-plane sidecar ran
-            # it), translated to replay vocabulary.
-            from volcano_tpu.ops.executor import last_allocate_executor
+            # degradations), translated to replay vocabulary.
             from volcano_tpu.trace.replay import replay_executor_name
 
             rec.capture(
                 snap,
                 assignment,
-                executor=replay_executor_name(last_allocate_executor()),
+                executor=replay_executor_name(dispatch.last_executor()),
                 weights=self.weights,
                 gang_rounds=self.gang_rounds,
             )
@@ -452,7 +446,7 @@ class JaxAllocateAction(Action):
         # transfer runs concurrently with the pure-host ORDER replay and
         # the remaining relay is only the (delta-sized) task planes.
         prestaged = False
-        if pc is not None and nodes and owns_device():
+        if pc is not None and nodes:
             with trace.span("vtpu/allocate.prestage", "kernel") as sp:
                 pending = pc.begin_nodes(
                     nodes, epoch, "predicates" in ssn.predicate_fns
@@ -485,19 +479,18 @@ class JaxAllocateAction(Action):
             return
         proposals, snap = self._kernel_proposals(ssn, ordered, nodes, pc)
 
-        # Reason counts the executor produced for unplaced tasks — the
+        # Reason counts the dispatch produced for unplaced tasks — the
         # device-derived "why pending" source (ops/explain).
         explain_ctx = None
         if self.explain and snap is not None:
-            from volcano_tpu.ops import executor as _executor
+            from volcano_tpu.ops import dispatch
             from volcano_tpu.ops import explain as _explain
 
-            counts = _executor.last_explain_counts()
+            counts = dispatch.last_explain_counts()
             if counts is not None:
                 planes = None
                 if self.explain_planes:
-                    # node-level attribution for the /explain surface;
-                    # recomputed locally (the wire ships counts only)
+                    # node-level attribution for the /explain surface,
                     # over the rows that recorded any infeasibility
                     import numpy as _np
 
@@ -508,11 +501,7 @@ class JaxAllocateAction(Action):
                 explain_ctx = _ExplainContext(
                     ssn, snap, counts, ordered, nodes, planes=planes
                 )
-                # None when the sidecar reduced the counts — its own
-                # metrics carry that cost; don't fabricate a local one
-                explain_ms = _executor.last_explain_ms()
-                if explain_ms is not None:
-                    last_phase_stats["explain_ms"] = explain_ms
+                last_phase_stats["explain_ms"] = dispatch.last_explain_ms()
 
         try:
             with trace.span("vtpu/allocate.apply", "action"):

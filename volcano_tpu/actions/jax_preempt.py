@@ -52,18 +52,9 @@ class JaxPreemptAction(Action):
         return "jax-preempt"
 
     def _device_outcome(self, pk) -> Tuple[np.ndarray, np.ndarray]:
-        """(evicted[V] bool, pipelined_node[P]) via the selected executor
-        — the compute-plane sidecar when configured — degrading pallas →
-        dense on runtime failure (the same native-path degradation
-        discipline run_packed_auto uses)."""
-        from volcano_tpu.ops.kernels import DEFAULT_WEIGHTS
-
-        if self.weights == DEFAULT_WEIGHTS:
-            # wire protocol carries no weights — only default-configured
-            # sessions may route through the sidecar
-            from volcano_tpu.ops.executor import execute_preempt
-
-            return execute_preempt(pk)
+        """(evicted[V] bool, pipelined_node[P]) via the selected executor,
+        degrading pallas → dense on runtime failure (the same native-path
+        degradation discipline run_packed_auto uses)."""
         from volcano_tpu.ops.dispatch import run_preempt_auto
 
         return run_preempt_auto(pk, weights=self.weights)

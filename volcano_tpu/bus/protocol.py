@@ -1,9 +1,8 @@
 """Bus wire protocol: framing, object serde, and error mapping.
 
-The out-of-process bus generalizes the compute-plane boundary
-(serving/compute_plane.py) from "ship one packed snapshot" to "be the
-API server": the same length-prefixed, versioned frame discipline, but
-carrying JSON-encoded API objects and watch streams over TCP.
+The out-of-process bus makes the API server a separate process: a
+length-prefixed, versioned frame discipline carrying JSON-encoded API
+objects and watch streams over TCP.
 
 Frame layout (little-endian):
 
@@ -14,9 +13,8 @@ The correlation id demultiplexes one duplex connection: for T_REQ /
 T_RESP / T_ERROR it is the client-assigned request id; for watch frames
 (T_WATCH_EVENT / T_BOOKMARK) it is the client-assigned watch id; for
 admission review frames (T_ADMIT_REQ / T_ADMIT_RESP) it is the
-server-assigned review id.  Payloads are pure JSON — like the compute
-plane, the wire is free of pickle so an untrusted peer cannot execute
-code.
+server-assigned review id.  Payloads are pure JSON — the wire is free
+of pickle so an untrusted peer cannot execute code.
 
 Objects cross the wire via the same dataclass round-trip the in-process
 store uses for ``clone()`` (apis/serde), so a remote create/get is

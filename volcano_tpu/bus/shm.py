@@ -1,6 +1,6 @@
 """Same-host shared-memory bus transport: mmap'd SPSC byte rings.
 
-When the scheduler, apiserver, and compute sidecar are co-resident (the
+When the scheduler, apiserver, and controllers are co-resident (the
 ``local_up --multiproc`` topology), every bus frame still pays the
 loopback TCP stack.  This module carries the IDENTICAL frames — same
 header, same negotiated codec, same byte stream — through a pair of

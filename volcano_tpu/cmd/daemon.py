@@ -211,7 +211,7 @@ def apply_faults(spec: str) -> None:
     a clean exit: a typo'd schedule must not run a different chaos
     plan).  An empty flag leaves VTPU_FAULTS env resolution intact.
     Lives here — not in cmd.scheduler — so store-only daemons
-    (vtpu-apiserver, vtpu-compute-plane) don't drag the scheduler
+    (vtpu-apiserver) don't drag the scheduler
     stack in for a flag helper."""
     if not spec:
         return

@@ -9,7 +9,6 @@ cmd/scheduler/app/options/options.go:44-66.
 from __future__ import annotations
 
 import argparse
-import os
 
 from volcano_tpu.cache import SchedulerCache
 from volcano_tpu.client import APIServer, SchedulerClient
@@ -160,7 +159,7 @@ def add_common_args(parser: argparse.ArgumentParser) -> None:
     parser.add_argument(
         "--faults", default="",
         help="deterministic fault-injection schedule, e.g. "
-        "'seed=42;bus.disconnect=0.05;compute.crash=0.1:count=2' "
+        "'seed=42;bus.disconnect=0.05;device.lowering=0.1:count=2' "
         "(volcano_tpu.faults; same grammar as VTPU_FAULTS — chaos "
         "testing only, never set in production)",
     )
@@ -198,18 +197,6 @@ def resolve_bus(bus: str):
         return connect_bus(bus)
     except BusError as e:
         raise SystemExit(str(e)) from e
-
-
-def pin_host_behind_sidecar() -> None:
-    """On a chip host exactly one process holds the chip.  Behind a
-    compute-plane sidecar that process is the sidecar, so this
-    scheduler runs JAX on the host: its in-process fallback (sidecar
-    down or restarting) must not start the TPU backend and contend for
-    the sidecar's chip.  Call before anything touches JAX."""
-    if os.environ.get("VTPU_COMPUTE_PLANE"):
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
 
 
 def main(argv=None) -> int:
@@ -335,8 +322,7 @@ def main(argv=None) -> int:
     parser.add_argument(
         "--warmup", action="store_true",
         help="compile the headline-bucket session kernels before the "
-        "first cycle (first compile is ~20-40s on TPU; same flag as "
-        "vtpu-compute-plane)",
+        "first cycle (first compile is ~20-40s on TPU)",
     )
     parser.add_argument(
         "--cycle-deadline-ms", type=float, default=0,
@@ -366,7 +352,6 @@ def main(argv=None) -> int:
     )
     add_common_args(parser)
     args = parser.parse_args(argv)
-    pin_host_behind_sidecar()
     apply_faults(args.faults)
     configure_compile_cache()
 
@@ -379,20 +364,9 @@ def main(argv=None) -> int:
     )
 
     if args.warmup:
-        if os.environ.get("VTPU_COMPUTE_PLANE"):
-            # kernels run in the sidecar (which has its own --warmup);
-            # the in-process copies only serve the failure fallback —
-            # don't block startup compiling them
-            from volcano_tpu.utils.logging import get_logger
+        from volcano_tpu.ops.dispatch import warmup_kernels
 
-            get_logger(__name__).info(
-                "skipping local warmup: VTPU_COMPUTE_PLANE is set "
-                "(warm the sidecar with its own --warmup)"
-            )
-        else:
-            from volcano_tpu.ops.dispatch import warmup_kernels
-
-            warmup_kernels()  # times and logs itself
+        warmup_kernels()  # times and logs itself
 
     def _autoscale_policy(a):
         if a.shard_autoscale != "on":

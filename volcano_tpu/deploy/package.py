@@ -22,8 +22,9 @@ the out-of-process bus in volcano_tpu/bus):
     THROUGH the bus, so a killed pod's standby takes over — real
     cross-pod HA (opt-in: every scheduler pod demands a TPU slice, so
     a standby needs spare accelerator capacity; see scheduler.replicas
-    below).  When ``compute_plane.enabled``, each scheduler pod carries
-    the kernel sidecar container sharing a socket volume.
+    below).  The scheduler container runs the device kernels in its
+    own process, so it carries the TPU request itself and compiles
+    them at start-up (``--warmup``).
   - ``<name>-controllers`` Deployment: two leader-elected replicas by
     default — controllers demand no accelerator, so HA is free.
   - ``<name>-admission`` Deployment: registers its webhooks over the
@@ -75,10 +76,14 @@ DEFAULT_VALUES: Dict[str, Any] = {
         # synthetic node pool the apiserver seeds (kubelet substitute)
         "nodes": 8,
         "port": 8080,
-        # every scheduler pod demands a full TPU slice (sidecar or
-        # in-process), so a standby replica needs SPARE accelerator
-        # capacity — on a single-slice cluster it would sit Pending and
-        # the kubelet's restart of a dead leader beats any takeover.
+        # the TPU request of the scheduler container, which runs the
+        # device kernels in its own process
+        "tpu_resource": "google.com/tpu",
+        "tpu_chips": 8,
+        # every scheduler pod demands a full TPU slice, so a standby
+        # replica needs SPARE accelerator capacity — on a single-slice
+        # cluster it would sit Pending and the kubelet's restart of a
+        # dead leader beats any takeover.
         # Default to 1; set 2 (adds --leader-elect) where slices exist.
         "replicas": 1,
         # event-driven scheduling (adds --micro-cycles): wake on watch
@@ -118,13 +123,6 @@ DEFAULT_VALUES: Dict[str, Any] = {
     "admission": {
         "port": 8082,
     },
-    "compute_plane": {
-        "enabled": True,
-        "socket_dir": "/run/vtpu",
-        "warmup": True,
-        "tpu_resource": "google.com/tpu",
-        "tpu_chips": 8,
-    },
     "prometheus": {
         "scrape": True,
     },
@@ -139,13 +137,29 @@ def merge_values(base: Dict[str, Any], override: Dict[str, Any]) -> Dict[str, An
         if isinstance(val, dict) and isinstance(out.get(key), dict):
             out[key] = merge_values(out[key], val)
         elif val is None and out.get(key) is not None:
-            # a bare section header ("compute_plane:") or blank scalar
+            # a bare section header ("scheduler:") or blank scalar
             # ("port:") parses as null — keep the default rather than
             # clobbering the value and crashing render() later
             continue
         else:
             out[key] = copy.deepcopy(val)
     return out
+
+
+#: values sections that no longer exist, and where their settings went.
+#: Refused rather than ignored: a dropped override (say a chip count)
+#: would otherwise render the default without a word.
+_REMOVED_SECTIONS = {
+    "compute_plane": "the kernels run in the scheduler container; set "
+    "scheduler.tpu_resource and scheduler.tpu_chips instead",
+}
+
+
+def _refuse_removed(section: str) -> None:
+    if section in _REMOVED_SECTIONS:
+        raise ValueError(
+            f"values section {section!r} was removed: "
+            f"{_REMOVED_SECTIONS[section]}")
 
 
 def _coerce(text: str) -> Any:
@@ -171,6 +185,7 @@ def apply_set(values: Dict[str, Any], assignment: str,
     keys = [k for k in path.split(".") if k]
     if not keys:
         raise ValueError(f"--set has empty key path: {assignment!r}")
+    _refuse_removed(keys[0])
     out = copy.deepcopy(values)
     node = out
     for k in keys[:-1]:
@@ -195,6 +210,8 @@ def load_values(text: str) -> Dict[str, Any]:
     raw = yaml.safe_load(text) or {}
     if not isinstance(raw, dict):
         raise ValueError("values file must be a YAML mapping")
+    for section in raw:
+        _refuse_removed(section)
     return merge_values(DEFAULT_VALUES, raw)
 
 
@@ -261,7 +278,6 @@ def render(values: Dict[str, Any]) -> List[Tuple[str, Dict[str, Any]]]:
     ns = basic["namespace"]
     image = f"{basic['image_name']}:{basic['image_tag_version']}"
     pull_secret = basic.get("image_pull_secret", "")
-    cp = values["compute_plane"]
     bus_port = int(values["bus"]["port"])
     api_port = int(values["apiserver"]["port"])
     sched_port = int(values["scheduler"]["port"])
@@ -383,9 +399,12 @@ def render(values: Dict[str, Any]) -> List[Tuple[str, Dict[str, Any]]]:
         manifests.append(("20-apiserver-deployment.yaml", dep))
         manifests.append(("21-apiserver-service.yaml", svc))
 
-    # ---- scheduler: leader-elected replicas + compute-plane sidecar,
-    # or N shard-pinned federation members when scheduler.shards > 1 ----
+    # ---- scheduler: leader-elected replicas, or N shard-pinned
+    # federation members when scheduler.shards > 1; each pod one
+    # container holding the chip ----
     sched_replicas = int(values["scheduler"].get("replicas", 1))
+    tpu_resource = values["scheduler"]["tpu_resource"]
+    tpu_chips = values["scheduler"]["tpu_chips"]
     shards = int(values["scheduler"].get("shards", 0) or 0)
 
     def scheduler_manifest(fname: str, deploy_name: str,
@@ -397,6 +416,7 @@ def render(values: Dict[str, Any]) -> List[Tuple[str, Dict[str, Any]]]:
             "--listen-host", "0.0.0.0",
             "--listen-port", str(sched_port),
             "--scheduler-conf", "/etc/volcano-tpu/volcano-scheduler.conf",
+            "--warmup",
         ]
         if values["scheduler"].get("micro_cycles"):
             sched_cmd.append("--micro-cycles")
@@ -413,45 +433,15 @@ def render(values: Dict[str, Any]) -> List[Tuple[str, Dict[str, Any]]]:
             ],
             "livenessProbe": _probe(sched_port),
             "ports": [{"containerPort": sched_port, "name": "metrics"}],
+            "resources": {
+                "limits": {tpu_resource: str(tpu_chips)},
+            },
         }
-        sched_containers = [scheduler]
-        sched_volumes: List[Dict[str, Any]] = [
-            {"name": "scheduler-config",
-             "configMap": {"name": f"{name}-scheduler-configmap"}},
-        ]
-        if cp["enabled"]:
-            socket = f"{cp['socket_dir']}/compute-plane.sock"
-            scheduler["env"] = [
-                {"name": "VTPU_COMPUTE_PLANE", "value": socket}]
-            scheduler["volumeMounts"].append(
-                {"name": "compute-plane-socket",
-                 "mountPath": cp["socket_dir"]})
-            sidecar_cmd = ["vtpu-compute-plane", "--socket", socket]
-            if cp["warmup"]:
-                sidecar_cmd.append("--warmup")
-            sched_containers.append({
-                "name": "compute-plane",
-                "image": image,
-                "command": sidecar_cmd,
-                "volumeMounts": [
-                    {"name": "compute-plane-socket",
-                     "mountPath": cp["socket_dir"]},
-                ],
-                "resources": {
-                    "limits": {cp["tpu_resource"]: str(cp["tpu_chips"])},
-                },
-            })
-            sched_volumes.append(
-                {"name": "compute-plane-socket", "emptyDir": {}})
-        else:
-            # in-process kernels: the scheduler itself owns the device,
-            # so the TPU limit moves onto it
-            scheduler["resources"] = {
-                "limits": {cp["tpu_resource"]: str(cp["tpu_chips"])},
-            }
         return (fname, _deployment(
             deploy_name, ns, {"app": deploy_name},
-            containers=sched_containers, volumes=sched_volumes,
+            containers=[scheduler],
+            volumes=[{"name": "scheduler-config",
+                      "configMap": {"name": f"{name}-scheduler-configmap"}}],
             # federation members are shard-pinned singletons: their HA
             # is the lease plane itself (survivors absorb an expired
             # member's slices), not a standby replica

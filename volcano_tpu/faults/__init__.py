@@ -9,7 +9,7 @@ Three pieces:
   streams, journaled firings, compiled out to a no-op by default.
 * :mod:`volcano_tpu.faults.breaker` — per-executor circuit breakers
   with cooldown and half-open re-probe, behind the degradation ladder
-  (pallas → blocked/sharded, native → xla-scan, sidecar → in-process).
+  (pallas → blocked/sharded, native → xla-scan, preempt pallas → dense).
 * :mod:`volcano_tpu.faults.watchdog` — the ``--cycle-deadline-ms``
   cycle watchdog bounding the device phase, with host-path completion.
 

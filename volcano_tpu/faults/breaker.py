@@ -2,7 +2,7 @@
 
 Before this module, every degradation in the tree was an isolated
 ``except`` that retried the broken path on the very next session — a
-sidecar that segfaults on a particular session shape, or a Pallas
+native executor that fails on a particular session shape, or a Pallas
 lowering that OOMs VMEM at the current bucket, got re-attempted (and
 re-failed, re-logged, re-paid its failure latency) every cycle forever.
 A breaker turns that into real machinery:

@@ -1,7 +1,7 @@
 """Deterministic, seedable fault-injection plane.
 
 Every recovery seam in the system carries a *named injection point*
-(``bus.disconnect``, ``compute.crash``, ``device.lowering``,
+(``bus.disconnect``, ``device.lowering``, ``device.nan``,
 ``cache.bind_fail``, ...).  A point is evaluated with
 ``plane.should(point)``; when the active :class:`FaultPlane` says it
 fires, the call site raises / drops / delays exactly the way the real
@@ -21,7 +21,7 @@ pre-fault-plane build).
 
 Spec grammar (``VTPU_FAULTS=<spec>`` / ``--faults <spec>``)::
 
-    seed=42;bus.disconnect=0.05;compute.crash=0.1:count=2;device.slow=1:ms=50:after=3
+    seed=42;bus.disconnect=0.05;device.lowering=0.1:count=2;device.slow=1:ms=50:after=3
 
 semicolon-separated clauses; ``seed=<int>`` seeds the streams (default
 0); every other clause is ``<point>=<probability>`` with optional
@@ -270,8 +270,7 @@ def configure(spec: Optional[str]) -> None:
 
 def get_plane():
     """The active plane (Null by default).  First call resolves
-    ``VTPU_FAULTS`` from the environment, like ops.executor's
-    VTPU_COMPUTE_PLANE discipline."""
+    ``VTPU_FAULTS`` from the environment."""
     global _plane
     if _plane is None:
         with _plane_lock:

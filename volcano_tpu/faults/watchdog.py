@@ -1,22 +1,18 @@
 """Cycle watchdog: a wall-clock budget for the device phase.
 
 ``--cycle-deadline-ms`` arms a per-cycle deadline: the scheduler stamps
-:func:`begin_cycle` at the top of ``run_once``, and the executor
-indirection (ops/executor.py) runs the device phase under the REMAINING
-budget via :func:`run_with_deadline`.  Overrun raises
+:func:`begin_cycle` at the top of ``run_once``, and jax-allocate's
+dispatch entry (ops/dispatch.run_allocate) runs the device phase under
+the REMAINING budget via :func:`run_with_deadline`.  Overrun raises
 :class:`CycleDeadlineExceeded`; jax-allocate catches it, abandons the
 device proposals, and completes the cycle on the host scoring path —
 the session is left consistent because the device phase is pure
 (packed arrays in, assignment out; nothing session-side mutates until
 APPLY).
 
-The overrunning computation itself cannot be interrupted (neither a
-blocked XLA execute nor a socket read is cancellable from Python); it
-is *abandoned* on a daemon worker thread and its result discarded.
-Remote-session state is kept consistent by the executor marking the
-sidecar route unhealthy, which closes the connection and drops the
-delta-session handshake (the next successful session re-handshakes with
-a full frame).
+The overrunning computation itself cannot be interrupted (a blocked
+XLA execute is not cancellable from Python); it is *abandoned* on a
+daemon worker thread and its result discarded.
 
 Disabled (the default) costs nothing: ``remaining_s`` returns None and
 ``run_with_deadline`` calls the function inline — no thread, no timer.

@@ -230,8 +230,7 @@ registry = _Registry()
 #: bounded role vocabulary for the identity label (MTR001 discipline)
 _IDENTITY_ROLES = (
     "scheduler", "controllers", "admission", "apiserver",
-    "compute-plane", "leader", "follower", "standalone", "init",
-    "removed",
+    "leader", "follower", "standalone", "init", "removed",
 )
 
 
@@ -617,12 +616,11 @@ def update_bus_server_watchers(count: int) -> None:
 # ---- fault plane + graceful degradation (volcano_tpu/faults) ----
 # volcano_executor_fallbacks_total is the demotion audit: every time an
 # executor path degrades to a lower rung (pallas→blocked, native→
-# xla-scan, remote→local, device→host) one count lands here with the
-# cause, so a silent permanent demotion is impossible.
+# xla-scan, preempt-pallas→dense, device→host) one count lands here
+# with the cause, so a silent permanent demotion is impossible.
 
 def register_executor_fallback(from_: str, to: str, cause: str) -> None:
-    """cause ∈ {error, circuit-open, deadline, corrupt-output,
-    unhealthy}."""
+    """cause ∈ {error, circuit-open, deadline, corrupt-output}."""
     # label-vocab: from, to — the executor rung names (ops/dispatch.py
     # degradation ladder), a static set
     registry.inc(

@@ -1,5 +1,8 @@
 """Kernel auto-dispatch: pick the fastest exact formulation for the
-session shape and backend.
+session shape and backend.  The one place the ``jax-*`` actions run
+their kernels: :func:`run_allocate` for jax-allocate and
+:func:`run_preempt_auto` for jax-preempt, both in this process, on the
+chip the scheduler holds.
 
 Three formulations share one semantics (identical bindings, proven by
 tests/test_blocked.py and tests/test_pallas.py):
@@ -198,11 +201,12 @@ def select_preempt_executor(pk) -> str:
 def run_preempt_auto(pk, weights: ScoreWeights = DEFAULT_WEIGHTS):
     """PreemptPacked → (evicted, pipelined), fastest exact path: pallas
     when eligible, degrading to the dense formulation on runtime
-    failure.  The single copy of the preempt dispatch — used in-process,
-    by the jax-preempt action, and by the compute-plane sidecar.  The
-    pallas rung sits behind a circuit breaker: repeated failures stop
-    re-attempting (and re-paying the failure latency) every cycle; a
-    half-open probe later promotes it back."""
+    failure.  The single copy of the preempt dispatch, and the
+    jax-preempt action's entry.  The cycle watchdog does not bound it:
+    preempt has no host-completion seam to hand an abandoned device
+    pass to.  The pallas rung sits behind a circuit breaker: repeated
+    failures stop re-attempting (and re-paying the failure latency)
+    every cycle; a half-open probe later promotes it back."""
     from volcano_tpu import faults, trace
     from volcano_tpu.metrics import metrics
     from volcano_tpu.ops.preempt_pack import preempt_dense
@@ -417,6 +421,60 @@ def run_packed_auto(
     )
 
 
+#: reason counts of the last run_allocate(explain=True) — [T, P] int32
+#: aligned with the snapshot's ordered tasks, or None when the session
+#: needed no explanation (everything placed) or explain was off — and
+#: the wall-clock ms of their reduction.  Same read-right-after-the-call
+#: discipline as _last_executor.
+_last_explain_counts = None
+_last_explain_ms = None
+
+
+def last_explain_counts():
+    return _last_explain_counts
+
+
+def last_explain_ms():
+    return _last_explain_ms
+
+
+def run_allocate(
+    snap: PackedSnapshot,
+    weights: ScoreWeights = DEFAULT_WEIGHTS,
+    gang_rounds: int = 3,
+    explain: bool = False,
+) -> np.ndarray:
+    """The jax-allocate action's entry: :func:`run_packed_auto` under
+    the cycle watchdog's remaining budget, then — with ``explain`` — the
+    reason-count reduction over the rows that went unplaced (read back
+    with :func:`last_explain_counts`).  Lazy: a fully placed session
+    computes nothing extra."""
+    from volcano_tpu.faults import watchdog
+
+    global _last_explain_counts, _last_explain_ms
+    # cleared up front: an aborted call (deadline, error) must not leave
+    # a previous session's counts readable as this session's
+    _last_explain_counts = None
+    _last_explain_ms = None
+    # run_packed_auto resolves through the module globals at call time,
+    # so a wrapper installed on the module attribute sees every dispatch
+    out = watchdog.run_with_deadline(
+        lambda: run_packed_auto(snap, weights=weights, gang_rounds=gang_rounds),
+        watchdog.remaining_s(),
+        "allocate",
+    )
+    if explain:
+        unplaced = np.nonzero(np.asarray(out)[: snap.n_tasks] < 0)[0]
+        if unplaced.size:
+            from volcano_tpu.ops import explain as _explain
+
+            _last_explain_counts = _explain.run_explain(
+                snap, task_rows=unplaced
+            ).counts
+            _last_explain_ms = _explain.last_run_ms
+    return out
+
+
 def warmup_kernels(n_tasks: int = 4096, n_nodes: int = 1024,
                    gang_size: int = 8, micro_shapes: bool = True) -> str:
     """Populate the jit cache for the session kernels at a
@@ -424,8 +482,7 @@ def warmup_kernels(n_tasks: int = 4096, n_nodes: int = 1024,
     same-bucket session after is cache-hit) and log the duration.
     Returns the executor auto-dispatch SELECTED — if the run degraded to
     a fallback mid-warmup, the dispatcher logged that error itself.
-    Shared by the compute-plane sidecar's and the scheduler daemon's
-    ``--warmup`` flags.
+    Behind the scheduler daemon's ``--warmup`` flag.
 
     ``micro_shapes`` additionally compiles the minimum task bucket at
     the same node count: event-driven micro-cycles score a handful of

@@ -133,9 +133,9 @@ def run_explain(
     "not proven infeasible", which sends consumers to the host sweep —
     conservative, never wrong).
 
-    Runs wherever the kernels run (scheduler process or compute-plane
-    sidecar) as a ``vtpu/allocate.explain`` span, whose duration feeds
-    the explain-overhead histogram there."""
+    Runs in the scheduler process, beside the kernels, as a
+    ``vtpu/allocate.explain`` span, whose duration feeds the
+    explain-overhead histogram."""
     from volcano_tpu import trace
     from volcano_tpu.metrics import metrics
 

@@ -16,7 +16,7 @@ the cache's event handlers dirtied:
     tasks) from dynamic (idle/used/task count/ok): a warm cycle
     re-packs only dirty nodes and ships only those rows
     (``PackedSnapshot.delta`` → device-side ``.at[idx].set`` scatter in
-    ops/device_stage.py, delta frames in serving/compute_plane.py).
+    ops/device_stage.py).
   * the bit registries are append-only and persistent, which makes the
     equivalence contract testable: a warm pack must be BIT-IDENTICAL to
     a cold ``pack_session`` seeded with the same registries

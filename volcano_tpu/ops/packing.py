@@ -151,9 +151,9 @@ class PackedSnapshot:
 
     # ---- warm-cycle metadata (volcano_tpu/ops/pack_cache.py) ----
     #: identity of the producing PackCache (None for cold one-shot packs);
-    #: device stagers and the compute-plane delta protocol key their
-    #: persistent buffers on it.  NOT serialized (journal/wire carry the
-    #: fully materialized arrays, so trace.replay.verify is delta-blind).
+    #: device stagers key their persistent buffers on it.  NOT serialized
+    #: (the journal carries the fully materialized arrays, so
+    #: trace.replay.verify is delta-blind).
     cache_key: Optional[str] = None
     #: monotonically increasing pack revision within the cache_key
     rev: int = 0

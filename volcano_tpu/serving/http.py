@@ -62,7 +62,7 @@ class _Handler(BaseHTTPRequestHandler):
                 return
             # degraded ≠ unhealthy: the daemon is alive and scheduling,
             # but a fallback rung is carrying the load (open circuit
-            # breaker, unreachable compute-plane sidecar).  200 so
+            # breaker on an executor rung).  200 so
             # liveness probes don't restart a working pod; the body
             # names the reason so operators and the chaos harness see
             # the demotion.
@@ -166,7 +166,7 @@ class _Handler(BaseHTTPRequestHandler):
 
 def _default_degraded() -> Optional[str]:
     """Default /healthz degraded source: every open circuit breaker in
-    the process (executor demotions, unreachable compute-plane)."""
+    the process (executor demotions)."""
     from volcano_tpu.faults.breaker import degraded_reasons
 
     reasons = degraded_reasons()

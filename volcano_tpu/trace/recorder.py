@@ -122,8 +122,8 @@ class TraceRecorder:
     enabled = True
 
     #: per-cycle event cap — bounds memory when events are emitted by a
-    #: process that never runs the scheduler loop (e.g. the compute-plane
-    #: sidecar dispatching kernels per request): without begin/end_cycle
+    #: process that never runs the scheduler loop (e.g. a harness
+    #: dispatching kernels directly): without begin/end_cycle
     #: the buffer would otherwise grow forever.  Excess events are
     #: dropped and counted in the cycle record's ``n_dropped``.
     max_events_per_cycle = 100_000

@@ -1,7 +1,7 @@
 """Where JAX keeps its persistent compile cache.
 
 One rule for every entry point that compiles kernels (``chip_smoke.py``,
-``bench.py``, ``vtpu-scheduler``, ``vtpu-compute-plane``):
+``bench.py``, ``vtpu-scheduler``):
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself, and nothing
   here sets anything.
