@@ -216,7 +216,7 @@ def test_cycle_spans_are_all_named_in_span_names(tmp_path):
     assert {
         "vtpu/cycle", "vtpu/cache.snapshot", "vtpu/session.open",
         "vtpu/plugin.gang.open", "vtpu/session.close", "vtpu/plugin.gang.close",
-        "vtpu/session.job_updater", "vtpu/action.jax-allocate",
+        "vtpu/session.job_updater", "vtpu/session.job_writeback", "vtpu/action.jax-allocate",
         "vtpu/action.backfill", "vtpu/allocate.order", "vtpu/allocate.pack",
         "vtpu/allocate.dispatch", "vtpu/allocate.apply",
         "vtpu/allocate.host_sweep", "vtpu/statement.commit", "vtpu/cache.flush",

@@ -1557,6 +1557,14 @@ class SchedulerCache(Cache):
             else:
                 self.unschedulable_digest.pop(job.uid, None)
 
+    @property
+    def status_writeback_io(self) -> bool:
+        """Whether :meth:`update_job_status` reaches a status writer,
+        whose calls may block on I/O (the API server, in the daemon).
+        Without one the writeback is inert, and the job updater keeps
+        it on its own thread."""
+        return self.status_updater is not None
+
     def update_job_status(self, job: JobInfo) -> Optional[scheduling.PodGroup]:
         """cache.go:871-894."""
         self.record_job_status_event(job)

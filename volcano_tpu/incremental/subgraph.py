@@ -104,6 +104,8 @@ class _ShadowCache:
     # could never reach a real writeback even if invoked
     update_job_status_async = None
     _commit_plane = None
+    #: its writeback below is inert, whatever writer the real cache has
+    status_writeback_io = False
     #: jax-allocate only consults the warm packer when the session
     #: carries a pack epoch (shadow sessions never do), but a plain None
     #: here also shadows the real cache's lazy pack_cache property

@@ -346,6 +346,13 @@ def register_schedule_attempt(result: str) -> None:
     registry.inc(f"{_NAMESPACE}_schedule_attempts_total", {"result": result})
 
 
+def register_job_status_writes(path: str, n: int) -> None:
+    """volcano_job_status_writes_total{path}: jobs whose status writeback
+    was due at session close, by where the writes went, path ∈ {inline,
+    pool, plane} (framework/job_updater)."""
+    registry.inc(f"{_NAMESPACE}_job_status_writes_total", {"path": path}, n)
+
+
 def update_pod_schedule_status(status: str, count: int = 1) -> None:
     """metrics.go pod_schedule_successes/errors: pods whose bind effect
     landed (or failed to land) on the bus.  status ∈ {successes,

@@ -112,6 +112,7 @@ SPAN_NAMES = (
     "vtpu/session.close",  # framework.close_session
     "vtpu/plugin.{plugin}.close",
     "vtpu/session.job_updater",  # JobUpdater.update_all in close_session
+    "vtpu/session.job_writeback",  # the due status writes, inside vtpu/session.job_updater
     "vtpu/action.{action}",  # each action of run_once
     "vtpu/allocate.prestage",  # jax-allocate: node planes staged before ORDER
     "vtpu/allocate.order",  # jax-allocate ORDER
