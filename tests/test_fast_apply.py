@@ -220,14 +220,12 @@ def test_fast_apply_refuses_pvc_pods():
     close_session(ssn)
 
 
-def test_fast_apply_refuses_preassigned_anti_affinity():
-    """A RUNNING pod with required anti-affinity makes the host symmetry
-    predicate load-bearing for every placement; the packer cannot see it
-    (needs_host_validation covers only packed pending tasks), so the
-    bulk path must refuse and the slow path must enforce the spread."""
+def _guarded_cluster(term_extra=None):
+    """Two nodes; a RUNNING pod on n0 whose required anti-affinity term
+    matches both pending pods."""
     anti = {"podAntiAffinity": {"requiredDuringSchedulingIgnoredDuringExecution": [
         {"labelSelector": {"matchLabels": {"app": "x"}},
-         "topologyKey": "kubernetes.io/hostname"}]}}
+         "topologyKey": "kubernetes.io/hostname", **(term_extra or {})}]}}
     nodes = [
         build_node(f"n{i}", {"cpu": "8", "memory": "16Gi"},
                    labels={"kubernetes.io/hostname": f"n{i}"})
@@ -243,11 +241,31 @@ def test_fast_apply_refuses_preassigned_anti_affinity():
     ]
     pgs = [build_pod_group("ns", "pgr", 1, queue="q"),
            build_pod_group("ns", "pg", 1, queue="q")]
-    cluster = dict(nodes=nodes, pods=pods, pod_groups=pgs, queues=[build_queue("q")])
+    return dict(nodes=nodes, pods=pods, pod_groups=pgs, queues=[build_queue("q")])
+
+
+def test_fast_apply_refuses_preassigned_anti_affinity():
+    """A RUNNING pod's required anti-affinity term that the pack cannot
+    encode (a namespace selector) sends the pending pods it matches to
+    the host, so the bulk path must refuse and the slow path must keep
+    them off the guard's node."""
+    cluster = _guarded_cluster({"namespaceSelector": {}})
     cache, ssn, engaged = _run(cluster, force_slow=False)
     assert not engaged
     binds = dict(cache.binder.binds)
     # slow path places the app=x pods only away from the guard's node
+    assert all(v != "n0" for k, v in binds.items() if k in ("ns/t0", "ns/t1"))
+    close_session(ssn)
+
+
+def test_fast_apply_commits_encoded_anti_affinity():
+    """The same guard with a term the pack encodes (a class of the
+    symmetry rule): the bulk path commits and the pods land away from
+    the guard's node."""
+    cache, ssn, engaged = _run(_guarded_cluster(), force_slow=False)
+    assert engaged
+    binds = dict(cache.binder.binds)
+    assert {"ns/t0", "ns/t1"} <= set(binds)
     assert all(v != "n0" for k, v in binds.items() if k in ("ns/t0", "ns/t1"))
     close_session(ssn)
 

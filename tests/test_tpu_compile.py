@@ -64,7 +64,7 @@ def one_chip(topo):
 
 
 def _compile_allocate(one_chip, T: int, C: int, NS: int, R: int = 2, J: int = 8192,
-                      K: int = 0):
+                      K: int = 0, absolute=()):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -78,6 +78,7 @@ def _compile_allocate(one_chip, T: int, C: int, NS: int, R: int = 2, J: int = 81
         spec((J,), jnp.int32),
         spec((J,), jnp.int32),
         spec((T,), jnp.bool_),
+        absolute=absolute,
     ).compile()
 
 
@@ -112,6 +113,15 @@ def test_allocate_kernel_with_spread_classes(one_chip, K):
     while kernel_vmem_bytes(1, (NS + 1) * LANES, 2, K=K) <= VMEM_BUDGET:
         NS += 1
     compiled = _compile_allocate(one_chip, 2048, 1, NS, K=K)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("absolute", [(True, True), (False, True, True)])
+def test_allocate_kernel_with_anti_affinity_classes(one_chip, absolute):
+    """Required anti-affinity: classes of the absolute kind (bound, no
+    minimum), alone and beside a spread class, at the 1,000 x 5,000
+    shape of scheduler_perf's SchedulingPodAntiAffinity."""
+    compiled = _compile_allocate(one_chip, 1024, 1, 40, K=len(absolute), absolute=absolute)
     assert "tpu_custom_call" in compiled.as_text()
 
 

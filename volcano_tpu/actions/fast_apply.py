@@ -178,12 +178,10 @@ def try_fast_apply(
     listers = _find_pod_listers(ssn)
     if listers is None:
         return False
-    # needs_host_validation only covers the packed (pending) tasks' own
-    # affinity specs — a PRE-ASSIGNED pod with required anti-affinity
-    # makes the host predicate's symmetry check load-bearing for every
-    # placement, which the kernel cannot see.  Refuse.
-    if any(pl.any_required_anti_affinity() for pl in listers):
-        return False
+    # A PRE-ASSIGNED pod's required anti-affinity binds every pending
+    # task it matches (the symmetry rule): the pack encodes each such
+    # term as a class, or marks the matching rows for the host, which
+    # sets needs_host_validation and refused above.
 
     nodes_by_name = ssn.nodes
     gang_ready = bool(ready_chain)
@@ -369,13 +367,15 @@ def try_fast_apply(
         tn = pl._task_nodes
         for t in bulk:
             tn[t.uid] = t.node_name
-        # anti-affinity sets: gate guarantees no pod (anti-)affinity terms
-        # (needs_host_validation would be set), so nothing to maintain.
-        # Topology spread counts, once a check built them, move as the
-        # per-task allocate events would have moved them.
+        # Topology spread and inter-pod affinity counts, once a check
+        # built them, move as the per-task allocate events would have
+        # moved them.
         if pl.spread.active:
             for t in bulk:
                 pl.spread.update(t, t.node_name)
+        if pl.affinity.tracking:
+            for t in bulk:
+                pl.affinity.update(t)
 
     import time
 

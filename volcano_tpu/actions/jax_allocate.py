@@ -252,10 +252,11 @@ def compute_task_order_replay(ssn: Session) -> List[TaskInfo]:
 
 def _spread_cut(snap) -> int:
     """Ordered rows whose proposals stand: all of them, or those before
-    the first row with a hard topology spread constraint the pack could
-    not encode.  The kernel placed that row with no mask, and the counts
-    it moved there steer every later proposal, so from it on each task
-    takes the host chooser — the host action's own choices."""
+    the first row with a hard topology spread constraint or a required
+    inter-pod term the pack could not encode.  The kernel placed that
+    row with no mask, and the counts it moved there steer every later
+    proposal, so from it on each task takes the host chooser — the host
+    action's own choices."""
     host = snap.task_spread_host
     if host is None or not host[: snap.n_tasks].any():
         return snap.n_tasks
@@ -362,6 +363,13 @@ class JaxAllocateAction(Action):
             metrics.register_spread_rows(
                 snap.spread_stats["spread_rows_device"],
                 snap.spread_stats["spread_rows_host"],
+            )
+        if snap.affinity_stats is not None:
+            # affinity_rows_device / _host, affinity_classes, affinity_pack_ms
+            last_phase_stats.update(snap.affinity_stats)
+            metrics.register_affinity_rows(
+                snap.affinity_stats["affinity_rows_device"],
+                snap.affinity_stats["affinity_rows_host"],
             )
 
         if snap.cache_key is not None:

@@ -1018,6 +1018,22 @@ def register_spread_rows(device: int, host: int) -> None:
             )
 
 
+def register_affinity_rows(device: int, host: int) -> None:
+    """volcano_allocate_affinity_rows_total{path}: pending tasks under a
+    required inter-pod term — one of their own, or a placed or pending
+    pod's anti-affinity term they match — that jax-allocate packed, by
+    where the terms are decided, path ∈ {device, host}: ``device`` when
+    the kernel encodes every such term of the row as an anti-affinity
+    class, ``host`` when the row keeps host validation or host scoring
+    (required pod affinity, a namespace selector, label keys, class
+    overflow, preferred terms)."""
+    for path, n in (("device", device), ("host", host)):
+        if n:
+            registry.inc(
+                f"{_NAMESPACE}_allocate_affinity_rows_total", {"path": path}, n
+            )
+
+
 def register_host_sweep(nodes: int) -> None:
     """volcano_allocate_host_sweep_nodes_total: nodes whose predicates
     one host sweep (actions/allocate.host_node_chooser) evaluated."""

@@ -24,8 +24,8 @@ Layers on top:
     endpoint and the trace journal's per-cycle reason summary.
   * full per-pair reason planes (node-level attribution, [T, N]) are
     retained only when asked (``retain_planes``) — the hot path ships
-    one [T, P] matrix back, P = 5, or 7 in a session with hard topology
-    spread constraints (their two reasons).
+    one [T, P] matrix back, P = 5, or 8 in a session with hard topology
+    spread constraints or required anti-affinity (their three reasons).
 """
 
 from __future__ import annotations
@@ -42,6 +42,7 @@ from volcano_tpu.api.unschedule_info import (
     NODE_SELECTOR_MISMATCH,
     NODE_TAINT_UNTOLERATED,
     NODE_UNSCHEDULABLE,
+    POD_AFFINITY_MISMATCH,
     SPREAD_CONSTRAINTS_MISMATCH,
     SPREAD_LABEL_MISSING,
 )
@@ -58,6 +59,7 @@ EXPLAIN_REASONS = (
     NODE_TAINT_UNTOLERATED,
     SPREAD_LABEL_MISSING,
     SPREAD_CONSTRAINTS_MISMATCH,
+    POD_AFFINITY_MISMATCH,
 )
 
 assert len(EXPLAIN_REASONS) == N_EXPLAIN_REASONS
@@ -171,7 +173,12 @@ def _reduce_reasons(
     import jax.numpy as jnp
 
     from volcano_tpu.ops.device_stage import device_plane as _dp
-    from volcano_tpu.ops.packing import _bucket, spread_node_planes, spread_task_columns
+    from volcano_tpu.ops.packing import (
+        _bucket,
+        class_absolute,
+        spread_node_planes,
+        spread_task_columns,
+    )
 
     padded = None
     if rows is not None:
@@ -190,6 +197,7 @@ def _reduce_reasons(
         spread = tuple(jnp.asarray(a) for a in (
             thr if padded is None else thr[padded],
             *spread_node_planes(snap, snap.node_idle.shape[0]),
+            class_absolute(snap),
         ))
 
     reasons, counts = explain_counts(
@@ -229,9 +237,9 @@ def _reduce_reasons(
 def task_exactly_encoded(snap: PackedSnapshot, i: int) -> bool:
     """May device counts for row ``i`` be trusted as the host truth?
     Requires the row's predicates to be bitset-exact (no rich affinity),
-    its hard topology spread constraints encoded, no registry overflow
-    (every row suspect then), and MiB-exact memory lanes (the fit plane
-    rounds otherwise)."""
+    its hard topology spread constraints and required inter-pod terms
+    encoded, no registry overflow (every row suspect then), and
+    MiB-exact memory lanes (the fit plane rounds otherwise)."""
     if getattr(snap, "registry_overflow", False) or not snap.memory_exact:
         return False
     spread_host = getattr(snap, "task_spread_host", None)

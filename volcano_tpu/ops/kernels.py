@@ -138,9 +138,12 @@ def predicate_mask(
 #: every node passed ready() at snapshot time (cache.snapshot skips
 #: unready nodes), so the packed ¬node_ok is exactly "unschedulable".
 #: Spread fails either on a node without the constraint's key or on
-#: the skew, in the order of the pod's constraints.
-N_EXPLAIN_REASONS = 7
-R_FIT, R_ROOM, R_UNSCHED, R_SEL, R_TOL, R_SPREAD_LABEL, R_SPREAD = range(N_EXPLAIN_REASONS)
+#: the skew, in the order of the pod's constraints; a class of the
+#: absolute kind (required pod anti-affinity) fails after every spread
+#: class, as the host's inter-pod filter runs after its spread filter.
+N_EXPLAIN_REASONS = 8
+(R_FIT, R_ROOM, R_UNSCHED, R_SEL, R_TOL, R_SPREAD_LABEL, R_SPREAD,
+ R_AFFINITY) = range(N_EXPLAIN_REASONS)
 
 
 @jax.jit
@@ -156,10 +159,10 @@ def explain_counts(
     node_max_tasks: jnp.ndarray,  # [N] i32
     tolerance: jnp.ndarray,  # [R]
     n_nodes: jnp.ndarray,  # i32 scalar — valid node rows (rest padding)
-    spread=None,  # (thr [T, K], dom, elig, count [K, N]) or None
+    spread=None,  # (thr [T, K], dom, elig, count [K, N], absolute [K]) or None
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(reason[T, N] i8, counts[T, P] i32), P = 5 without ``spread``
-    and 7 with it (the two spread reasons).
+    and 8 with it (the two spread reasons and the anti-affinity one).
 
     ``reason[t, n]`` is the index of the FIRST predicate the pair fails
     in host order, or ``N_EXPLAIN_REASONS`` when the node is feasible
@@ -190,14 +193,16 @@ def explain_counts(
     )
     planes = R_SPREAD_LABEL
     if spread is not None:
-        thr, dom, elig, count = spread
-        label_fail, skew_fail = spread_ok(dom, elig, count, thr)  # [T, K, N]
+        thr, dom, elig, count, absolute = spread
+        label_fail, skew_fail = spread_ok(dom, elig, count, thr, absolute)  # [T, K, N]
         first = jnp.full(reason.shape, feasible)
         for k in reversed(range(thr.shape[1])):
             first = jnp.where(
                 label_fail[:, k], jnp.int8(R_SPREAD_LABEL),
-                jnp.where(skew_fail[:, k], jnp.int8(R_SPREAD), first),
+                jnp.where(skew_fail[:, k] & ~absolute[k], jnp.int8(R_SPREAD), first),
             )
+        anti_fail = jnp.any(skew_fail & absolute[None, :, None], axis=1)
+        first = jnp.where((first == feasible) & anti_fail, jnp.int8(R_AFFINITY), first)
         reason = jnp.where(reason == feasible, first, reason)
         planes = N_EXPLAIN_REASONS
     valid = jnp.arange(reason.shape[1]) < n_nodes
@@ -327,18 +332,24 @@ class _ScanState(NamedTuple):
 SPREAD_BIG = float(2**30)
 
 
-def spread_ok(dom, elig, count, thr):
-    """Hard topology spread on every node — the kernels' form of
-    PodTopologySpread.Filter.  ``dom``, ``elig``, ``count`` are [K, N]
-    planes (ops.packing.spread_node_planes); ``thr`` is [..., K], the
-    row's maxSkew less its self-match, negative where the class does not
-    constrain it.  A node passes class k when it carries the key and its
+def spread_ok(dom, elig, count, thr, absolute):
+    """Hard topology spread and required anti-affinity on every node —
+    the kernels' form of PodTopologySpread.Filter and of
+    InterPodAffinity's anti-affinity rules.  ``dom``, ``elig``,
+    ``count`` are [K, N] planes (ops.packing.spread_node_planes);
+    ``thr`` is [..., K], negative where the class does not constrain the
+    row; ``absolute`` [K] bool marks the classes of the absolute kind.
+    A node passes a spread class when it carries the key and its
     domain's count less the minimum over eligible nodes is at most
-    ``thr``.  Returns ([..., K, N] per-class failures of the label,
-    of the skew), both False where the class does not constrain."""
+    ``thr`` (the row's maxSkew less its self-match); it passes an
+    absolute class when it lacks the key or its domain's count is at
+    most ``thr`` (the bound): the minimum is taken as 0.  Returns
+    ([..., K, N] per-class failures of the label, of the count), both
+    False where the class does not constrain."""
     mn = jnp.min(jnp.where(elig > 0.0, count, SPREAD_BIG), axis=-1, keepdims=True)
+    mn = jnp.where(absolute[:, None], 0.0, mn)
     on = (thr >= 0.0)[..., None]
-    label_fail = on & (dom < 0.0)
+    label_fail = on & (dom < 0.0) & ~absolute[:, None]
     skew_fail = on & (dom >= 0.0) & (count - mn > thr[..., None])
     return label_fail, skew_fail
 
@@ -402,9 +413,9 @@ def _assign_step(
     Mirrors the per-task body of allocate.go:177-230 with the
     resource-fit + plugin predicates folded into the mask and
     SelectBestNode's tie-break made deterministic (first index).
-    ``spread_nodes`` = (dom, elig) [K, N] planes when the session has
-    hard spread constraints; the task then carries its (thr, inc)
-    columns and the state its running domain counts."""
+    ``spread_nodes`` = (dom, elig [K, N], absolute [K]) when the
+    session has spread or anti-affinity classes; the task then carries
+    its (thr, inc) columns and the state its running domain counts."""
     resreq, sel_tol_row, job_idx, active = task[:4]
     used_ext, job_assigned, spread_count = state
 
@@ -414,8 +425,8 @@ def _assign_step(
     )
     if spread_nodes is not None:
         thr, inc = task[4:]
-        dom, elig = spread_nodes
-        label_fail, skew_fail = spread_ok(dom, elig, spread_count, thr)
+        dom, elig, absolute = spread_nodes
+        label_fail, skew_fail = spread_ok(dom, elig, spread_count, thr, absolute)
         passes = ~jnp.any(label_fail | skew_fail, axis=0)
         feasible = feasible & passes
         score = jnp.where(passes, score, -jnp.inf)
@@ -529,15 +540,15 @@ def schedule_pass(
     job_min_available: jnp.ndarray,
     tolerance: jnp.ndarray,
     active: jnp.ndarray,
-    spread=None,  # (thr [T, K], inc [T, K], dom, elig, count0 [K, N]) or None
+    spread=None,  # (thr, inc [T, K], dom, elig, count0 [K, N], absolute [K]) or None
     weights: ScoreWeights = DEFAULT_WEIGHTS,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One greedy pass → (chosen[T], job_assigned[J]).  The host loop in
     run_packed applies the gang commit/discard between passes — typical
     sessions converge after one pass instead of paying gang_rounds fixed
-    device rounds.  ``spread`` adds the hard topology spread mask, the
-    per-class domain counts carried through the scan (ops.packing's
-    spread_task_columns and spread_node_planes).
+    device rounds.  ``spread`` adds the hard topology spread and
+    anti-affinity mask, the per-class domain counts carried through the
+    scan (ops.packing's spread_task_columns and spread_node_planes).
 
     Static feasibility (labels/taints/readiness) is evaluated per distinct
     bitset signature class, not per task: the scan gathers a [N] row from
@@ -559,9 +570,9 @@ def schedule_pass(
     xs = (task_resreq, task_feas_class, task_job, active)
     spread_nodes = count0 = None
     if spread is not None:
-        thr, inc, dom, elig, count0 = spread
+        thr, inc, dom, elig, count0, absolute = spread
         xs = xs + (thr, inc)
-        spread_nodes = (dom, elig)
+        spread_nodes = (dom, elig, absolute)
 
     def step(state, task):
         resreq, feas_cls, job_idx, act = task[:4]
@@ -624,15 +635,19 @@ def _feasibility_classes(snap: PackedSnapshot):
 
 def spread_arrays(snap: PackedSnapshot, rows: int, width: int):
     """schedule_pass's ``spread`` operand for ``rows`` task rows and
-    ``width`` node columns, or None when the session has no hard spread
-    constraint."""
+    ``width`` node columns, or None when the session has no spread or
+    anti-affinity class."""
     if not snap.has_spread:
         return None
-    from volcano_tpu.ops.packing import spread_node_planes, spread_task_columns
+    from volcano_tpu.ops.packing import (
+        class_absolute,
+        spread_node_planes,
+        spread_task_columns,
+    )
 
     thr, inc = spread_task_columns(snap, rows)
     dom, elig, count = spread_node_planes(snap, width)
-    return tuple(jnp.asarray(a) for a in (thr, inc, dom, elig, count))
+    return tuple(jnp.asarray(a) for a in (thr, inc, dom, elig, count, class_absolute(snap)))
 
 
 def run_packed(

@@ -29,6 +29,12 @@ maxSkew, node eligibility) — by :func:`pack_spread`:
   spread_dom_count[K, D] i32  matching pods per domain at pack time
   task_spread_on[T, K]   bool the classes each task row is constrained by
   task_spread_self[T, K] bool the classes each task row counts toward
+Required pod anti-affinity packs into the same planes as classes of the
+absolute kind (``spread_absolute[K]``): a pod's own term (the pods that
+match it per domain), and a placed or pending pod's term that a row
+matches (the pods that hold it per domain, the symmetry rule).  A row
+passes such a class where its domain's count is at most the bound, 0,
+rather than where it exceeds the minimum by at most maxSkew.
 
 Label/taint relational predicates become pointwise bitset ops (SURVEY §7
 "predicate expressiveness"): W words of 32 bits each; the registry assigns a
@@ -40,7 +46,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -53,8 +59,8 @@ DEFAULT_BIT_WORDS = 2
 #: Memory lane quantization (bytes per MiB).
 MIB = float(1 << 20)
 
-#: Spread constraint classes one pack encodes for the kernels; a row
-#: whose constraints need more keeps host validation.
+#: Spread and anti-affinity classes one pack encodes for the kernels; a
+#: row whose constraints need more keeps host validation.
 SPREAD_MAX_CLASSES = 8
 
 
@@ -171,8 +177,12 @@ class PackedSnapshot:
     spread_key_dom: Optional[np.ndarray] = None
     #: [K] i32 — the topology key row of each class
     spread_class_key: Optional[np.ndarray] = None
-    #: [K] i32 — maxSkew of each class
+    #: [K] i32 — maxSkew of each class; the bound of an absolute one
     spread_max_skew: Optional[np.ndarray] = None
+    #: [K] bool — classes of the absolute kind (anti-affinity): a node
+    #: passes where its domain's count is at most the bound; None reads
+    #: as none
+    spread_absolute: Optional[np.ndarray] = None
     #: [K, N_pad] bool — nodes that count toward the class's domains
     spread_node_elig: Optional[np.ndarray] = None
     #: [K, D_pad] i32 — matching pods per domain when packed
@@ -181,11 +191,15 @@ class PackedSnapshot:
     task_spread_on: Optional[np.ndarray] = None
     #: [T_pad, K] bool — rows that count toward each class once placed
     task_spread_self: Optional[np.ndarray] = None
-    #: [T_pad] bool — rows with hard constraints the pack did not
-    #: encode (host validation); recomputed by every pack, never carried
+    #: [T_pad] bool — rows with hard spread constraints or required
+    #: inter-pod terms the pack did not encode (host validation);
+    #: recomputed by every pack, never carried
     task_spread_host: Optional[np.ndarray] = None
     #: what the last pack_spread counted (rows, classes, ms)
     spread_stats: Optional[Dict[str, float]] = None
+    #: the same for the anti-affinity classes (affinity_rows_device /
+    #: _host, affinity_classes, affinity_pack_ms)
+    affinity_stats: Optional[Dict[str, float]] = None
 
     @property
     def has_spread(self) -> bool:
@@ -225,6 +239,7 @@ _SNAPSHOT_ARRAYS = (
     "spread_key_dom",
     "spread_class_key",
     "spread_max_skew",
+    "spread_absolute",
     "spread_node_elig",
     "spread_dom_count",
     "task_spread_on",
@@ -405,7 +420,12 @@ def pack_task_bits(
                         snap.task_tol_bits, i, (tol_.key, tol_.value, effect)
                     )
     aff = pod.spec.affinity or {}
-    if aff.get("podAffinity") or aff.get("podAntiAffinity"):
+    # required anti-affinity is pack_spread's (a class, or a host row);
+    # pod affinity and preferred anti-affinity stay on the host
+    anti = aff.get("podAntiAffinity") or {}
+    if aff.get("podAffinity") or any(
+        v for k, v in anti.items() if k != "requiredDuringSchedulingIgnoredDuringExecution"
+    ):
         needs_host = True
     node_pref = (aff.get("nodeAffinity") or {}).get(
         "preferredDuringSchedulingIgnoredDuringExecution"
@@ -670,35 +690,68 @@ def spread_row_encodable(hard) -> bool:
     return True
 
 
-def pack_spread(snap: "PackedSnapshot", tasks, nodes, encode: bool = True) -> None:
-    """Encode the tasks' hard topology spread constraints into ``snap``
-    (the planes in the module docstring), from the pods the session's
-    nodes hold now.  Runs after every pack, cold or warm: the planes
-    depend on where pods sit, so none is carried between packs.
+def affinity_row_encodable(pod) -> bool:
+    """Do the kernels encode this pod's own required inter-pod terms
+    exactly?  Its anti-affinity terms, where none carries a namespace
+    selector or label keys; not required pod affinity, which no class
+    kind expresses yet."""
+    from volcano_tpu.plugins import util as putil
 
-    A row the kernels cannot encode exactly — ``spread_row_encodable``
-    refuses it, its classes overflow :data:`SPREAD_MAX_CLASSES`, or its
+    return not putil.required_terms(pod, "podAffinity") and all(
+        putil.term_encodable(t) for t in putil.required_terms(pod, "podAntiAffinity")
+    )
+
+
+class _ClassPlanes(NamedTuple):
+    """One kind's classes, before :func:`pack_spread` joins the kinds."""
+
+    key_dom: np.ndarray  # [Kk, N_pad] i32
+    class_key: np.ndarray  # [K] i32, rows of key_dom
+    bound: np.ndarray  # [K] i32
+    absolute: np.ndarray  # [K] bool
+    elig: np.ndarray  # [K, N_pad] bool
+    dom_count: np.ndarray  # [K, D] i32
+    on: np.ndarray  # [T_pad, K] bool
+    self_: np.ndarray  # [T_pad, K] bool
+
+
+def pack_spread(snap: "PackedSnapshot", tasks, nodes, encode: bool = True) -> None:
+    """Encode the tasks' hard topology spread constraints and required
+    pod anti-affinity into ``snap`` (the planes in the module docstring),
+    from the pods the session's nodes hold now.  Runs after every pack,
+    cold or warm: the planes depend on where pods sit, so none is
+    carried between packs.  Spread classes come first; anti-affinity
+    classes take what is left of :data:`SPREAD_MAX_CLASSES`.
+
+    A spread row the kernels cannot encode exactly —
+    ``spread_row_encodable`` refuses it, its classes overflow, or its
     constraints map to classes out of their order (the explanation's
     first failure follows the pod's order) — is marked in
-    ``task_spread_host`` and sets ``needs_host_validation``;
+    ``task_spread_host`` and sets ``needs_host_validation``; so is a row
+    whose inter-pod terms are not encoded (:func:`_affinity_classes`).
     ``encode=False`` (the preempt and reclaim packs, whose kernels model
-    no spread) marks every row with a hard constraint so."""
+    neither) marks every row with a hard constraint or a required
+    inter-pod term so."""
     import contextlib
     import time
 
     from volcano_tpu import trace
     from volcano_tpu.plugins import util as putil
 
-    t0 = time.perf_counter()
     T_pad = snap.task_resreq.shape[0]
     N_pad = snap.node_idle.shape[0]
     host = np.zeros(T_pad, dtype=bool)
+    parts: List[_ClassPlanes] = []
+
+    def span(name):
+        return trace.span(name, "kernel") if encode else contextlib.nullcontext()
+
+    t0 = time.perf_counter()
     class_index: Dict[Tuple, int] = {}
     reps: List[Tuple] = []  # (pod, constraint, class key) of each class
     rows: List[Tuple[int, List[int]]] = []
     device_rows = host_rows = 0
-    span = trace.span("vtpu/allocate.spread_pack", "kernel") if encode else contextlib.nullcontext()
-    with span:
+    with span("vtpu/allocate.spread_pack"):
         for i, t in enumerate(tasks):
             pod = t.pod
             if pod is None or not pod.spec.topology_spread_constraints:
@@ -725,29 +778,70 @@ def pack_spread(snap: "PackedSnapshot", tasks, nodes, encode: bool = True) -> No
                 device_rows += 1
             else:
                 host_rows += 1
-        snap.task_spread_host = host
         if reps:
-            _spread_planes(snap, tasks, nodes, reps, rows, N_pad, T_pad, putil)
-        else:
-            for name in ("spread_key_dom", "spread_class_key", "spread_max_skew",
-                         "spread_node_elig", "spread_dom_count", "task_spread_on",
-                         "task_spread_self"):
-                setattr(snap, name, None)
-        if host[: len(tasks)].any():
-            snap.needs_host_validation = True
+            parts.append(_spread_planes(tasks, nodes, reps, rows, N_pad, T_pad, putil))
+    t1 = time.perf_counter()
     snap.spread_stats = {
         "spread_rows_device": device_rows,
         "spread_rows_host": host_rows,
         "spread_classes": len(reps),
-        "spread_pack_ms": (time.perf_counter() - t0) * 1e3,
+        "spread_pack_ms": (t1 - t0) * 1e3,
     }
+    with span("vtpu/allocate.affinity_pack"):
+        aff_reps, aff_rows, device_rows, host_rows, holds = _affinity_classes(
+            snap, tasks, nodes, host, encode, SPREAD_MAX_CLASSES - len(reps), putil)
+        if aff_reps:
+            parts.append(_affinity_planes(
+                tasks, nodes, aff_reps, aff_rows, holds, N_pad, T_pad, putil))
+    snap.affinity_stats = {
+        "affinity_rows_device": device_rows,
+        "affinity_rows_host": host_rows,
+        "affinity_classes": len(aff_reps),
+        "affinity_pack_ms": (time.perf_counter() - t1) * 1e3,
+    }
+    _set_class_planes(snap, parts)
+    snap.task_spread_host = host
+    if host[: len(tasks)].any():
+        snap.needs_host_validation = True
 
 
-def _spread_planes(snap, tasks, nodes, reps, rows, N_pad, T_pad, putil) -> None:
-    """The planes of :func:`pack_spread` for its K registered classes."""
-    K = len(reps)
-    node_objs = [n.node for n in nodes]
-    keys = list(dict.fromkeys(c.topology_key for _pod, c, _key in reps))
+def _set_class_planes(snap, parts: List[_ClassPlanes]) -> None:
+    """The planes of every kind's classes, spread's first."""
+    if not parts:
+        for name in ("spread_key_dom", "spread_class_key", "spread_max_skew",
+                     "spread_absolute", "spread_node_elig", "spread_dom_count",
+                     "task_spread_on", "task_spread_self"):
+            setattr(snap, name, None)
+        return
+    if len(parts) == 1:
+        p = parts[0]
+    else:
+        width = max(q.dom_count.shape[1] for q in parts)
+        offsets = np.cumsum([0] + [q.key_dom.shape[0] for q in parts[:-1]])
+        p = _ClassPlanes(
+            np.concatenate([q.key_dom for q in parts]),
+            np.concatenate([q.class_key + o for q, o in zip(parts, offsets)]).astype(np.int32),
+            *(np.concatenate([getattr(q, f) for q in parts])
+              for f in ("bound", "absolute", "elig")),
+            np.concatenate([
+                np.pad(q.dom_count, ((0, 0), (0, width - q.dom_count.shape[1])))
+                for q in parts
+            ]),
+            *(np.concatenate([getattr(q, f) for q in parts], axis=1) for f in ("on", "self_")),
+        )
+    snap.spread_key_dom = p.key_dom
+    snap.spread_class_key = p.class_key
+    snap.spread_max_skew = p.bound
+    snap.spread_absolute = p.absolute
+    snap.spread_node_elig = p.elig
+    snap.spread_dom_count = p.dom_count
+    snap.task_spread_on = p.on
+    snap.task_spread_self = p.self_
+
+
+def _key_domains(node_objs, keys: List[str], N_pad: int):
+    """([len(keys), N_pad] i32 domain index of each node under each key,
+    -1 where it lacks the label; the domain count of each key)."""
     key_dom = np.full((len(keys), N_pad), -1, dtype=np.int32)
     n_values = []
     for r, tk in enumerate(keys):
@@ -761,6 +855,16 @@ def _spread_planes(snap, tasks, nodes, reps, rows, N_pad, T_pad, putil) -> None:
             v = (obj.metadata.labels or {}).get(tk) if obj is not None else None
             if v is not None:
                 key_dom[r, j] = index[v]
+    return key_dom, n_values
+
+
+def _spread_planes(tasks, nodes, reps, rows, N_pad, T_pad, putil) -> _ClassPlanes:
+    """The planes of :func:`pack_spread` for its K registered spread
+    classes."""
+    K = len(reps)
+    node_objs = [n.node for n in nodes]
+    keys = list(dict.fromkeys(c.topology_key for _pod, c, _key in reps))
+    key_dom, n_values = _key_domains(node_objs, keys, N_pad)
     key_row = {tk: r for r, tk in enumerate(keys)}
     class_key = np.array([key_row[c.topology_key] for _p, c, _k in reps], dtype=np.int32)
 
@@ -816,13 +920,142 @@ def _spread_planes(snap, tasks, nodes, reps, rows, N_pad, T_pad, putil) -> None:
         if t.pod is not None:
             self_[i, matches(t.pod)] = True
 
-    snap.spread_key_dom = key_dom
-    snap.spread_class_key = class_key
-    snap.spread_max_skew = np.array([int(c.max_skew) for _p, c, _k in reps], dtype=np.int32)
-    snap.spread_node_elig = elig
-    snap.spread_dom_count = dom_count
-    snap.task_spread_on = on
-    snap.task_spread_self = self_
+    bound = np.array([int(c.max_skew) for _p, c, _k in reps], dtype=np.int32)
+    return _ClassPlanes(key_dom, class_key, bound, np.zeros(K, dtype=bool), elig,
+                        dom_count, on, self_)
+
+
+def _affinity_classes(snap, tasks, nodes, host, encode: bool, budget: int, putil):
+    """The anti-affinity classes of the rows, at most ``budget`` of them:
+    (reps, rows, device rows, host rows, the term keys each pod holds by
+    uid), where each rep is ``(class key,
+    selector)`` — the key ``("match", namespaces, selector, topology
+    key)`` for a row's own term, ``("hold", ...)`` for a term a placed or
+    pending pod holds and the row matches.  A row whose terms the
+    kernels cannot encode (:func:`affinity_row_encodable`, a held term
+    with a namespace selector, the budget) is marked in ``host``.  Rows
+    are counted as affinity rows where they hold a required term or
+    match a held one."""
+    held: Dict[Tuple, list] = {}  # term key -> [selector, encodable]
+    holds: Dict[str, List[Tuple]] = {}  # uid -> keys of the terms its pod holds
+
+    def hold(t) -> None:
+        keys = holds[t.uid] = []
+        for key, term in putil.held_anti_affinity(t.pod):
+            keys.append(key)
+            entry = held.setdefault(key, [term.get("labelSelector"), True])
+            entry[1] = entry[1] and putil.term_encodable(term)
+
+    if encode:
+        for n in nodes:
+            for t in n.tasks.values():
+                if t.pod is not None and t.pod.spec.affinity:
+                    hold(t)
+        for t in tasks:
+            if t.pod is not None and t.pod.spec.affinity:
+                hold(t)
+    memo: Dict[Tuple, List[Tuple]] = {}
+
+    def matched_held(pod) -> List[Tuple]:
+        mkey = (pod.metadata.namespace, tuple((pod.metadata.labels or {}).items()))
+        hit = memo.get(mkey)
+        if hit is None:
+            hit = memo[mkey] = [
+                key for key, (sel, _ok) in held.items()
+                if putil.pod_matches_term(key[0], sel, pod)
+            ]
+        return hit
+
+    class_index: Dict[Tuple, int] = {}
+    reps: List[Tuple] = []
+    rows: List[Tuple[int, List[int]]] = []
+    device_rows = host_rows = 0
+    for i, t in enumerate(tasks):
+        pod = t.pod
+        if pod is None:
+            continue
+        own = putil.required_terms(pod, "podAntiAffinity") if pod.spec.affinity else []
+        sym = matched_held(pod) if held else []
+        if not own and not sym and not (
+            pod.spec.affinity and putil.required_terms(pod, "podAffinity")
+        ):
+            continue
+        ids: Optional[List[int]] = None
+        if encode and affinity_row_encodable(pod) and all(held[k][1] for k in sym):
+            ids = []
+            # a pod's own anti-affinity terms are the terms it holds
+            wanted = [(("match",) + key, held[key][0]) for key in holds.get(t.uid, ())] + [
+                (("hold",) + key, held[key][0]) for key in sym]
+            for ckey, sel in wanted:
+                k = class_index.get(ckey)
+                if k is None and len(reps) < budget:
+                    k = class_index[ckey] = len(reps)
+                    reps.append((ckey, sel))
+                if k is None:
+                    ids = None
+                    break
+                ids.append(k)
+        if ids is None:
+            host[i] = True
+        else:
+            rows.append((i, ids))
+        if ids is not None and not snap.task_has_preferences[i]:
+            device_rows += 1
+        else:
+            host_rows += 1
+    return reps, rows, device_rows, host_rows, holds
+
+
+def _affinity_planes(tasks, nodes, reps, rows, holds, N_pad, T_pad, putil) -> _ClassPlanes:
+    """The planes of the anti-affinity classes: a node is a domain of a
+    class where it carries the key, every pod its node holds counts
+    (whatever its status, as the host's counts have it), and the bound
+    is 0."""
+    K = len(reps)
+    node_objs = [n.node for n in nodes]
+    keys = list(dict.fromkeys(ckey[-1] for ckey, _sel in reps))
+    key_dom, n_values = _key_domains(node_objs, keys, N_pad)
+    key_row = {tk: r for r, tk in enumerate(keys)}
+    class_key = np.array([key_row[ckey[-1]] for ckey, _sel in reps], dtype=np.int32)
+    dom = key_dom[class_key]  # [K, N_pad]
+
+    # a pod counts toward a "match" class by its namespace and labels,
+    # toward a "hold" class by the terms it holds
+    match = [(k, ckey[1], sel) for k, (ckey, sel) in enumerate(reps) if ckey[0] == "match"]
+    hold_class = {ckey[1:]: k for k, (ckey, _sel) in enumerate(reps) if ckey[0] == "hold"}
+    memo: Dict[Tuple, List[int]] = {}
+
+    def counts_toward(t) -> List[int]:
+        pod = t.pod
+        mkey = (pod.metadata.namespace, tuple((pod.metadata.labels or {}).items()))
+        hit = memo.get(mkey)
+        if hit is None:
+            hit = memo[mkey] = [
+                k for k, ns, sel in match if putil.pod_matches_term(ns, sel, pod)
+            ]
+        held = holds.get(t.uid)
+        if held and hold_class:
+            hit = hit + [hold_class[key] for key in held if key in hold_class]
+        return hit
+
+    dom_count = np.zeros((K, max(max(n_values), 1)), dtype=np.int32)
+    dom_of = dom.tolist()
+    for j, n in enumerate(nodes):
+        for t in n.tasks.values():
+            if t.pod is not None:
+                for k in counts_toward(t):
+                    if dom_of[k][j] >= 0:
+                        dom_count[k, dom_of[k][j]] += 1
+
+    on = np.zeros((T_pad, K), dtype=bool)
+    for i, ids in rows:
+        on[i, ids] = True
+    self_ = np.zeros((T_pad, K), dtype=bool)
+    for i, t in enumerate(tasks):
+        if t.pod is not None:
+            self_[i, counts_toward(t)] = True
+    return _ClassPlanes(key_dom, class_key, np.zeros(K, dtype=np.int32),
+                        np.ones(K, dtype=bool), dom >= 0, dom_count, on, self_)
 
 
 def spread_node_planes(snap: "PackedSnapshot", width: int):
@@ -843,10 +1076,20 @@ def spread_node_planes(snap: "PackedSnapshot", width: int):
     return dom, elig, count
 
 
+def class_absolute(snap: "PackedSnapshot") -> np.ndarray:
+    """[K] bool: the classes of the absolute kind (all False for a
+    snapshot packed before the kind existed)."""
+    absolute = getattr(snap, "spread_absolute", None)
+    if absolute is None:
+        return np.zeros(snap.spread_max_skew.shape[0], dtype=bool)
+    return absolute.astype(bool)
+
+
 def spread_task_columns(snap: "PackedSnapshot", rows: int):
     """(thr, inc) [rows, K] f32 task columns of the kernels: ``thr`` is
     the most a node's domain count may exceed the minimum by — maxSkew
-    less the row's self-match — or -1 where the class does not
+    less the row's self-match — or, for a class of the absolute kind,
+    the most it may hold (the bound), or -1 where the class does not
     constrain the row; ``inc`` is 1 where the row counts toward the
     class once placed."""
     K = snap.spread_max_skew.shape[0]
@@ -855,6 +1098,7 @@ def spread_task_columns(snap: "PackedSnapshot", rows: int):
     inc = np.zeros((rows, K), dtype=np.float32)
     on = snap.task_spread_on[:n]
     self_ = snap.task_spread_self[:n].astype(np.float32)
-    thr[:n] = np.where(on, snap.spread_max_skew[None, :] - self_, -1.0)
+    bound = snap.spread_max_skew[None, :] - np.where(class_absolute(snap)[None, :], 0.0, self_)
+    thr[:n] = np.where(on, bound, -1.0)
     inc[:n] = self_
     return thr, inc

@@ -27,12 +27,14 @@ trip regardless of how many gang rounds it takes — through a
 high-latency device link each avoided round trip is worth far more than
 the kernel time itself.
 
-Hard topology spread (K constraint classes, ops/packing.pack_spread)
-rides the same layout: each task row gains K thresholds and K count
-flags, the node array gains per-class domain, eligibility and
-initial-count planes, and a VMEM scratch carries the running domain
-counts.  Each step masks the scores by every constraining class — one
-cross-node minimum per class — and a placement adds one to the chosen
+Hard topology spread and required pod anti-affinity (K classes,
+ops/packing.pack_spread) ride the same layout: each task row gains K
+thresholds and K count flags, the node array gains per-class domain,
+eligibility and initial-count planes, and a VMEM scratch carries the
+running domain counts.  Each step masks the scores by every
+constraining class — against one cross-node minimum per spread class,
+against its bound for a class of the absolute kind (anti-affinity; the
+kind is static, ``absolute``) — and a placement adds one to the chosen
 node's whole domain (kernels.spread_ok / spread_update, op for op).
 """
 
@@ -58,6 +60,7 @@ from volcano_tpu.ops.kernels import (
     SPREAD_BIG,
 )
 from volcano_tpu.ops.packing import (
+    class_absolute,
     PackedSnapshot,
     spread_node_planes,
     spread_task_columns,
@@ -134,9 +137,12 @@ def score_planes(
     return s_bp + jnp.float32(w_lr) * s_lr + jnp.float32(w_bal) * s_bal
 
 
-def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights, K: int = 0):
+def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights, K: int = 0,
+                 absolute: Tuple[bool, ...] = ()):
     """Kernel factory — R resource lanes, TB tasks per grid step, NS node
-    sublanes (nodes = NS*128), static plugin weights, K spread classes.
+    sublanes (nodes = NS*128), static plugin weights, K classes, of which
+    those flagged in ``absolute`` (missing entries read False) are of
+    the absolute kind.
 
     Incremental repeated-row fast path: a placement at step k-1 changes
     node state at ONE node, so when task k's row (resreq lanes + class +
@@ -152,6 +158,7 @@ def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights, K: int = 0):
     out of that plane: it is applied to it afresh every step."""
 
     TBS = TB // LANES
+    kinds = tuple(absolute) + (False,) * (K - len(absolute))
     RC = R + 2 + 2 * K  # task row width
     SP = 3 * R + 2  # first spread plane of nd
 
@@ -252,14 +259,18 @@ def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights, K: int = 0):
                     lambda ref, p: ref[p, pl.ds(bq, 1), :]
                 )
 
-            # --- hard topology spread (kernels.spread_ok) ---
+            # --- spread and anti-affinity classes (kernels.spread_ok) ---
             masked = masked_s[:]
             for c in range(K):
                 thr = col(R + 2 + c)
                 count = spread_s[0][c]
                 dom = nd_ref[SP + c]
-                mn = jnp.min(jnp.where(nd_ref[SP + K + c] > 0.0, count, SPREAD_BIG))
-                passes = (thr < 0.0) | ((dom >= 0.0) & (count - mn <= thr))
+                if kinds[c]:
+                    # the minimum is taken as 0; a node without the key passes
+                    passes = (thr < 0.0) | (dom < 0.0) | (count <= thr)
+                else:
+                    mn = jnp.min(jnp.where(nd_ref[SP + K + c] > 0.0, count, SPREAD_BIG))
+                    passes = (thr < 0.0) | ((dom >= 0.0) & (count - mn <= thr))
                 masked = jnp.where(passes, masked, -jnp.inf)
 
             # --- lowest-index argmax + row-sliced state update ---
@@ -303,7 +314,8 @@ def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights, K: int = 0):
 
 
 def _pass_call(
-    taskrow, cf, nd, maxal, allocpos, tol, weights, block_size, interpret
+    taskrow, cf, nd, maxal, allocpos, tol, weights, block_size, interpret,
+    absolute=(),
 ):
     """Build + invoke the pallas_call for one greedy pass → chosen[T_act].
     All operands already device-resident/derived; traceable inside
@@ -317,7 +329,7 @@ def _pass_call(
     TB = block_size
     assert TB % LANES == 0 and T_act % TB == 0
     TBS = TB // LANES
-    kernel = _make_kernel(R, TB, NS, weights, K)
+    kernel = _make_kernel(R, TB, NS, weights, K, absolute)
     G = T_act // TB
 
     full = lambda *shape: pl.BlockSpec(
@@ -354,7 +366,7 @@ def _pass_call(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("weights", "block_size", "interpret"),
+    static_argnames=("weights", "block_size", "interpret", "absolute"),
 )
 def schedule_pass_pallas(
     taskrow: jnp.ndarray,  # [T_act, R+2+2K] f32 — resreq lanes, class, active, spread
@@ -364,6 +376,7 @@ def schedule_pass_pallas(
     weights: ScoreWeights = DEFAULT_WEIGHTS,
     block_size: int = 256,
     interpret: bool = False,
+    absolute: Tuple[bool, ...] = (),
 ) -> jnp.ndarray:
     """One greedy pass on TPU → chosen[T_act] (node index or -1)."""
     R = tol.shape[1]
@@ -374,13 +387,14 @@ def schedule_pass_pallas(
     maxal = jnp.maximum(alloc, 1.0)
     allocpos = (alloc > 0.0).astype(jnp.float32)
     return _pass_call(
-        taskrow, cf, nd, maxal, allocpos, tol, weights, block_size, interpret
+        taskrow, cf, nd, maxal, allocpos, tol, weights, block_size, interpret,
+        absolute,
     )
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("weights", "block_size", "gang_rounds", "interpret"),
+    static_argnames=("weights", "block_size", "gang_rounds", "interpret", "absolute"),
 )
 def schedule_session_pallas(
     taskrow: jnp.ndarray,  # [T_act, R+2+2K] f32 (active column ignored)
@@ -395,6 +409,7 @@ def schedule_session_pallas(
     block_size: int = 256,
     gang_rounds: int = 3,
     interpret: bool = False,
+    absolute: Tuple[bool, ...] = (),
 ) -> jnp.ndarray:
     """Whole session on device → assignment[T_act] (node index or -1,
     gang-committed only).
@@ -423,7 +438,8 @@ def schedule_session_pallas(
         i, active, _chosen, _committed, _done = carry
         tr = taskrow.at[:, R + 1].set(active.astype(jnp.float32))
         chosen = _pass_call(
-            tr, cf, nd, maxal, allocpos, tol, weights, block_size, interpret
+            tr, cf, nd, maxal, allocpos, tol, weights, block_size, interpret,
+            absolute,
         )
         assigned = jnp.zeros((J,), jnp.int32).at[task_job].add(
             (chosen >= 0).astype(jnp.int32)
@@ -450,7 +466,7 @@ def schedule_session_pallas(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("weights", "block_size", "gang_rounds", "interpret"),
+    static_argnames=("weights", "block_size", "gang_rounds", "interpret", "absolute"),
 )
 def schedule_session_pallas_packed(
     taskrow_ext: jnp.ndarray,  # [T_act, R+3+2K] — resreq, class, active0, spread, job
@@ -462,6 +478,7 @@ def schedule_session_pallas_packed(
     block_size: int = 256,
     gang_rounds: int = 3,
     interpret: bool = False,
+    absolute: Tuple[bool, ...] = (),
 ) -> jnp.ndarray:
     """Transfer-packed entry: the per-task job row and initial active
     mask ride inside the task rows and the two job vectors ride one
@@ -486,6 +503,7 @@ def schedule_session_pallas_packed(
         block_size=block_size,
         gang_rounds=gang_rounds,
         interpret=interpret,
+        absolute=absolute,
     )
 
 
@@ -607,7 +625,7 @@ def kernel_vmem_bytes(C: int, NK: int, R: int, block_size: int = 256, K: int = 0
     jax.jit,
     static_argnames=(
         "T_rows", "R", "U", "C", "ND", "NS", "JP", "K",
-        "weights", "block_size", "gang_rounds", "interpret",
+        "weights", "block_size", "gang_rounds", "interpret", "absolute",
     ),
 )
 def schedule_session_pallas_buf(
@@ -620,6 +638,7 @@ def schedule_session_pallas_buf(
     block_size: int = 256,
     gang_rounds: int = 3,
     interpret: bool = False,
+    absolute: Tuple[bool, ...] = (),
 ) -> jnp.ndarray:
     """Two-buffer entry: the per-SESSION payload and the per-CLUSTER
     payload (class feasibility + node planes) arrive as two byte
@@ -682,7 +701,7 @@ def schedule_session_pallas_buf(
     return schedule_session_pallas_packed(
         taskrow_ext, cf_u8, nd, tol, jobs2,
         weights=weights, block_size=block_size, gang_rounds=gang_rounds,
-        interpret=interpret,
+        interpret=interpret, absolute=absolute,
     )
 
 
@@ -799,6 +818,7 @@ def make_session_dispatch(
     T_rows = arrays["taskrow"].shape[0]
     R = arrays["tol"].shape[1]
     K = (arrays["taskrow"].shape[1] - R - 2) // 2
+    absolute = tuple(bool(a) for a in class_absolute(snap)) if K else ()
     n_act = min(snap.n_tasks, T_act)
     jobs2 = np.stack(
         [
@@ -833,7 +853,7 @@ def make_session_dispatch(
             return schedule_session_pallas_packed(
                 *(jnp.asarray(a) for a in args5),
                 weights=weights, block_size=block_size,
-                gang_rounds=gang_rounds, interpret=interpret,
+                gang_rounds=gang_rounds, interpret=interpret, absolute=absolute,
             )
     else:
         task_job16[:n_tj] = snap.task_job[:n_tj].astype(np.uint16)
@@ -859,7 +879,7 @@ def make_session_dispatch(
             T_rows=T_rows, R=R, U=U_pad, C=arrays["cf_u8"].shape[0],
             ND=arrays["nd"].shape[0], NS=arrays["nd"].shape[1], JP=JP, K=K,
             weights=weights, block_size=block_size,
-            gang_rounds=gang_rounds, interpret=interpret,
+            gang_rounds=gang_rounds, interpret=interpret, absolute=absolute,
         )
 
         def dispatch():

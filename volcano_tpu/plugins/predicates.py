@@ -105,15 +105,16 @@ class PredicatesPlugin(Plugin):
                 if reason is not None:
                     raise FitError(task, node, reason)
 
-            # Pod (anti-)affinity (predicates.go:280-298).
+            # Required pod (anti-)affinity and the placed pods' required
+            # anti-affinity (predicates.go:280-298): O(terms) against the
+            # session's per-domain counts (util.InterPodAffinity).
             if pod.spec.affinity and (
                 pod.spec.affinity.get("podAffinity")
                 or pod.spec.affinity.get("podAntiAffinity")
             ) or pl.any_required_anti_affinity():
-                if not putil.pod_affinity_predicate(
-                    pod, node, ssn.nodes, pl.assigned_pods()
-                ):
-                    raise FitError(task, node, reasons.POD_AFFINITY_MISMATCH)
+                reason = pl.affinity.filter(task, node)
+                if reason is not None:
+                    raise FitError(task, node, reason)
 
             # Volume binding (the vendored VolumeBindingChecker /
             # FindPodVolumes analogue): every referenced PVC must exist

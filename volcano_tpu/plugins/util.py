@@ -175,7 +175,7 @@ def fits_host_ports(pod: core.Pod, existing_pods: Iterable[core.Pod]) -> bool:
 
 # ---- pod (anti-)affinity ----
 
-def _affinity_terms(pod: core.Pod, kind: str) -> List[dict]:
+def required_terms(pod: core.Pod, kind: str) -> List[dict]:
     aff = (pod.spec.affinity or {}).get(kind) or {}
     return aff.get("requiredDuringSchedulingIgnoredDuringExecution") or []
 
@@ -477,6 +477,251 @@ class TopologySpread:
         return None
 
 
+# ---- inter-pod affinity (k8s v1.30 plugins/interpodaffinity) ----
+
+def term_key(term: dict, pod: core.Pod) -> Tuple:
+    """What two terms must share to count the same pods in the same
+    domains: the namespaces (the term's list, or the pod's own namespace
+    when it is empty; ``namespaceSelector`` is not honoured, and a term
+    carrying one stays off the device), the selector and the topology
+    key."""
+    return (
+        tuple(sorted(term.get("namespaces") or [pod.metadata.namespace])),
+        _selector_key(term.get("labelSelector")),
+        term.get("topologyKey") or DEFAULT_TOPOLOGY_KEY,
+    )
+
+
+def term_encodable(term: dict) -> bool:
+    """Is a term within what the device encodes: no ``namespaceSelector``
+    and no ``matchLabelKeys`` / ``mismatchLabelKeys``?"""
+    return not (
+        term.get("namespaceSelector") is not None
+        or term.get("matchLabelKeys") or term.get("mismatchLabelKeys")
+    )
+
+
+def pod_matches_term(namespaces: Tuple[str, ...], selector: Optional[dict],
+                     pod: core.Pod) -> bool:
+    """Does ``pod`` fall under a term of these namespaces and selector?"""
+    return pod.metadata.namespace in namespaces and match_label_selector(
+        pod.metadata.labels or {}, selector
+    )
+
+
+def held_anti_affinity(pod: Optional[core.Pod]) -> List[Tuple[Tuple, dict]]:
+    """(term key, term) of each required anti-affinity term ``pod`` holds."""
+    if pod is None or not pod.spec.affinity:
+        return []
+    return [(term_key(t, pod), t) for t in required_terms(pod, "podAntiAffinity")]
+
+
+class TermCounts:
+    """Pods per domain of one topology key: those that match a term
+    (``matched``), or those that hold a required anti-affinity term
+    (``held``).  Only nodes that carry the key are domains."""
+
+    __slots__ = ("namespaces", "selector", "key", "counts", "total")
+
+    def __init__(self, namespaces: Tuple[str, ...], selector: Optional[dict], key: str):
+        self.namespaces = namespaces
+        self.selector = selector
+        self.key = key
+        self.counts: Dict[str, int] = {}
+        self.total = 0
+
+    def move(self, domain: Optional[str], step: int) -> None:
+        if domain is not None:
+            self.counts[domain] = self.counts.get(domain, 0) + step
+            self.total += step
+
+
+class _PodTerms:
+    """One incoming pod's view of the counts: (class, whether the pod
+    itself counts toward it) for its own required affinity terms, its
+    own required anti-affinity terms, and the held classes whose term
+    matches it (refreshed when a new held class appears)."""
+
+    __slots__ = ("affinity", "anti", "sym", "sym_rev", "self_affine")
+
+    def __init__(self, affinity, anti, self_affine: bool):
+        self.affinity: List[Tuple[TermCounts, bool]] = affinity
+        self.anti: List[Tuple[TermCounts, bool]] = anti
+        self.sym: List[Tuple[TermCounts, bool]] = []
+        self.sym_rev = -1
+        #: the pod matches every one of its own affinity terms
+        self.self_affine = self_affine
+
+
+class InterPodAffinity:
+    """Per-session PreFilter state of InterPodAffinity's required terms:
+    per distinct (namespaces, selector, topology key), the pods in each
+    domain that match the term, and the pods in each domain that hold it
+    as a required anti-affinity term.  Built on the first check from the
+    pods the session's nodes hold, kept in step by the allocate and
+    deallocate events, so a (task, node) check costs O(terms) rather
+    than a scan of every pod.
+
+    A pod counts while its node holds it, whatever its status: an
+    evicted (Releasing) pod still excludes others from its domain until
+    it is gone, as a terminating pod does in kube-scheduler."""
+
+    def __init__(self, session):
+        self.session = session
+        #: uid → (node name, pod) of every pod the session's nodes hold
+        self._where: Optional[Dict[str, Tuple[str, core.Pod]]] = None
+        self._matched: Dict[Tuple, TermCounts] = {}
+        self._held: Dict[Tuple, TermCounts] = {}
+        self._held_rev = 0
+        self._pods: Dict[str, _PodTerms] = {}
+        #: whether a pod the nodes hold carries required anti-affinity;
+        #: None until asked, kept true by the events once it is
+        self._holders: Optional[bool] = None
+
+    @property
+    def tracking(self) -> bool:
+        """Does the state follow placements (built, or holders known)?"""
+        return self._where is not None or self._holders is not None
+
+    def _labels(self, node_name: str) -> Dict[str, str]:
+        node = self.session.nodes.get(node_name)
+        if node is None or node.node is None:
+            return {}
+        return node.node.metadata.labels or {}
+
+    def any_held(self) -> bool:
+        """Does any pod the session's nodes hold carry a required
+        anti-affinity term?  One scan of the nodes on the first ask,
+        until the counts exist."""
+        if self._where is not None:
+            return any(c.total > 0 for c in self._held.values())
+        if self._holders is None:
+            self._holders = any(
+                t.pod is not None and bool(held_anti_affinity(t.pod))
+                for node in self.session.nodes.values()
+                for t in node.tasks.values()
+            )
+        return self._holders
+
+    def _build(self) -> None:
+        self._where = {}
+        for node in self.session.nodes.values():
+            for t in node.tasks.values():
+                if t.pod is not None:
+                    self._where[t.uid] = (node.name, t.pod)
+                    self._count(t.pod, node.name, 1)
+
+    def _count(self, pod: core.Pod, node_name: str, step: int) -> None:
+        labels = self._labels(node_name)
+        for cls in self._matched.values():
+            if pod_matches_term(cls.namespaces, cls.selector, pod):
+                cls.move(labels.get(cls.key), step)
+        for key, term in held_anti_affinity(pod):
+            cls = self._held.get(key)
+            if cls is None:
+                # no placed pod held it before, so its counts start at 0
+                cls = self._held[key] = TermCounts(
+                    key[0], term.get("labelSelector"), key[2])
+                self._held_rev += 1
+            cls.move(labels.get(cls.key), step)
+
+    def update(self, task: TaskInfo) -> None:
+        """Follow ``task`` to where the session's nodes now hold it."""
+        if not self.tracking:
+            return
+        node = self.session.nodes.get(task.node_name) if task.node_name else None
+        here = node.name if node is not None and task.uid in node.tasks else ""
+        if self._where is None:
+            if here and held_anti_affinity(task.pod):
+                self._holders = True
+            return
+        old = self._where.get(task.uid)
+        if old is not None and old[0] == here:
+            return
+        if old is not None:
+            del self._where[task.uid]
+            self._count(old[1], old[0], -1)
+        if here and task.pod is not None:
+            self._where[task.uid] = (here, task.pod)
+            self._count(task.pod, here, 1)
+
+    def _matched_class(self, term: dict, pod: core.Pod) -> TermCounts:
+        key = term_key(term, pod)
+        cls = self._matched.get(key)
+        if cls is None:
+            cls = self._matched[key] = TermCounts(key[0], term.get("labelSelector"), key[2])
+            for node_name, p in self._where.values():
+                if pod_matches_term(cls.namespaces, cls.selector, p):
+                    cls.move(self._labels(node_name).get(cls.key), 1)
+        return cls
+
+    def _terms(self, task: TaskInfo) -> _PodTerms:
+        pod = task.pod
+        entry = self._pods.get(task.uid)
+        if entry is None:
+            def own(kind):
+                out = []
+                for t in required_terms(pod, kind):
+                    cls = self._matched_class(t, pod)
+                    out.append((cls, pod_matches_term(cls.namespaces, cls.selector, pod)))
+                return out
+
+            aff = own("podAffinity")
+            entry = self._pods[task.uid] = _PodTerms(
+                aff, own("podAntiAffinity"), all(s for _c, s in aff))
+        if entry.sym_rev != self._held_rev:
+            holds = {k for k, _t in held_anti_affinity(pod)}
+            entry.sym = [
+                (c, key in holds) for key, c in self._held.items()
+                if pod_matches_term(c.namespaces, c.selector, pod)
+            ]
+            entry.sym_rev = self._held_rev
+        return entry
+
+    def filter(self, task: TaskInfo, node: NodeInfo) -> Optional[str]:
+        """None when ``task`` may land on ``node`` under every required
+        inter-pod term, else the Filter's reason.  Three rules, each
+        against the counts of the node's domain with the pod itself left
+        out: each own affinity term needs a matching pod there (or, for
+        the first pod of a series, no matching pod anywhere and the pod
+        matching its own terms), no own anti-affinity term may find one,
+        and no pod there may hold a term the pod matches (symmetry).  A
+        node without a term's key is no domain of it: affinity fails
+        there, anti-affinity does not."""
+        if self._where is None:
+            self._build()
+        entry = self._terms(task)
+        labels = node.node.metadata.labels if node.node is not None else {}
+        own = self._where.get(task.uid)
+        own_labels = self._labels(own[0]) if own is not None else {}
+
+        def others(cls: TermCounts, counts_self: bool, domain: str) -> int:
+            """Pods of ``domain`` in the counts, less the pod itself."""
+            return cls.counts.get(domain, 0) - int(
+                counts_self and own_labels.get(cls.key) == domain)
+
+        if entry.affinity:
+            found = True
+            for cls, counts_self in entry.affinity:
+                domain = labels.get(cls.key)
+                if domain is None:
+                    return reasons.POD_AFFINITY_MISMATCH
+                if others(cls, counts_self, domain) <= 0:
+                    found = False
+            # the first of a series: self_affine means the pod counts
+            # toward every one of these classes where its node has the key
+            if not found and not (entry.self_affine and all(
+                cls.total - int(cls.key in own_labels) == 0 for cls, _s in entry.affinity
+            )):
+                return reasons.POD_AFFINITY_MISMATCH
+        for rule in (entry.anti, entry.sym):
+            for cls, counts_self in rule:
+                domain = labels.get(cls.key)
+                if domain is not None and others(cls, counts_self, domain) > 0:
+                    return reasons.POD_AFFINITY_MISMATCH
+        return None
+
+
 class PodLister:
     """Session-wide pod view for relational predicates.
 
@@ -488,35 +733,24 @@ class PodLister:
         self.session = session
         # task uid -> (pod, node_name); node objects resolved via session.
         self._task_nodes: Dict[str, str] = {}
-        # Assigned tasks whose pod declares required anti-affinity —
-        # maintained incrementally so the symmetry gate in the predicates
-        # plugin is O(1) per call instead of an O(tasks) sweep (which made
-        # a session's predicate validation O(tasks²)).
-        self._assigned_anti_affinity: set = set()
-        self._has_anti_affinity: set = set()
         #: hard topology spread counts, kept in step with the same events
         self.spread = TopologySpread(session)
+        #: required inter-pod (anti-)affinity counts, likewise
+        self.affinity = InterPodAffinity(session)
         for job in session.jobs.values():
             for task in job.tasks.values():
                 if task.pod is not None:
                     self._task_nodes[task.uid] = task.node_name
-                    if _affinity_terms(task.pod, "podAntiAffinity"):
-                        self._has_anti_affinity.add(task.uid)
-                        if task.node_name:
-                            self._assigned_anti_affinity.add(task.uid)
 
     def update_task(self, task: TaskInfo, node_name: str) -> None:
         self._task_nodes[task.uid] = node_name
         self.spread.update(task, node_name)
-        if task.uid in self._has_anti_affinity:
-            if node_name:
-                self._assigned_anti_affinity.add(task.uid)
-            else:
-                self._assigned_anti_affinity.discard(task.uid)
+        self.affinity.update(task)
 
     def any_required_anti_affinity(self) -> bool:
-        """True iff any assigned pod declares required anti-affinity."""
-        return bool(self._assigned_anti_affinity)
+        """True iff a pod the session's nodes hold declares required
+        anti-affinity."""
+        return self.affinity.any_held()
 
     def pods_on_node(self, node: NodeInfo) -> List[core.Pod]:
         return [t.pod for t in node.tasks.values() if t.pod is not None]
@@ -532,52 +766,6 @@ class PodLister:
                 if nn:
                     out.append((task.pod, nn))
         return out
-
-
-def pod_affinity_predicate(
-    pod: core.Pod,
-    node: NodeInfo,
-    all_nodes: Dict[str, NodeInfo],
-    assigned_pods: List[tuple],
-) -> bool:
-    """Required pod affinity/anti-affinity + symmetric anti-affinity of
-    existing pods, the used subset of vendored InterPodAffinityMatches."""
-    node_obj = node.node
-
-    def domain_pods(topology_key: str) -> List[core.Pod]:
-        """Existing pods whose node shares the candidate's topology domain."""
-        out = []
-        for ep, nn in assigned_pods:
-            other = all_nodes.get(nn)
-            other_node = other.node if other is not None else None
-            if _same_topology(node_obj, other_node, topology_key):
-                out.append(ep)
-        return out
-
-    # Required affinity: each term needs >=1 matching pod in the domain.
-    for term in _affinity_terms(pod, "podAffinity"):
-        pods = domain_pods(term.get("topologyKey", DEFAULT_TOPOLOGY_KEY))
-        if not any(_term_matches_pod(term, pod, ep) for ep in pods):
-            return False
-
-    # Required anti-affinity: no matching pod in the domain.
-    for term in _affinity_terms(pod, "podAntiAffinity"):
-        pods = domain_pods(term.get("topologyKey", DEFAULT_TOPOLOGY_KEY))
-        if any(_term_matches_pod(term, pod, ep) for ep in pods if ep is not pod):
-            return False
-
-    # Symmetry: existing pods' required anti-affinity must not match the
-    # incoming pod within their topology domain.
-    for ep, nn in assigned_pods:
-        if ep is pod:
-            continue
-        for term in _affinity_terms(ep, "podAntiAffinity"):
-            other = all_nodes.get(nn)
-            other_node = other.node if other is not None else None
-            if _same_topology(node_obj, other_node, term.get("topologyKey", DEFAULT_TOPOLOGY_KEY)):
-                if _term_matches_pod(term, ep, pod):
-                    return False
-    return True
 
 
 def inter_pod_affinity_score(

@@ -118,6 +118,7 @@ SPAN_NAMES = (
     "vtpu/allocate.order",  # jax-allocate ORDER
     "vtpu/allocate.pack",
     "vtpu/allocate.spread_pack",  # the topology spread planes, inside the pack
+    "vtpu/allocate.affinity_pack",  # the anti-affinity classes, inside the pack
     "vtpu/allocate.dispatch",  # the kernel's executor call
     "vtpu/allocate.explain",  # the reason-count reduction (ops/explain)
     "vtpu/allocate.apply",  # jax-allocate APPLY
