@@ -596,7 +596,6 @@ def bench_action(name: str, kwargs: dict, iters: int = 3) -> dict:
     # untimed flush below drains the plane before binds are counted and
     # reverted (the commit barrier the next snapshot would impose).
     cache = fresh_cache()
-    cache.snapshot_reuse = True
     cache.enable_pipelined_commit()
     orig_tis = capture_task_infos(cache)
     open_times, exec_times = [], []

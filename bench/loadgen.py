@@ -196,7 +196,6 @@ class LoadgenTopology:
             client=SchedulerClient(self.sched_remote),
             scheduler_name="volcano-tpu",
             pipelined_commit=True,
-            snapshot_reuse=True,
         )
         self.scheduler = Scheduler(
             self.cache, scheduler_conf_path=conf_path, period=period,
@@ -322,7 +321,7 @@ class FederatedTopology(LoadgenTopology):
                 "--shard-lease-duration", str(lease_duration),
                 "--schedule-period", str(period),
                 "--micro-debounce-ms", str(debounce_ms),
-                "--pipelined-commit", "--snapshot-reuse",
+                "--pipelined-commit",
                 "--scheduler-conf", conf_path,
                 "--listen-port", "0",
                 *extra_flags,

@@ -138,7 +138,6 @@ def allocate_phase(kwargs: dict, warm_cycles: int = 2) -> dict:
 
     t0 = time.perf_counter()
     cache = make_cache_builder(**kwargs)()
-    cache.snapshot_reuse = True
     cache.enable_pipelined_commit()
     pristine = capture_task_infos(cache)
     setup_s = time.perf_counter() - t0

@@ -337,7 +337,7 @@ def test_static_manifest_commands_parse():
                          "--leader-elect", "--leader-elect-id",
                          "--scheduler-conf", "--schedule-period",
                          "--scheduler-name",
-                         "--snapshot-reuse", "--warmup",
+                         "--warmup",
                          "--micro-cycles", "--micro-debounce-ms",
                          "--percentage-nodes-to-find",
                          "--minimum-feasible-nodes",

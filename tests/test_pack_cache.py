@@ -377,59 +377,16 @@ def test_dirty_tracking_granularity():
     assert cache._topology_rev > topo0
 
 
-def _snapshot_state(snapshot):
-    out = {}
-    for uid, j in sorted(snapshot.jobs.items()):
-        out[("job", uid)] = (
-            j.allocated.milli_cpu,
-            j.allocated.memory,
-            j.total_request.milli_cpu,
-            sorted(j.tasks),
-            {s.name: sorted(ts) for s, ts in j.task_status_index.items()},
-            j.priority,
-        )
-    for name, n in sorted(snapshot.nodes.items()):
-        out[("node", name)] = (
-            n.idle.milli_cpu,
-            n.idle.memory,
-            n.used.milli_cpu,
-            sorted(n.tasks),
-        )
-    return out
-
-
 def test_snapshot_clone_reuse_equivalence():
-    """A snapshot_reuse=True cache must produce snapshots identical to a
-    cold-cloning cache across scheduling cycles with binds and churn."""
+    """A pooled cache must produce snapshots identical to a cold-cloning
+    cache across jax-allocate cycles with binds and churn: one case of
+    the clone pool's harness (tests/test_snapshot_pool.py)."""
+    from tests.test_snapshot_pool import late_job, run_equivalence
+
     rng = np.random.RandomState(4)
     cluster = _base_cluster(rng, n_jobs=5, gang=3, n_nodes=6)
-    cache_a = make_cache(**copy.deepcopy(cluster))
-    cache_b = make_cache(**copy.deepcopy(cluster))
-    cache_a.snapshot_reuse = True
-
-    action = JaxAllocateAction()
-    for cycle in range(3):
-        ssn_a = open_session(cache_a, STANDARD(), [])
-        ssn_b = open_session(cache_b, STANDARD(), [])
-        assert _snapshot_state(ssn_a) == _snapshot_state(ssn_b), f"cycle {cycle}"
-        action.execute(ssn_a)
-        action.execute(ssn_b)
-        close_session(ssn_a)
-        close_session(ssn_b)
-        # churn: one more pending job arriving between cycles (same uids
-        # on both caches — the builders mint fresh ones per call)
-        pg = build_pod_group("ns", f"late{cycle}", 1, queue="q")
-        pod = build_pod("ns", f"late{cycle}-t0", "",
-                        {"cpu": "1", "memory": "1Gi"}, group=f"late{cycle}")
-        for c in (cache_a, cache_b):
-            c.add_pod_group(copy.deepcopy(pg))
-            c.add_pod(copy.deepcopy(pod))
-    # final snapshots agree too
-    ssn_a = open_session(cache_a, STANDARD(), [])
-    ssn_b = open_session(cache_b, STANDARD(), [])
-    assert _snapshot_state(ssn_a) == _snapshot_state(ssn_b)
-    close_session(ssn_a)
-    close_session(ssn_b)
+    run_equivalence([JaxAllocateAction()], cluster, cycles=4,
+                    churn=late_job, tier_conf=STANDARD)
 
 
 def test_kernels_identical_with_staged_planes():

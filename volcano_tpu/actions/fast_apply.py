@@ -288,6 +288,13 @@ def try_fast_apply(
                 _seq_add_scalars(qacc, scal, (1,))
 
     # ---- mutate: everything above validated, nothing mutated yet ----
+    # the session-side touched sets feed the cache's snapshot clone
+    # pool: marked before the first write, so a raise below cannot hand
+    # a written clone back
+    if hasattr(ssn, "touched_jobs"):
+        ssn.touched_jobs.update(job_accs)
+        ssn.touched_nodes.update(node_rows)
+        ssn.node_state_epoch += 1
     binding = TaskStatus.Binding
     for host, rows in node_rows.items():
         node = nodes_by_name[host]
@@ -396,11 +403,6 @@ def try_fast_apply(
     if ssn._trace.enabled:
         for t in bulk:
             ssn._trace.decision("bind", t.uid, t.node_name)
-    # the session-side touched sets feed the cache's snapshot clone pool
-    if hasattr(ssn, "touched_jobs"):
-        ssn.touched_jobs.update(job_accs)
-        ssn.touched_nodes.update(node_rows)
-        ssn.node_state_epoch += 1
     return len(bulk) == len(ordered)
 
 

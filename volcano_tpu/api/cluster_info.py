@@ -25,8 +25,8 @@ class ClusterInfo:
         #: consumed revision (cache/cache.py); None for caches that do
         #: not track dirtiness (tests' fakes, custom Cache impls).
         self.pack_epoch = None
-        #: clone-pool generation for opt-in snapshot reuse (cache.snapshot
-        #: ↔ cache.release_session_clones handshake)
+        #: clone-pool generation (cache.snapshot ↔
+        #: cache.release_session_clones handshake)
         self.clone_gen: int = 0
 
     def __repr__(self) -> str:

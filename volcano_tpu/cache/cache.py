@@ -159,7 +159,6 @@ class SchedulerCache(Cache):
         default_priority: int = 0,
         sync_side_effects: bool = True,
         client=None,
-        snapshot_reuse: bool = False,
         pipelined_commit: bool = False,
         commit_workers: int = 2,
     ):
@@ -243,21 +242,20 @@ class SchedulerCache(Cache):
         self._dirty_nodes: Dict[str, int] = {}  # guarded-by: self._mutex
         self._dirty_nodes_full: Dict[str, int] = {}  # guarded-by: self._mutex
         #: per-object last-mutation revision (never cleared — validity
-        #: stamps for the opt-in snapshot clone pool below)
+        #: stamps for the snapshot clone pool below)
         self._job_mut_rev: Dict[str, int] = {}  # guarded-by: self._mutex
         self._node_mut_rev: Dict[str, int] = {}  # guarded-by: self._mutex
         #: lazily built cycle-persistent packer; jax-allocate picks it up
         #: through the session's cache reference
         self._pack_cache = None
 
-        # ---- opt-in snapshot clone reuse ----
-        #: when True, snapshot() reuses the previous session's clones for
-        #: objects that session left untouched AND the cache has not
+        # ---- snapshot clone pool ----
+        #: snapshot() reuses the previous session's clone of every
+        #: object that session left untouched AND the cache has not
         #: mutated since — the handshake is close_session →
-        #: release_session_clones.  Off by default: correctness relies on
-        #: the session-side touched-set discipline, which custom actions
-        #: outside the shipped set may not follow.
-        self.snapshot_reuse = snapshot_reuse
+        #: release_session_clones.  ``_pool_open`` is set while a
+        #: session holds the handed clones: a session that never hands
+        #: them back leaves it set, and the next snapshot clones all.
         self._clone_gen = 0
         self._handed_nodes: Dict[str, NodeInfo] = {}
         self._handed_jobs: Dict[str, JobInfo] = {}
@@ -854,10 +852,30 @@ class SchedulerCache(Cache):
     # ---- snapshot (cache.go:712-790) ----
 
     def snapshot(self, scope: str = "full") -> ClusterInfo:
-        with trace.span("vtpu/cache.snapshot", "cache", scope=scope):
-            return self._snapshot(scope)
+        """The world a session computes on: a private clone of every
+        ready node and every schedulable job.
 
-    def _snapshot(self, scope: str) -> ClusterInfo:
+        A clone the previous session handed back through
+        :meth:`release_session_clones` is handed out again instead of a
+        fresh one when the cache has not mutated its object since.  The
+        contract that makes this exact: a session writes to a job or
+        node clone (its tasks, its ``Resource`` accumulators, its fit
+        errors) only through a path that first adds its uid or name to
+        ``ssn.touched_jobs`` / ``ssn.touched_nodes``; close_session
+        hands back only the untouched clones, and only when no action
+        raised.  The span ``vtpu/cache.snapshot`` carries how many of
+        each kind were reused and cloned (``jobs_reused``,
+        ``jobs_cloned``, ``nodes_reused``, ``nodes_cloned``), and
+        ``volcano_snapshot_clones_total{kind,path}`` adds them up."""
+        with trace.span("vtpu/cache.snapshot", "cache", scope=scope) as sp:
+            snapshot, counts = self._snapshot(scope)
+            sp.set(**counts)
+        from volcano_tpu.metrics import metrics
+
+        metrics.register_snapshot_clones(counts)
+        return snapshot
+
+    def _snapshot(self, scope: str):
         # ``scope`` is the incremental-session seam:
         #   "full"       — every job (the classic snapshot);
         #   "restricted" — clone ONLY jobs with schedulable work
@@ -893,8 +911,12 @@ class SchedulerCache(Cache):
             self._bound_dirty()
 
             pool_n, pool_j = {}, {}
-            if self.snapshot_reuse and not self._pool_open and self._pool_rev >= 0:
+            if not self._pool_open and self._pool_rev >= 0:
                 pool_n, pool_j = self._pool_nodes, self._pool_jobs
+            pool_rev = self._pool_rev
+            unstamped = self._rev + 1
+            node_rev, job_rev = self._node_mut_rev, self._job_mut_rev
+            nodes_reused = jobs_reused = 0
 
             for node in self.nodes.values():
                 if not node.ready():
@@ -902,10 +924,10 @@ class SchedulerCache(Cache):
                 pooled = pool_n.get(node.name)
                 if (
                     pooled is not None
-                    and self._node_mut_rev.get(node.name, self._rev + 1)
-                    <= self._pool_rev
+                    and node_rev.get(node.name, unstamped) <= pool_rev
                 ):
                     snapshot.nodes[node.name] = pooled
+                    nodes_reused += 1
                 else:
                     snapshot.nodes[node.name] = node.clone()
 
@@ -940,10 +962,10 @@ class SchedulerCache(Cache):
                 pooled = pool_j.get(job.uid)
                 if (
                     pooled is not None
-                    and self._job_mut_rev.get(job.uid, self._rev + 1)
-                    <= self._pool_rev
+                    and job_rev.get(job.uid, unstamped) <= pool_rev
                 ):
                     snapshot.jobs[job.uid] = pooled
+                    jobs_reused += 1
                 else:
                     snapshot.jobs[job.uid] = job.clone()
                 # re-stamped even on pooled clones: priority classes are
@@ -966,28 +988,41 @@ class SchedulerCache(Cache):
                         self.share_ledger.schedulable_uids()
                         & set(snapshot.jobs)
                     )
-            if self.snapshot_reuse:
-                self._clone_gen += 1
-                snapshot.clone_gen = self._clone_gen
-                self._handed_nodes = dict(snapshot.nodes)
-                self._handed_jobs = dict(snapshot.jobs)
-                self._handed_rev = self._rev
-                self._pool_nodes = {}
-                self._pool_jobs = {}
-                self._pool_rev = -1
-                self._pool_open = True
+            self._clone_gen += 1
+            snapshot.clone_gen = self._clone_gen
+            self._handed_nodes = dict(snapshot.nodes)
+            self._handed_jobs = dict(snapshot.jobs)
+            self._handed_rev = self._rev
+            self._pool_nodes = {}
+            self._pool_jobs = {}
+            self._pool_rev = -1
+            self._pool_open = True
 
-            return snapshot
+        counts = {
+            "jobs_reused": jobs_reused,
+            "jobs_cloned": len(snapshot.jobs) - jobs_reused,
+            "nodes_reused": nodes_reused,
+            "nodes_cloned": len(snapshot.nodes) - nodes_reused,
+        }
+        return snapshot, counts
+
+    def _drop_clone_pool(self) -> None:
+        """Make the next snapshot clone every object (a cold snapshot to
+        compare against): the pool stays closed, and a session still out
+        hands nothing back, its generation being stale."""
+        with self._mutex:
+            self._clone_gen += 1
+            self._pool_open = True
 
     def release_session_clones(
         self, clone_gen: int, touched_jobs, touched_nodes
     ) -> None:
         """close_session hands back the session's untouched clones so the
-        next snapshot can reuse them (opt-in, ``snapshot_reuse=True``).
-        ``touched_*`` are the session's mutation sets — anything in them
-        (or from a stale generation) is simply dropped."""
+        next snapshot can reuse them.  ``touched_*`` are the session's
+        mutation sets — anything in them (or from a stale generation) is
+        simply dropped."""
         with self._mutex:
-            if not self.snapshot_reuse or clone_gen != self._clone_gen:
+            if clone_gen != self._clone_gen:
                 return
             self._pool_nodes = {
                 name: cl

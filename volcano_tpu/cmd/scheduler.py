@@ -38,7 +38,6 @@ class SchedulerDaemon(BaseDaemon):
         scheduler_conf: str = "",
         schedule_period: float = 1.0,
         scheduler_name: str = "volcano-tpu",
-        snapshot_reuse: bool = False,
         cycle_deadline_ms=None,
         pipelined_commit: bool = False,
         micro_cycles: bool = False,
@@ -80,7 +79,6 @@ class SchedulerDaemon(BaseDaemon):
                 micro_debounce_ms=micro_debounce_ms,
                 lease_duration=shard_lease_duration,
                 pipelined_commit=pipelined_commit,
-                snapshot_reuse=snapshot_reuse,
                 scheduler_name=scheduler_name,
                 gang_broker=gang_broker,
                 kill_mode="exit",  # shard.kill hard-exits the process
@@ -98,7 +96,6 @@ class SchedulerDaemon(BaseDaemon):
         self.cache = SchedulerCache(
             client=SchedulerClient(api),
             scheduler_name=scheduler_name,
-            snapshot_reuse=snapshot_reuse,
             pipelined_commit=pipelined_commit,
         )
         self.scheduler = Scheduler(
@@ -204,12 +201,6 @@ def main(argv=None) -> int:
     parser.add_argument("--scheduler-conf", default="")
     parser.add_argument("--schedule-period", type=float, default=1.0)
     parser.add_argument("--scheduler-name", default="volcano-tpu")
-    parser.add_argument(
-        "--snapshot-reuse", action="store_true",
-        help="reuse the previous session's untouched clones at session "
-        "open (warm-cycle optimization; relies on the shipped actions' "
-        "touched-set discipline — leave off with out-of-tree actions)",
-    )
     parser.add_argument(
         "--pipelined-commit", action="store_true",
         help="overlap the commit path (binds, evictions, status "
@@ -391,7 +382,6 @@ def main(argv=None) -> int:
             scheduler_conf=args.scheduler_conf,
             schedule_period=args.schedule_period,
             scheduler_name=args.scheduler_name,
-            snapshot_reuse=args.snapshot_reuse,
             cycle_deadline_ms=args.cycle_deadline_ms or None,
             pipelined_commit=args.pipelined_commit,
             micro_cycles=args.micro_cycles,

@@ -61,7 +61,6 @@ class FederatedScheduler:
         lease_duration: float = 2.0,
         lease_retry_period: float = 0.2,
         pipelined_commit: bool = False,
-        snapshot_reuse: bool = False,
         scheduler_name: str = "volcano-tpu",
         spill_after: int = 2,
         gang_broker: bool = True,
@@ -80,7 +79,6 @@ class FederatedScheduler:
             client=self.client,
             scheduler_name=scheduler_name,
             pipelined_commit=pipelined_commit,
-            snapshot_reuse=snapshot_reuse,
         )
         self.state = ShardState(n_shards)
         self.filter = ShardInformerFilter(self.cache, self.state, lister=api)

@@ -156,9 +156,9 @@ def _close_session(ssn: Session) -> None:
     with trace.span("vtpu/session.job_updater", "framework"):
         JobUpdater(ssn).update_all()
 
-    # hand untouched clones back for reuse by the next snapshot (no-op
-    # unless the cache opted into snapshot_reuse) — after plugin closes
-    # and the job updater, which are the last clone-mutating steps
+    # hand untouched clones back for reuse by the next snapshot — after
+    # plugin closes and the job updater, which are the last
+    # clone-mutating steps; a raise in either hands nothing back
     release = getattr(ssn.cache, "release_session_clones", None)
     if release is not None:
         release(ssn.clone_gen, ssn.touched_jobs, ssn.touched_nodes)

@@ -47,6 +47,17 @@ ValidateExFn = Callable[[object], Optional[ValidateResult]]
 
 
 class Session:
+    """One cycle's view of the cluster: the snapshot's clones plus the
+    plugins' callbacks.
+
+    The clones may outlive the session: close_session hands every job
+    and node clone not named in ``touched_jobs`` / ``touched_nodes``
+    back to the cache, and the next snapshot hands it out again.  So
+    any code that writes to a job, task or node clone (status, node
+    name, ``Resource`` accumulators, fit errors) must add the job's uid
+    or the node's name to those sets first, as the session and
+    Statement ops, the drive loops, the bulk apply and gang's close do."""
+
     def __init__(self, cache: Cache):
         self.uid: str = str(uuid.uuid4())
         self.cache = cache

@@ -353,6 +353,19 @@ def register_job_status_writes(path: str, n: int) -> None:
     registry.inc(f"{_NAMESPACE}_job_status_writes_total", {"path": path}, n)
 
 
+def register_snapshot_clones(counts: Dict[str, int]) -> None:
+    """volcano_snapshot_clones_total{kind,path}: the objects a session
+    snapshot handed out, kind ∈ {job, node}, path ∈ {reused, cloned} —
+    reused is a clone the previous session handed back untouched
+    (cache/cache.py snapshot), cloned a fresh one."""
+    for kind in ("job", "node"):
+        for path in ("reused", "cloned"):
+            registry.inc(
+                f"{_NAMESPACE}_snapshot_clones_total",
+                {"kind": kind, "path": path}, counts[f"{kind}s_{path}"],
+            )
+
+
 def update_pod_schedule_status(status: str, count: int = 1) -> None:
     """metrics.go pod_schedule_successes/errors: pods whose bind effect
     landed (or failed to land) on the bus.  status ∈ {successes,

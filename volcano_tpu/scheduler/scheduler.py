@@ -384,6 +384,13 @@ class Scheduler:
                         # raised inside the try so close_session still
                         # runs for the (real) restricted session
                         raise subgraph.ShadowDivergence(diffs)
+        except BaseException:
+            # an action that raised may have written a clone it never
+            # marked touched: a stale generation hands nothing back, so
+            # the next snapshot clones every object
+            if ssn is not None:
+                ssn.clone_gen = 0
+            raise
         finally:
             try:
                 # ssn is None when open_session itself crashed (a plugin
