@@ -168,7 +168,7 @@ def _pipelined_cycle_s(dispatch, k: int = 8, iters: int = 3) -> "float | None":
         prev = None
         t0 = time.perf_counter()
         for _ in range(k):
-            cur = dispatch()          # cycle N+1 dispatches...
+            cur = dispatch()[0]       # cycle N+1 dispatches...
             if prev is not None:
                 np.asarray(prev)      # ...while cycle N's result commits
             prev = cur
@@ -196,7 +196,7 @@ def _pipelined_compute_s(dispatch, k: int = 16, iters: int = 3) -> "float | None
     def run_n(n):
         out = None
         for _ in range(n):
-            out = dispatch()
+            out = dispatch()[0]
         out.block_until_ready()
 
     run_n(1)  # warm any remaining compile/dispatch setup

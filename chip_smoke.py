@@ -254,7 +254,8 @@ def device_busy(trace_dir: str) -> dict:
 
 def sharded_phase(kwargs: dict, devices) -> dict:
     """run_packed_sharded on a mesh of ``devices`` vs native; returns
-    info."""
+    info.  The jobs are of one pod each: the sharded formulation takes no
+    session with multi-task gangs (ops/dispatch.py)."""
     import jax
     from jax.sharding import Mesh
 
@@ -262,7 +263,7 @@ def sharded_phase(kwargs: dict, devices) -> dict:
     from volcano_tpu.ops.sharded import run_packed_sharded
     from volcano_tpu.ops.synthetic import generate_snapshot
 
-    snap = generate_snapshot(**kwargs)
+    snap = generate_snapshot(**{**kwargs, "gang_size": 1})
     mesh = Mesh(np.array(devices), ("nodes",))
     reference = native.baseline_allocate(snap)
     t0 = time.perf_counter()

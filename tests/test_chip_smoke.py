@@ -50,8 +50,8 @@ def smoke(monkeypatch):
     monkeypatch.setattr(dispatch, "_tpu_available", lambda: True)
     monkeypatch.setattr(dispatch, "_SMALL_AREA", 0)
     monkeypatch.setattr(
-        pallas_session, "run_packed_pallas",
-        functools.partial(pallas_session.run_packed_pallas, interpret=True),
+        pallas_session, "run_outcome_pallas",
+        functools.partial(pallas_session.run_outcome_pallas, interpret=True),
     )
     monkeypatch.setattr(
         preempt_pallas, "run_preempt_pallas",

@@ -190,11 +190,12 @@ class TestDeviceHostEquivalence:
             dev_msg, dev_synth = dev[key]
             assert dev_msg == host_msg, key
             assert dev_synth, key
-        # the tasks the ORDER replay pruned (the tiny queue goes overused
-        # mid-replay) are explained by the tail pack, so nothing sweeps
+        # one queue: ORDER lists every task whatever the queue's share,
+        # each gang's failing task is explained at its step of the scan,
+        # and nothing sweeps or needs the tail pack
         stats = jax_allocate.last_phase_stats
         assert stats["apply_host_sweep"] == 0
-        assert stats["explain_tail_tasks"] > 0
+        assert stats["explain_tail_tasks"] == 0
         assert stats["apply_explained"] == len(host)
 
     def test_explain_off_still_records_via_host_sweep(self):
@@ -208,19 +209,26 @@ class TestDeviceHostEquivalence:
 
     def test_synthesis_refused_after_placements_still_correct(self):
         """A placeable job ahead of the stuck one: placements touch node
-        state, the synthesis gate closes, and the stuck task takes the
-        host sweep — message still present and well-formed."""
-        nodes, pods, pgs, queues = _mixed_reason_objects()
-        pods = pods + [
-            build_pod("ns", "easy-0", "", {"cpu": "1", "memory": "1Gi"},
-                      group="pg-easy"),
-        ]
-        pgs = pgs + [build_pod_group("ns", "pg-easy", 1, queue="q1")]
-        cache = make_cache(nodes, pods, pgs, queues)
+        state, so counts taken at the pack would no longer hold; the
+        stuck task's counts were taken at its step of the scan, after
+        the placement, and synthesize the host sweep's message."""
+        def objects():
+            nodes, pods, pgs, queues = _mixed_reason_objects()
+            pods = pods + [
+                build_pod("ns", "easy-0", "", {"cpu": "1", "memory": "1Gi"},
+                          group="pg-easy"),
+            ]
+            pgs = pgs + [build_pod_group("ns", "pg-easy", 1, queue="q1")]
+            return nodes, pods, pgs, queues
+
+        host_cache, cache = make_cache(*objects()), make_cache(*objects())
+        host = _run_capture(host_cache, [AllocateAction()], TIERS)
         fe_map = _run_capture(cache, [JaxAllocateAction(explain=True)], TIERS)
-        assert cache.binder.binds  # the easy pod placed
+        assert cache.binder.binds == host_cache.binder.binds  # the easy pod placed
+        assert cache.binder.binds
         msg, synth = fe_map["ns/stuck-0"]
-        assert not synth  # gate closed — host sweep ran
+        assert synth and jax_allocate.last_phase_stats["apply_host_sweep"] == 0
+        assert msg == host["ns/stuck-0"][0]
         assert parse_fit_errors(msg) is not None
 
     def test_plane_retention_attributes_per_node(self):
@@ -328,20 +336,25 @@ class TestDeviceHostEquivalence:
             'volcano_unschedulable_task_reasons{reason="'
             + reasons.NODE_TAINT_UNTOLERATED + '"} 1'
         ) in text
-        assert "volcano_explain_latency_milliseconds_count" in text
+        # the reasons were counted on the device, at the task's step of
+        # the allocate scan: no separate reduction ran
+        assert 'volcano_allocate_apply_tasks_total{path="explained"}' in text
+        assert "volcano_explain_latency_milliseconds_count" not in text
 
 
 # ---- the tail: pending tasks ORDER's share cut leaves out ----
 
 
-def _preemption_basic_mini(extra_nodes=(), extra_pods=()):
+def _preemption_basic_mini(extra_nodes=(), extra_pods=(), gang=1):
     """kube-scheduler's ``PreemptionBasic`` in miniature: 12 nodes of 4
     cpu, each holding four Running priority-1 pods of 900m, and 12
     pending priority-10 pods of 3000m in queue ``default``, every pod a
     job of its own.  ORDER's every-task-places simulation finds the
     queue over its deserved 48 cpu after 2 of them (43.2 + 2 × 3), so it
-    lists 2; APPLY, where nothing places, reaches all 12.  Extra pods
-    come after the others, each a job of its own."""
+    lists 2; APPLY, where nothing places, reaches all 12.  With
+    ``gang`` > 1 the pending pods are jobs of ``gang`` pods (minMember
+    ``gang``) and ORDER lists every one.  Extra pods come after the
+    others, each a job of its own."""
     nodes = [build_node(f"n{i:02d}", {"cpu": "4", "memory": "32Gi"})
              for i in range(12)]
     nodes += list(extra_nodes)
@@ -353,15 +366,18 @@ def _preemption_basic_mini(extra_nodes=(), extra_pods=()):
                       group=f"low-{i:02d}-{k}", priority=1)
             for k in range(4)
         ]
-    pods += [
+    high = [
         build_pod("ns", f"high-{i:02d}", "", {"cpu": "3", "memory": "500Mi"},
-                  group=f"high-{i:02d}", priority=10)
+                  group=f"high-{i // gang:02d}", priority=10)
         for i in range(12)
     ]
-    pods += list(extra_pods)
     pgs = [build_pod_group("ns", p.metadata.name, 1, queue="default")
            for p in pods]
-    return nodes, pods, pgs, [build_queue("default", weight=1)]
+    pgs += [build_pod_group("ns", f"high-{j:02d}", gang, queue="default")
+            for j in range(12 // gang)]
+    pgs += [build_pod_group("ns", p.metadata.name, 1, queue="default")
+            for p in extra_pods]
+    return nodes, pods + high + list(extra_pods), pgs, [build_queue("default", weight=1)]
 
 
 def _tail_counter() -> float:
@@ -422,6 +438,24 @@ class TestTailExplanation:
         assert _tail_counter() - before == 10
         (span,) = _tail_spans(recorder)
         assert span["args"]["tasks"] == 10
+
+    def test_single_queue_gangs_order_lists_every_task_builds_no_tail(self, recorder):
+        """One queue of gangs: ORDER lists every preemptor whatever the
+        queue's share, the kernel counts each gang's failing task's
+        reasons at its step, and no tail is built and nothing sweeps."""
+        before = _tail_counter()
+        host, host_binds, dev, dev_binds = _host_and_device(
+            lambda: _preemption_basic_mini(gang=2))
+        stats = jax_allocate.last_phase_stats
+        assert host_binds == dev_binds == {}
+        assert len(host) == 6 and dev == {k: (msg, True) for k, (msg, _) in host.items()}
+        assert {k: stats[k] for k in ("apply_host_sweep", "apply_explained",
+                                      "explain_tail_tasks", "gang_discarded",
+                                      "gang_outcomes_device")} == {
+            "apply_host_sweep": 0, "apply_explained": 6, "explain_tail_tasks": 0,
+            "gang_discarded": 6, "gang_outcomes_device": 6}
+        assert _tail_counter() == before
+        assert _tail_spans(recorder) == []
 
     def test_order_lists_every_task_builds_no_tail(self, recorder):
         """No share cut: every pending task is an ORDER row, so the tail
@@ -727,11 +761,16 @@ class TestExplainPlumbing:
         assert dispatch.last_explain_ms() is None
 
         snap = self._stuck_snapshot()
-        dispatch.run_allocate(snap, explain=True)
+        outcome = dispatch.run_allocate(snap, explain=True)
         counts = dispatch.last_explain_counts()
-        assert counts is not None and counts.shape == (snap.n_tasks, 5)
-        assert (counts.sum(axis=1) == snap.n_nodes).all()
-        assert dispatch.last_explain_ms() >= 0.0
+        # counted at each failing step of the scan: every gang's first
+        # task fails and ends it, its other tasks are never tried
+        assert dispatch.last_explain_at_step()
+        failed = outcome.placed == -1
+        assert counts is not None and counts.shape == (snap.n_tasks, 8)
+        assert failed.any() and (counts[failed].sum(axis=1) == snap.n_nodes).all()
+        assert (counts[~failed] == 0).all()
+        assert (outcome.placed[~failed] == -2).all()
 
         # without the flag nothing is reduced, and nothing stale is kept
         dispatch.run_allocate(snap, explain=False)

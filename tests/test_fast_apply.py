@@ -87,6 +87,9 @@ def _assert_state_equal(a, b):
         assert ja_.allocated.memory == jb.allocated.memory, uid
         assert ja_.total_request.milli_cpu == jb.total_request.milli_cpu, uid
         assert list(ja_.tasks) == list(jb.tasks), uid  # insertion order
+        assert {t: fe.error() for t, fe in ja_.nodes_fit_errors.items()} == {
+            t: fe.error() for t, fe in jb.nodes_fit_errors.items()
+        }, uid
         assert {
             s: set(ts) for s, ts in ja_.task_status_index.items()
         } == {s: set(ts) for s, ts in jb.task_status_index.items()}, uid
@@ -199,12 +202,16 @@ def test_fast_apply_fractional_cpu_bit_identity():
 
 
 def test_fast_apply_refuses_partial_placement():
-    # one tiny node: most gangs cannot place -> partial -> refuse
+    # one tiny node: most gangs cannot place — the kernel discards them,
+    # and the bulk path applies the discards as the slow path would
     cluster = _cluster(n_jobs=6, gang=4, n_nodes=1)
     cluster["nodes"] = [build_node("n0", {"cpu": "4", "memory": "8Gi"})]
-    cache, ssn, engaged = _run(cluster, force_slow=False)
-    assert not engaged
-    close_session(ssn)
+    cache_f, ssn_f, engaged = _run(cluster, force_slow=False)
+    assert engaged
+    cache_s, ssn_s, _ = _run(cluster, force_slow=True)
+    _assert_state_equal((cache_f, ssn_f), (cache_s, ssn_s))
+    close_session(ssn_f)
+    close_session(ssn_s)
 
 
 def test_fast_apply_refuses_pvc_pods():

@@ -258,7 +258,7 @@ class _Rung:
             self.run = lambda: dispatch.run_packed_auto(snap)
             self.reference = run_packed(snap)
             self.breaker = rung
-            self.floor = dispatch._FALLBACK[rung]
+            self.floor = dispatch._fallback(rung, snap)
             return
         pk = _small_preempt()
 
@@ -297,7 +297,7 @@ class TestDispatchDegradation:
         before = rung.fallbacks("error")
         np.testing.assert_equal(rung.run(), rung.reference)
         if entry == "allocate":
-            assert dispatch.last_executor() == "blocked"
+            assert dispatch.last_executor() == rung.floor
         assert rung.fallbacks("error") == before + 1
         # 1 < threshold
         assert faults.get_breaker(rung.breaker).state == CLOSED
@@ -353,21 +353,23 @@ class TestDispatchDegradation:
         from volcano_tpu.ops import dispatch, pallas_session
         from volcano_tpu.ops.kernels import run_packed
 
+        from volcano_tpu.ops.kernels import ScanOutcome
+
         snap = _small_snapshot()
         reference = run_packed(snap)
         self._force_pallas(monkeypatch)
         # the kernel "succeeds" but NaN score planes argmax'd to garbage
         monkeypatch.setattr(
-            pallas_session, "run_packed_pallas",
-            lambda s, weights=None, gang_rounds=3: np.zeros(
+            pallas_session, "run_outcome_pallas",
+            lambda s, weights=None: ScanOutcome.of_assignment(np.zeros(
                 s.task_resreq.shape[0], dtype=np.int32
-            ),
+            )),
         )
         faults.configure("seed=1;device.nan=1:count=1")
         out = dispatch.run_packed_auto(snap)
         np.testing.assert_array_equal(out, reference)
         assert _counter("executor_fallbacks_total",
-                        **{"from": "pallas", "to": "blocked",
+                        **{"from": "pallas", "to": dispatch._fallback("pallas", snap),
                            "cause": "corrupt-output"}) >= 1
 
     def test_assignment_validity_gate(self):
@@ -388,25 +390,31 @@ class TestDispatchDegradation:
         """A device phase the watchdog abandoned must not, when it
         finally fails, record a breaker verdict, count a fallback, or
         run the full fallback allocate against the next live cycle."""
-        from volcano_tpu.ops import blocked, dispatch, pallas_session
+        from volcano_tpu.ops import blocked, dispatch, kernels, pallas_session
 
         snap = _small_snapshot()
         self._force_pallas(monkeypatch)
+        floor = dispatch._fallback("pallas", snap)
 
-        def slow_then_fail(s, weights=None, gang_rounds=3):
+        def slow_then_fail(s, weights=None):
             time.sleep(0.2)
             raise RuntimeError("late lowering failure")
 
         ran_fallback = []
-        monkeypatch.setattr(pallas_session, "run_packed_pallas",
+        monkeypatch.setattr(pallas_session, "run_outcome_pallas",
                             slow_then_fail)
         monkeypatch.setattr(
             blocked, "run_packed_blocked",
             lambda *a, **k: ran_fallback.append(1) or
             np.full(snap.task_resreq.shape[0], -1, dtype=np.int32),
         )
+        monkeypatch.setattr(
+            kernels, "run_outcome",
+            lambda *a, **k: ran_fallback.append(1) or kernels.ScanOutcome.of_assignment(
+                np.full(snap.n_tasks, -1, dtype=np.int32)),
+        )
         before = _counter("executor_fallbacks_total",
-                          **{"from": "pallas", "to": "blocked",
+                          **{"from": "pallas", "to": floor,
                              "cause": "error"})
         with pytest.raises(CycleDeadlineExceeded):
             faults.run_with_deadline(
@@ -416,7 +424,7 @@ class TestDispatchDegradation:
         assert ran_fallback == []
         assert faults.get_breaker("pallas").state == CLOSED
         assert _counter("executor_fallbacks_total",
-                        **{"from": "pallas", "to": "blocked",
+                        **{"from": "pallas", "to": floor,
                            "cause": "error"}) == before
 
     def test_device_slow_injects_latency(self):

@@ -30,8 +30,7 @@ def test_pallas_matches_plain_with_predicates():
 
 
 def test_pallas_matches_plain_capacity_pressure():
-    """Tight capacity: infeasible tasks, gang discards, multi-round
-    fixpoint."""
+    """Tight capacity: infeasible tasks, gang discards and rollbacks."""
     snap = generate_snapshot(
         n_tasks=400, n_nodes=16, gang_size=5, seed=4,
         node_cpu_milli=16_000, node_mem_mib=32_768,
@@ -91,13 +90,13 @@ def test_make_session_dispatch_prestaged_matches_wrapper():
 
     dispatch, T_act = make_session_dispatch(
         snap, block_size=128, interpret=True, prestage=True)
-    out = np.asarray(dispatch())
+    out = np.asarray(dispatch()[0])
     got = np.full(snap.n_tasks, -1, dtype=np.int32)
     n = min(snap.n_tasks, T_act)
     got[:n] = out[:n]
     assert (want == got).all()
     # repeated dispatches (the pipelined-slope probe) stay identical
-    out2 = np.asarray(dispatch())
+    out2 = np.asarray(dispatch()[0])
     assert (np.asarray(out) == np.asarray(out2)).all()
 
 

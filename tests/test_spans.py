@@ -194,8 +194,16 @@ def _small_cluster():
         for i in range(4)
     ]
     pods.append(build_pod("ns", "big", "", {"cpu": "16", "memory": "1Gi"}, group="pg-big"))
+    # a node affinity richer than the packed bitsets keeps the session on
+    # the Statement loop, whose host sweep and commit spans are checked
+    rich = {"nodeAffinity": {"requiredDuringSchedulingIgnoredDuringExecution": {
+        "nodeSelectorTerms": [{"matchExpressions": [
+            {"key": "accel", "operator": "In", "values": ["tpu", "gpu"]}]}]}}}
+    pods.append(build_pod("ns", "rich", "", {"cpu": "1", "memory": "1Gi"},
+                          group="pg-rich", affinity=rich))
     pgs = [build_pod_group("ns", "pg", 4, queue="q1"),
-           build_pod_group("ns", "pg-big", 1, queue="q1")]
+           build_pod_group("ns", "pg-big", 1, queue="q1"),
+           build_pod_group("ns", "pg-rich", 1, queue="q1")]
     return make_cache(nodes=nodes, pods=pods, pod_groups=pgs,
                       queues=[build_queue("q1", weight=1)])
 

@@ -90,6 +90,14 @@ def test_allocate_kernel_at_headline_shape(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_allocate_kernel_at_gang_backlog_shape(one_chip):
+    """The gang-backlog cell: 11,200 workers in 1,200 gangs over 5,000
+    nodes, beside 5,000 one-pod resident jobs — the per-job state planes
+    and the copy of the node state a gang begins from."""
+    compiled = _compile_allocate(one_chip, 11264, 1, 40, J=8192)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("R", [2, 3, 4])
 @pytest.mark.parametrize("C", [1, 64])
 def test_allocate_kernel_at_admission_edge(one_chip, C, R):

@@ -316,8 +316,8 @@ def test_replay_flags_perturbed_snapshot(tmp_path):
 
 
 def test_replay_uses_recorded_kernel_params(tmp_path):
-    """A capture made with non-default weights/gang_rounds must replay
-    with those same parameters, not the defaults."""
+    """A capture made with non-default weights must replay with those
+    same weights, not the defaults."""
     from volcano_tpu.ops.kernels import ScoreWeights
 
     weights = ScoreWeights(binpack_weight=3.0, least_requested_weight=0.25)
@@ -325,8 +325,8 @@ def test_replay_uses_recorded_kernel_params(tmp_path):
     rec = TraceRecorder(journal=journal, snapshot_every=1)
     snap = generate_snapshot(n_tasks=96, n_nodes=24, gang_size=4, seed=11)
     rec.begin_cycle()
-    assignment = run_snapshot(snap, executor="jax", weights=weights, gang_rounds=5)
-    rec.capture(snap, assignment, executor="jax", weights=weights, gang_rounds=5)
+    assignment = run_snapshot(snap, executor="jax", weights=weights)
+    rec.capture(snap, assignment, executor="jax", weights=weights)
     rec.end_cycle()
 
     _, extras = journal.read_snapshot(0)
@@ -334,7 +334,7 @@ def test_replay_uses_recorded_kernel_params(tmp_path):
     assert lanes[: len(ScoreWeights._fields) - 1] == [
         float(v) for v in tuple(weights)[:-1]
     ]
-    assert int(extras["gang_rounds"]) == 5
+    assert "gang_rounds" not in extras
     assert verify(journal, executor="jax").match
 
 

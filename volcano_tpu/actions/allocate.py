@@ -202,15 +202,28 @@ def make_place_task(ssn: Session, chooser):
     return place_task
 
 
-def gang_end_job(ssn: Session):
+def gang_end_job(ssn: Session, decided=frozenset(), tally=None):
     """Commit when the gang is ready, discard otherwise
-    (allocate.go:232-236)."""
+    (allocate.go:232-236).  The first decision on each job not in
+    ``decided`` (the jobs whose decision was applied in bulk) counts as
+    a gang outcome on the host path, also into ``tally``'s
+    ``gang_committed`` / ``gang_discarded`` / ``gang_outcomes_host``."""
+    seen = set(decided)
 
     def end_job(stmt, job: JobInfo) -> None:
-        if ssn.job_ready(job):
+        ready = ssn.job_ready(job)
+        if ready:
             stmt.commit()
         else:
             stmt.discard()
+        if job.uid not in seen:
+            seen.add(job.uid)
+            outcome = "committed" if ready else "discarded"
+            metrics.register_gang_outcome(outcome, "host")
+            if tally is not None:
+                key = f"gang_{outcome}"
+                tally[key] = tally.get(key, 0) + 1
+                tally["gang_outcomes_host"] = tally.get("gang_outcomes_host", 0) + 1
 
     return end_job
 

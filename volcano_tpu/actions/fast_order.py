@@ -124,9 +124,59 @@ def _le(l: float, r: float, diff: float) -> bool:
     return l < r or abs(l - r) < diff
 
 
+class TaskOrder(list):
+    """ORDER's rows, with what the allocate kernel and APPLY need of the
+    loop's structure: ``episodes`` — the row each episode (one job pop)
+    starts at — ``single_queue`` — every eligible job is in one queue
+    and no namespace order reads shares (none is weighted, or only one
+    namespace has pending tasks) — and ``lists_all``.  In a single-queue
+    session a job that does not commit moves nothing of the order but
+    the queue's share, whose one effect is ``overused``, which ends the
+    loop: where the session also has a gang (a job with several pending
+    tasks or needing two or more placements) the simulation lists every
+    episode regardless of ``overused`` (``lists_all``), and
+    :func:`order_cut` finds where the real loop stops once the kernel
+    has decided which gangs commit.  A session of one-pod jobs keeps
+    the simulation's stop at ``overused``, so its rows, and the executor
+    their area selects, are what they were before gangs ran in the
+    scan."""
+
+    def __init__(self, single_queue: bool = False, lists_all: bool = False):
+        super().__init__()
+        self.episodes: List[int] = []
+        self.single_queue = single_queue
+        self.lists_all = lists_all
+
+
+def order_cut(ssn: Session, order: "TaskOrder", committed) -> int:
+    """The first row of ``order`` the real loop never reaches in a
+    single-queue session: the start of the first episode at whose pop
+    the queue is overused (proportion's check, over the resources of
+    the placements that committed before it — ``committed[i]`` per
+    row); ``len(order)`` when there is none."""
+    n = len(order)
+    proportion = ssn.plugins.get("proportion")
+    if not n or not order.single_queue or proportion is None:
+        return n
+    if "proportion" not in ssn.overused_fns:
+        return n
+    attr = proportion.queue_opts.get(ssn.jobs[order[0].job].queue)
+    if attr is None:
+        return n
+    share = _Share(attr.allocated, attr.share, attr.deserved)
+    ends = order.episodes[1:] + [n]
+    for start, end in zip(order.episodes, ends):
+        if share.overused():
+            return start
+        for i in range(start, end):
+            if committed[i]:
+                share.add_task(order[i].resreq)
+    return n
+
+
 def try_compute_task_order(ssn: Session) -> Optional[List[TaskInfo]]:
-    """Simulated pop order, or None when the session's ordering semantics
-    fall outside the modeled shape."""
+    """Simulated pop order (a :class:`TaskOrder`), or None when the
+    session's ordering semantics fall outside the modeled shape."""
     job_chain = _chain(ssn, ssn.job_order_fns, "enabled_job_order")
     task_chain = _chain(ssn, ssn.task_order_fns, "enabled_task_order")
     queue_chain = _chain(ssn, ssn.queue_order_fns, "enabled_queue_order")
@@ -165,7 +215,18 @@ def try_compute_task_order(ssn: Session) -> Optional[List[TaskInfo]]:
 
     jobs = list(eligible_jobs(ssn))
     if not jobs:
-        return []
+        return TaskOrder()
+    # one queue, and namespace shares decide nothing: no weighted
+    # namespace order, or one namespace with pending work
+    single_queue = len({job.queue for job in jobs}) == 1 and (
+        not use_ns_drf
+        or len({j.namespace for j in jobs if j.task_status_index.get(TaskStatus.Pending)}) <= 1
+    )
+    lists_all = single_queue and any(
+        len(j.task_status_index.get(TaskStatus.Pending, {})) > 1
+        or j.min_available - j.ready_task_num() >= 2
+        for j in jobs
+    )
 
     job_shares: Dict[str, _Share] = {}
     if use_drf:
@@ -267,7 +328,7 @@ def try_compute_task_order(ssn: Session) -> Optional[List[TaskInfo]]:
             tasks.sort(key=lambda t: (t.creation_timestamp, t.uid))
         return tasks
 
-    order: List[TaskInfo] = []
+    order = TaskOrder(single_queue, lists_all)
 
     while ns_heap:
         namespace = heapq.heappop(ns_heap)[-1]
@@ -282,7 +343,7 @@ def try_compute_task_order(ssn: Session) -> Optional[List[TaskInfo]]:
         for queue_id in list(queue_in_namespace):
             qinfo = ssn.queues[queue_id]
             qs = queue_shares.get(queue_id)
-            if qs is not None and qs.overused():
+            if qs is not None and not lists_all and qs.overused():
                 del queue_in_namespace[queue_id]
                 continue
             if queue is None:
@@ -322,6 +383,8 @@ def try_compute_task_order(ssn: Session) -> Optional[List[TaskInfo]]:
         jshare = job_shares.get(job.uid)
         qshare = queue_shares.get(job.queue) if use_proportion else None
         nshare = ns_shares.get(namespace) if use_ns_drf else None
+        if tasks:
+            order.episodes.append(len(order))
         while consumed < len(tasks):
             task = tasks[consumed]
             consumed += 1

@@ -36,7 +36,7 @@ keeps the result valid — semantics never degrade below the host action.
 from __future__ import annotations
 
 import os
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -73,10 +73,14 @@ class _ExplainContext:
         the host chain's pressure checks have no device plane);
       * the task row is bitset-exact and memory-exact
         (ops.explain.task_exactly_encoded);
-      * no placement has mutated node state since the pack — after a
-        placement the host's pop-time first-failure can shift to a
-        resource-fit failure the snapshot-time counts predate, so those
-        tasks take the host sweep instead.
+      * the counts hold for the node state the host sees: either the
+        kernel counted them at the task's failing step of its scan
+        (``at_step``) and APPLY has followed the kernel's placements so
+        far (``diverged`` unset), or they are the pack's and no
+        placement has mutated node state since — after a placement the
+        host's pop-time first-failure can shift to a resource-fit
+        failure the snapshot-time counts predate, so those tasks take
+        the host sweep instead.
 
     APPLY reaches pending tasks ORDER never listed when ORDER's
     every-task-places simulation used up a queue's share that the real
@@ -89,7 +93,7 @@ class _ExplainContext:
     """
 
     def __init__(self, ssn: Session, snap, counts, ordered, nodes,
-                 planes=None):
+                 planes=None, at_step=False):
         from volcano_tpu.ops.explain import (
             ExplainResult,
             session_explain_compatible,
@@ -102,6 +106,11 @@ class _ExplainContext:
         self.nodes = nodes
         self.node_names = [n.name for n in nodes]
         self.enabled = session_explain_compatible(ssn)
+        #: counts taken at each row's failing step of the scan
+        self.at_step = at_step
+        #: set once APPLY placed a task elsewhere than the kernel did,
+        #: or committed out of ORDER: the step counts no longer hold
+        self.diverged = False
         #: planes given → the tail retains its own too
         self.retain_planes = planes is not None
         #: node-state epoch at pack time — any later mutation (even of a
@@ -161,13 +170,17 @@ class _ExplainContext:
         metrics.register_explain_tail(len(tasks))
         return snap, result, {t.uid: i for i, t in enumerate(tasks)}
 
-    def try_explain(self, task: TaskInfo):
-        """FitErrors for ``task`` when the device counts prove it
-        unschedulable everywhere — None sends the caller to the host
-        sweep."""
+    def _proven(self, task: TaskInfo):
+        """(result, row) when the device counts prove ``task`` fits no
+        node at the state the host is at, else None."""
         from volcano_tpu.ops.explain import task_exactly_encoded
 
-        if not self.enabled or self.ssn.node_state_epoch != self._epoch0:
+        if not self.enabled:
+            return None
+        if self.at_step and task.uid in self.index:
+            if self.diverged:
+                return None
+        elif self.ssn.node_state_epoch != self._epoch0:
             return None
         found = self._row(task.uid)
         if found is None:
@@ -179,6 +192,25 @@ class _ExplainContext:
             return None
         if not result.all_infeasible(i):
             return None
+        return result, i
+
+    def can_explain(self, task: TaskInfo) -> bool:
+        """Would :meth:`record` find ``task``'s fit errors now?"""
+        return self._proven(task) is not None
+
+    def record(self, task: TaskInfo, job) -> None:
+        """Write ``task``'s fit errors onto ``job``, as the host sweep
+        would; :meth:`can_explain` said it can."""
+        job.nodes_fit_errors[task.uid] = self.try_explain(task)
+
+    def try_explain(self, task: TaskInfo):
+        """FitErrors for ``task`` when the device counts prove it
+        unschedulable everywhere — None sends the caller to the host
+        sweep."""
+        found = self._proven(task)
+        if found is None:
+            return None
+        result, i = found
         hist = result.histogram(i)
         self.explained[task.uid] = hist
         for reason in hist:
@@ -263,23 +295,74 @@ def _spread_cut(snap) -> int:
     return int(np.argmax(host[: snap.n_tasks]))
 
 
+def _order_cut(ssn: Session, ordered, outcome) -> Tuple[int, bool]:
+    """(rows of ORDER the real loop runs as the kernel did, whether
+    nothing past them needs the host).  ORDER simulated every task
+    placing; a discarded or ended gang leaves its queue's share below
+    that.  In a single-queue session the share's one effect is
+    ``overused``, which ends the loop.  Where ORDER ``lists_all``, the
+    rows from the first episode the real queue is overused at are never
+    reached.  Otherwise ORDER stopped where its share ran out; the real
+    share, never above it, cannot stop the loop sooner, and the loop
+    goes on past ORDER's last row, to the host, unless everything
+    committed as simulated.  Elsewhere shares decide between queues, so
+    every row after the first gang that did not fully place takes the
+    host chooser."""
+    from volcano_tpu.actions.fast_order import TaskOrder, order_cut
+
+    n = len(ordered)
+    committed = outcome.assignment[:n] >= 0
+    if isinstance(ordered, TaskOrder) and ordered.single_queue:
+        if ordered.lists_all:
+            return order_cut(ssn, ordered, committed), True
+        return n, bool(committed.all())
+    short = np.nonzero(~committed)[0]
+    if not short.size:
+        return n, True
+    i = int(short[0])
+    job = ordered[i].job
+    while i < n and ordered[i].job == job:
+        i += 1
+    metrics.register_order_cut(n - i)
+    return i, False
+
+
 #: phase timings of the most recent execute() — read by bench.py right
 #: after the call, same single-threaded discipline as dispatch state
 last_phase_stats: Dict[str, float] = {}
+
+class _Decided(NamedTuple):
+    """The kernel's decisions on ORDER's rows: the proposals it made,
+    its ScanOutcome, the rows the real loop runs as the kernel did
+    (``cut``), whether nothing past them needs the host, and the reason
+    counts of the rows that fit no node (taken at each row's step of the
+    scan when ``at_step``)."""
+
+    proposals: Dict[str, str]
+    outcome: object
+    cut: int
+    complete: bool
+    counts: Optional[np.ndarray]
+    at_step: bool
+
+
+#: the gang outcome counts APPLY reports every cycle, both paths summed
+_GANG_KEYS = ("gang_committed", "gang_discarded", "gang_outcomes_device",
+              "gang_outcomes_host")
 
 
 class JaxAllocateAction(Action):
     def __init__(
         self,
         weights=None,
-        gang_rounds: int = 3,
         explain: Optional[bool] = None,
         explain_planes: Optional[bool] = None,
     ):
         from volcano_tpu.ops.kernels import DEFAULT_WEIGHTS
 
         self.weights = weights or DEFAULT_WEIGHTS
-        self.gang_rounds = gang_rounds
+        #: what the last _kernel_proposals decided, beside its proposals
+        self._decided: Optional[_Decided] = None
         from volcano_tpu.ops.explain import explain_enabled
 
         #: device-derived unschedulability explanations (ops/explain).
@@ -316,6 +399,7 @@ class JaxAllocateAction(Action):
         encode (needs_host_validation) are safe regardless: phase 3
         validates every proposal against the full host predicate set."""
         from volcano_tpu.ops import dispatch
+        from volcano_tpu.ops.kernels import NOT_REACHED
         from volcano_tpu.ops.packing import pack_session
 
         jobs = {}
@@ -393,10 +477,8 @@ class JaxAllocateAction(Action):
             with trace.span(
                 "vtpu/allocate.dispatch", "kernel", label="kernel:execute"
             ) as sp:
-                assignment = dispatch.run_allocate(
-                    snap, weights=self.weights, gang_rounds=self.gang_rounds,
-                    explain=self.explain,
-                )
+                outcome = dispatch.run_allocate(
+                    snap, weights=self.weights, explain=self.explain)
         except CycleDeadlineExceeded as e:
             # cycle watchdog: the device phase overran its budget and
             # was abandoned.  Nothing session-side has mutated (the
@@ -411,11 +493,16 @@ class JaxAllocateAction(Action):
                           error=str(e))
             return {}, snap
         metrics.update_kernel_duration("execute", sp.seconds)
+        # reason counts of the rows that fit no node, and whether they
+        # were taken at each row's step of the scan
+        counts = dispatch.last_explain_counts()
+        at_step = dispatch.last_explain_at_step()
         # the shape the kernel ran at, for work counts per dispatch
         last_phase_stats.update(
             kernel_tasks=snap.n_tasks,
             kernel_nodes=snap.n_nodes,
             kernel_resources=int(snap.task_resreq.shape[1]),
+            kernel_max_gang=int(max(snap.gang_need().max(initial=0), 0)),
         )
 
         rec = ssn._trace
@@ -429,16 +516,37 @@ class JaxAllocateAction(Action):
 
             rec.capture(
                 snap,
-                assignment,
+                outcome.assignment,
                 executor=replay_executor_name(dispatch.last_executor()),
                 weights=self.weights,
-                gang_rounds=self.gang_rounds,
             )
 
+        # where the real loop leaves ORDER's rows, now that the kernel
+        # decided which gangs commit
+        cut, complete = _order_cut(ssn, ordered_tasks, outcome)
+        spread_cut = _spread_cut(snap)
+        complete = complete and spread_cut >= cut
+        placed = outcome.placed
+        rolled = (placed >= 0) & (outcome.assignment < 0)
+        rollback = int(rolled[:cut].sum())
+        last_phase_stats["gang_rollback_tasks"] = rollback
+        metrics.register_gang_rollback(rollback)
+        # the scan's gang work, for work counts per dispatch
+        rows_job = snap.task_job[: snap.n_tasks]
+        last_phase_stats.update(
+            kernel_skipped_rows=(last_phase_stats["kernel_tasks"]
+                                 - int((placed != NOT_REACHED).sum())),
+            kernel_failed_rows=int((placed == -1).sum()),
+            kernel_gang_runs=int(np.unique(rows_job[snap.gang_need() >= 2]).size),
+            kernel_rollback_runs=int(np.unique(rows_job[rolled]).size),
+        )
+        # every row the scan placed is proposed, a discarded gang's too:
+        # the Statement loop then tries them where the kernel did
         proposals = {}
-        for i, task in enumerate(ordered_tasks[: _spread_cut(snap)]):
-            if assignment[i] >= 0 and not snap.task_has_preferences[i]:
-                proposals[task.uid] = nodes[assignment[i]].name
+        for i, task in enumerate(ordered_tasks[: min(cut, spread_cut)]):
+            if placed[i] >= 0 and not snap.task_has_preferences[i]:
+                proposals[task.uid] = nodes[placed[i]].name
+        self._decided = _Decided(proposals, outcome, cut, complete, counts, at_step)
         return proposals, snap
 
     # ---- phase 3 ----
@@ -485,7 +593,13 @@ class JaxAllocateAction(Action):
                 # surface so /explain never serves a previous cycle
                 self._publish_explain(ssn, None)
             return
+        self._decided = None
         proposals, snap = self._kernel_proposals(ssn, ordered, nodes, pc)
+        # the kernel's whole outcome stands beside proposals it made itself
+        decided = self._decided
+        self._decided = None
+        if decided is not None and decided.proposals is not proposals:
+            decided = None
 
         # Reason counts the dispatch produced for unplaced tasks — the
         # device-derived "why pending" source (ops/explain).
@@ -495,6 +609,9 @@ class JaxAllocateAction(Action):
             from volcano_tpu.ops import explain as _explain
 
             counts = dispatch.last_explain_counts()
+            at_step = dispatch.last_explain_at_step()
+            if decided is not None:
+                counts, at_step = decided.counts, decided.at_step
             if counts is not None:
                 planes = None
                 if self.explain_planes:
@@ -507,13 +624,14 @@ class JaxAllocateAction(Action):
                         task_rows=_np.nonzero(counts.sum(axis=1) > 0)[0],
                     ).reasons
                 explain_ctx = _ExplainContext(
-                    ssn, snap, counts, ordered, nodes, planes=planes
+                    ssn, snap, counts, ordered, nodes, planes=planes,
+                    at_step=at_step,
                 )
                 last_phase_stats["explain_ms"] = dispatch.last_explain_ms()
 
         try:
             with trace.span("vtpu/allocate.apply", "action"):
-                self._apply(ssn, ordered, proposals, snap, explain_ctx)
+                self._apply(ssn, ordered, proposals, snap, explain_ctx, decided)
         finally:
             if self.explain:
                 # also clears: a cycle that explained nothing (all
@@ -522,36 +640,60 @@ class JaxAllocateAction(Action):
                 # explanation as current
                 self._publish_explain(ssn, explain_ctx)
 
-    def _apply(self, ssn, ordered, proposals, snap, explain_ctx) -> None:
+    def _apply(self, ssn, ordered, proposals, snap, explain_ctx,
+               decided=None) -> None:
         """APPLY, counting the path that resolves each task
         (``metrics.APPLY_PATHS``) into the Prometheus counters and
         ``last_phase_stats`` (``apply_<path>``, ``apply_rejected``,
-        ``host_sweep_nodes``, ``host_sweep_ms``, and the tail
-        explanation's ``explain_tail_tasks`` and ``explain_tail_ms``)."""
+        ``host_sweep_nodes``, ``host_sweep_ms``, the gang outcomes
+        ``gang_committed``, ``gang_discarded``, ``gang_outcomes_device``
+        and ``_host``, and the tail explanation's ``explain_tail_tasks``
+        and ``explain_tail_ms``)."""
         paths = dict.fromkeys(metrics.APPLY_PATHS, 0)
         tally = {"apply_rejected": 0, "host_sweep_nodes": 0, "host_sweep_ms": 0.0}
+        for key in _GANG_KEYS:
+            last_phase_stats.setdefault(key, 0)
         try:
-            self._apply_paths(ssn, ordered, proposals, snap, explain_ctx, paths, tally)
+            self._apply_paths(ssn, ordered, proposals, snap, explain_ctx, paths,
+                              tally, decided)
         finally:
             metrics.register_allocate_apply(paths, tally["apply_rejected"])
             last_phase_stats.update({f"apply_{p}": n for p, n in paths.items()})
+            for key in _GANG_KEYS:
+                last_phase_stats[key] += tally.pop(key, 0)
             last_phase_stats.update(tally)
             last_phase_stats["explain_tail_tasks"] = explain_ctx.tail_tasks if explain_ctx else 0
             last_phase_stats["explain_tail_ms"] = explain_ctx.tail_ms if explain_ctx else 0.0
 
     def _apply_paths(self, ssn, ordered, proposals, snap, explain_ctx,
-                     paths, tally) -> None:
-        # Fully-placed exact sessions commit in bulk (actions/fast_apply);
+                     paths, tally, decided=None) -> None:
+        # Device-decided exact sessions apply in bulk (actions/fast_apply);
         # anything outside that envelope runs the loop below.
+        bulk_jobs = frozenset()
         if snap is not None:
-            from volcano_tpu.actions.fast_apply import try_fast_apply
+            from volcano_tpu.actions import fast_apply
+            from volcano_tpu.ops.kernels import ScanOutcome
 
+            rows, outcome, complete = ordered, None, False
+            if decided is not None:
+                cut, complete = decided.cut, decided.complete
+                rows = ordered[:cut]
+                outcome = ScanOutcome(decided.outcome.assignment[:cut],
+                                      decided.outcome.placed[:cut])
             with trace.span("vtpu/allocate.fast_apply", "action") as sp:
-                done = try_fast_apply(ssn, ordered, proposals, snap)
+                done = fast_apply.try_fast_apply(
+                    ssn, rows, proposals, snap, outcome=outcome,
+                    explain=explain_ctx, complete=complete,
+                )
                 sp.set(tasks=last_phase_stats.get("fast_apply_tasks", 0))
             paths["fast_apply"] = last_phase_stats.pop("fast_apply_tasks", 0)
+            paths["explained"] += last_phase_stats.pop("fast_apply_explained", 0)
             if done:
                 return
+            bulk_jobs = fast_apply.last_decided_jobs
+            if explain_ctx is not None and paths["fast_apply"]:
+                # the bulk commit ran ahead of ORDER
+                explain_ctx.diverged = True
 
         predicate_fn = make_predicate_fn(ssn)
         host_choose = host_node_chooser(ssn, tally)
@@ -569,6 +711,8 @@ class JaxAllocateAction(Action):
                     except FitError:
                         pass  # capacity/relational race → host fallback
                 tally["apply_rejected"] += 1
+                if explain_ctx is not None:
+                    explain_ctx.diverged = True
             if explain_ctx is not None:
                 fe = explain_ctx.try_explain(task)
                 if fe is not None:
@@ -579,13 +723,16 @@ class JaxAllocateAction(Action):
                     paths["explained"] += 1
                     return None
             paths["host_sweep"] += 1
-            return host_choose(task, job)
+            node = host_choose(task, job)
+            if node is not None and explain_ctx is not None:
+                explain_ctx.diverged = True
+            return node
 
         drive_allocate_loop(
             ssn,
             begin_job=lambda job: ssn.statement(),
             place_task=make_place_task(ssn, choose_node),
-            end_job=gang_end_job(ssn),
+            end_job=gang_end_job(ssn, bulk_jobs, tally),
         )
 
     def _publish_explain(

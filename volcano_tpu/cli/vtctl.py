@@ -657,7 +657,7 @@ def _trace_record(vc: VolcanoClient, args, out) -> int:
                 assignment = run_snapshot(snap, executor=args.executor)
             rec.capture(
                 snap, assignment, executor=args.executor,
-                weights=DEFAULT_WEIGHTS, gang_rounds=3,
+                weights=DEFAULT_WEIGHTS,
             )
             placed = int((assignment[: snap.n_tasks] >= 0).sum())
             rec.event("cycle-summary", "scheduler", placed=placed)

@@ -1031,6 +1031,38 @@ def register_spread_rows(device: int, host: int) -> None:
             )
 
 
+def register_gang_outcome(outcome: str, path: str, n: int = 1) -> None:
+    """volcano_allocate_gang_outcomes_total{outcome, path}: jobs whose
+    gang decision allocate made — commit or discard at the end of the
+    job's first episode — outcome ∈ {committed, discarded}, by where it
+    was made, path ∈ {device, host}: ``device`` when jax-allocate
+    applied the kernel's decision in bulk, ``host`` when the Statement
+    loop's gang_end_job made it."""
+    if n:
+        registry.inc(
+            f"{_NAMESPACE}_allocate_gang_outcomes_total",
+            {"outcome": outcome, "path": path}, n,
+        )
+
+
+def register_order_cut(rows: int) -> None:
+    """volcano_allocate_order_cut_rows_total{reason}: rows of ORDER
+    jax-allocate handed to the host chooser because a gang the kernel
+    did not commit left a share ORDER's simulation assumed placed;
+    reason ∈ {queue_share}: shares decide between queues."""
+    if rows:
+        registry.inc(
+            f"{_NAMESPACE}_allocate_order_cut_rows_total", {"reason": "queue_share"}, rows
+        )
+
+
+def register_gang_rollback(tasks: int) -> None:
+    """volcano_allocate_gang_rollback_tasks_total: rows the allocate
+    kernel placed and then undid when their gang was discarded."""
+    if tasks:
+        registry.inc(f"{_NAMESPACE}_allocate_gang_rollback_tasks_total", {}, tasks)
+
+
 def register_affinity_rows(device: int, host: int) -> None:
     """volcano_allocate_affinity_rows_total{path}: pending tasks under a
     required inter-pod term — one of their own, or a placed or pending

@@ -76,8 +76,8 @@ def load() -> Optional[ctypes.CDLL]:
         i32, i32,                        # job arrays
         f32,                             # tolerance
         ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int,
-        ctypes.c_int, ctypes.c_int,
-        i32,                             # out assignment
+        ctypes.c_int,
+        i32, i32, i32,                   # out assignment / placed / reasons
     ]
     lib.baseline_allocate.restype = ctypes.c_int
     i64 = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
@@ -98,27 +98,27 @@ def load() -> Optional[ctypes.CDLL]:
     return lib
 
 
-def baseline_allocate(snap, n_threads: int = 16, gang_rounds: int = 3) -> np.ndarray:
-    """Run the native greedy allocate on a PackedSnapshot → assignment[T]."""
+def baseline_outcome(snap, n_threads: int = 16):
+    """Run the native greedy allocate on a PackedSnapshot → ScanOutcome
+    (ops/kernels.py) over the first ``n_tasks`` rows, with the per-job
+    commit/discard and the failing steps' reason counts."""
+    from volcano_tpu.ops.kernels import N_EXPLAIN_REASONS, ScanOutcome
+
     lib = load()
     if lib is None:
         raise RuntimeError("native baseline unavailable (g++ missing?)")
-    T = snap.task_resreq.shape[0]
-    N = snap.node_idle.shape[0]
     J = snap.job_min_available.shape[0]
     R = snap.task_resreq.shape[1]
     W = snap.task_sel_bits.shape[1]
+    T = snap.n_tasks
     out = np.full(T, -1, dtype=np.int32)
-
-    task_valid_rows = snap.n_tasks
-    # Padded task rows have resreq 0 and job pointing at a padded job with
-    # min_available INT32_MAX, so they never commit; the C++ loop still
-    # walks them — trim instead for speed.
+    placed = np.full(T, -1, dtype=np.int32)
+    reasons = np.zeros((T, N_EXPLAIN_REASONS), dtype=np.int32)
     rc = lib.baseline_allocate(
-        np.ascontiguousarray(snap.task_resreq[:task_valid_rows]),
-        np.ascontiguousarray(snap.task_job[:task_valid_rows]),
-        np.ascontiguousarray(snap.task_sel_bits[:task_valid_rows]),
-        np.ascontiguousarray(snap.task_tol_bits[:task_valid_rows]),
+        np.ascontiguousarray(snap.task_resreq[:T]),
+        np.ascontiguousarray(snap.task_job[:T]),
+        np.ascontiguousarray(snap.task_sel_bits[:T]),
+        np.ascontiguousarray(snap.task_tol_bits[:T]),
         np.ascontiguousarray(snap.node_idle[: snap.n_nodes]),
         np.ascontiguousarray(snap.node_used[: snap.n_nodes]),
         np.ascontiguousarray(snap.node_alloc[: snap.n_nodes]),
@@ -130,18 +130,25 @@ def baseline_allocate(snap, n_threads: int = 16, gang_rounds: int = 3) -> np.nda
         np.ascontiguousarray(snap.job_min_available),
         np.ascontiguousarray(snap.job_ready_count),
         np.ascontiguousarray(snap.tolerance),
-        task_valid_rows,
+        T,
         snap.n_nodes,
         J,
         R,
         W,
         n_threads,
-        gang_rounds,
-        out[:task_valid_rows],
+        out,
+        placed,
+        reasons,
     )
     if rc != 0:
         raise RuntimeError(f"baseline_allocate failed: {rc}")
-    return out[:task_valid_rows]
+    return ScanOutcome(out, placed, reasons)
+
+
+def baseline_allocate(snap, n_threads: int = 16) -> np.ndarray:
+    """Run the native greedy allocate on a PackedSnapshot → committed
+    assignment[n_tasks]."""
+    return baseline_outcome(snap, n_threads).assignment
 
 
 def baseline_preempt(pk, n_threads: int = 16):

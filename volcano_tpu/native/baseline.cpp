@@ -6,8 +6,8 @@
 // Same semantics as the device kernel (volcano_tpu/ops/kernels.py): per
 // task in order — feasibility (resource fit with tolerance, label/taint
 // bitsets, pod-count, node-ok) → binpack + least-requested + balanced
-// score → lowest-index argmax → tentative allocate; then gang fixpoint
-// rounds (discard jobs under minAvailable, rerun).  The node loop fans out
+// score → lowest-index argmax → tentative allocate, with the per-job gang
+// commit/discard in the same pass.  The node loop fans out
 // over worker threads per task, mirroring the reference's 16-goroutine
 // ParallelizeUntil.
 //
@@ -45,7 +45,6 @@ struct Args {
   const int32_t* job_ready_count;    // [J]
   const float* tolerance;            // [R]
   int n_threads;
-  int gang_rounds;
 };
 
 struct Weights {
@@ -71,6 +70,29 @@ inline bool feasible(const Args& a, const float* resreq, const uint32_t* sel,
     if (tb[w] & ~tol[w]) return false;
   }
   return true;
+}
+
+// First failing predicate of (task, node) in the host's order — the
+// ops/kernels.py R_FIT, R_ROOM, R_UNSCHED, R_SEL, R_TOL indices.  Only
+// called for a task that fits no node, so every node has one.
+inline int first_failure(const Args& a, const float* resreq, const uint32_t* sel,
+                         const uint32_t* tol, const std::vector<float>& idle,
+                         const std::vector<int32_t>& count, int n) {
+  const float* id = idle.data() + (size_t)n * a.R;
+  for (int r = 0; r < a.R; ++r) {
+    bool lane_ok = resreq[r] < id[r] + a.tolerance[r];
+    if (!lane_ok && r >= 2 && resreq[r] <= a.tolerance[r]) lane_ok = true;
+    if (!lane_ok) return 0;
+  }
+  if (count[n] >= a.node_max_tasks[n]) return 1;
+  if (!a.node_ok[n]) return 2;
+  const uint32_t* lb = a.node_label_bits + (size_t)n * a.W;
+  const uint32_t* tb = a.node_taint_bits + (size_t)n * a.W;
+  for (int w = 0; w < a.W; ++w)
+    if (sel[w] & ~lb[w]) return 3;
+  for (int w = 0; w < a.W; ++w)
+    if (tb[w] & ~tol[w]) return 4;
+  return 8;  // feasible: never reached for a task that fit nowhere
 }
 
 // Shared score body (binpack + least-requested + balanced) over raw
@@ -176,7 +198,18 @@ class Pool {
 
 extern "C" {
 
-// Returns 0 on success; fills assignment[T] with node index or -1.
+// Returns 0 on success.  Fills assignment[T] with the committed node or
+// -1, placed[T] with the node the pass put the row on before its job's
+// commit or discard (-1: fit no node, -2: not reached), and reasons[T*8]
+// with, for each row that fit no node, the node counts per first failing
+// predicate at that step (ops/kernels.py R_FIT.. order; zero elsewhere).
+//
+// Per-job commit/discard in one pass, as drive_allocate_loop +
+// gang_end_job apply it: consecutive rows of one job form a run, opened
+// by its first row and closed by another job's row, the end of the pass
+// or a row that fits no node; a run closing short of the job's need
+// (minAvailable less ready, 0 once the job was ready) is undone; a row
+// that fits no node ends its job, whose later rows are skipped.
 int baseline_allocate(const float* task_resreq, const int32_t* task_job,
                       const uint32_t* task_sel_bits, const uint32_t* task_tol_bits,
                       const float* node_idle, const float* node_used,
@@ -185,16 +218,14 @@ int baseline_allocate(const float* task_resreq, const int32_t* task_job,
                       const int32_t* node_task_count, const int32_t* node_max_tasks,
                       const int32_t* job_min_available, const int32_t* job_ready_count,
                       const float* tolerance, int T, int N, int J, int R, int W,
-                      int n_threads, int gang_rounds, int32_t* assignment) {
+                      int n_threads, int32_t* assignment, int32_t* placed,
+                      int32_t* reasons) {
   Args a{T, N, J, R, W,
          task_resreq, task_job, task_sel_bits, task_tol_bits,
          node_idle, node_used, node_alloc, node_label_bits, node_taint_bits,
          node_ok, node_task_count, node_max_tasks, job_min_available,
-         job_ready_count, tolerance, n_threads, gang_rounds};
+         job_ready_count, tolerance, n_threads};
   Weights wt;
-
-  std::vector<uint8_t> active(T, 1);
-  std::vector<int32_t> chosen(T, -1);
 
   const int threads = n_threads > 0 ? n_threads : 16;
   std::unique_ptr<Pool> pool_holder;
@@ -204,73 +235,101 @@ int baseline_allocate(const float* task_resreq, const int32_t* task_job,
     pool = pool_holder.get();
   }
 
-  for (int round = 0; round < gang_rounds; ++round) {
-    // Reset state (discard semantics restore node accounting each round).
-    std::vector<float> idle(node_idle, node_idle + (size_t)N * R);
-    std::vector<float> used(node_used, node_used + (size_t)N * R);
-    std::vector<int32_t> count(node_task_count, node_task_count + N);
-    std::vector<int32_t> job_assigned(J, 0);
-    std::fill(chosen.begin(), chosen.end(), -1);
+  enum { UNSEEN = 0, READY = 1, ENDED = -1, DISCARDED = -2 };
+  std::vector<float> idle(node_idle, node_idle + (size_t)N * R);
+  std::vector<float> used(node_used, node_used + (size_t)N * R);
+  std::vector<int32_t> count(node_task_count, node_task_count + N);
+  std::vector<float> saved_idle, saved_used;
+  std::vector<int32_t> saved_count;
+  std::vector<int8_t> jstate(J, UNSEEN);
+  int cur = -1, run_placed = 0;
+  int64_t run_need = 0;
+  std::memset(reasons, 0, sizeof(int32_t) * (size_t)T * 8);
 
-    for (int t = 0; t < T; ++t) {
-      if (!active[t]) continue;
-      const float* resreq = task_resreq + (size_t)t * R;
-      const uint32_t* sel = task_sel_bits + (size_t)t * W;
-      const uint32_t* tol = task_tol_bits + (size_t)t * W;
+  auto close_run = [&]() -> bool {  // true: discarded
+    bool disc = run_placed < run_need;
+    if (disc && run_placed > 0) {
+      idle = saved_idle;
+      used = saved_used;
+      count = saved_count;
+    }
+    jstate[cur] = disc ? DISCARDED : READY;
+    cur = -1;
+    return disc;
+  };
 
-      // Parallel node sweep (mirrors workqueue.ParallelizeUntil w/ 16
-      // workers, scheduler_helper.go:110-111), deterministic reduce:
-      // chunk-local best, then lowest-index winner across chunks.
-      int best = -1;
-      double best_score = -std::numeric_limits<double>::infinity();
-      if (pool == nullptr) {
-        for (int n = 0; n < N; ++n) {
+  for (int t = 0; t < T; ++t) {
+    const int j = task_job[t];
+    if (j != cur && cur >= 0) close_run();
+    if (jstate[j] < 0) { placed[t] = -2; continue; }
+    if (j != cur) {
+      cur = j;
+      run_placed = 0;
+      run_need = jstate[j] == UNSEEN
+          ? (int64_t)job_min_available[j] - job_ready_count[j] : 0;
+      if (run_need >= 2) {
+        saved_idle = idle;
+        saved_used = used;
+        saved_count = count;
+      }
+    }
+    const float* resreq = task_resreq + (size_t)t * R;
+    const uint32_t* sel = task_sel_bits + (size_t)t * W;
+    const uint32_t* tol = task_tol_bits + (size_t)t * W;
+
+    // Parallel node sweep (mirrors workqueue.ParallelizeUntil w/ 16
+    // workers, scheduler_helper.go:110-111), deterministic reduce:
+    // chunk-local best, then lowest-index winner across chunks.
+    int best = -1;
+    double best_score = -std::numeric_limits<double>::infinity();
+    if (pool == nullptr) {
+      for (int n = 0; n < N; ++n) {
+        if (!feasible(a, resreq, sel, tol, idle, count, n)) continue;
+        double sc = score_at(wt, resreq, used.data() + (size_t)n * a.R,
+                             a.node_alloc + (size_t)n * a.R);
+        if (sc > best_score) { best_score = sc; best = n; }
+      }
+    } else {
+      std::vector<int> cb(threads, -1);
+      std::vector<double> cs(threads,
+                             -std::numeric_limits<double>::infinity());
+      int chunk = (N + threads - 1) / threads;
+      pool->Dispatch([&](int w) {
+        int lo = w * chunk, hi = std::min(N, lo + chunk);
+        for (int n = lo; n < hi; ++n) {
           if (!feasible(a, resreq, sel, tol, idle, count, n)) continue;
           double sc = score_at(wt, resreq, used.data() + (size_t)n * a.R,
-                               a.node_alloc + (size_t)n * a.R);
-          if (sc > best_score) { best_score = sc; best = n; }
+                             a.node_alloc + (size_t)n * a.R);
+          if (sc > cs[w]) { cs[w] = sc; cb[w] = n; }
         }
-      } else {
-        std::vector<int> cb(threads, -1);
-        std::vector<double> cs(threads,
-                               -std::numeric_limits<double>::infinity());
-        int chunk = (N + threads - 1) / threads;
-        pool->Dispatch([&](int w) {
-          int lo = w * chunk, hi = std::min(N, lo + chunk);
-          for (int n = lo; n < hi; ++n) {
-            if (!feasible(a, resreq, sel, tol, idle, count, n)) continue;
-            double sc = score_at(wt, resreq, used.data() + (size_t)n * a.R,
-                               a.node_alloc + (size_t)n * a.R);
-            if (sc > cs[w]) { cs[w] = sc; cb[w] = n; }
-          }
-        });
-        for (int w = 0; w < threads; ++w) {
-          if (cb[w] >= 0 && cs[w] > best_score) { best_score = cs[w]; best = cb[w]; }
-        }
+      });
+      for (int w = 0; w < threads; ++w) {
+        if (cb[w] >= 0 && cs[w] > best_score) { best_score = cs[w]; best = cb[w]; }
       }
-
-      if (best < 0) continue;
-      chosen[t] = best;
-      float* id = idle.data() + (size_t)best * R;
-      float* us = used.data() + (size_t)best * R;
-      for (int r = 0; r < R; ++r) { id[r] -= resreq[r]; us[r] += resreq[r]; }
-      count[best] += 1;
-      job_assigned[task_job[t]] += 1;
     }
 
-    // Gang commit/discard.
-    bool changed = false;
-    for (int t = 0; t < T; ++t) {
-      if (!active[t]) continue;
-      int j = task_job[t];
-      bool ready = job_assigned[j] + job_ready_count[j] >= job_min_available[j];
-      if (!ready) { active[t] = 0; changed = true; }
+    if (best < 0) {
+      placed[t] = -1;
+      int32_t* why = reasons + (size_t)t * 8;
+      for (int n = 0; n < N; ++n) {
+        int f = first_failure(a, resreq, sel, tol, idle, count, n);
+        if (f < 8) why[f] += 1;
+      }
+      bool disc = close_run();
+      if (!disc) jstate[j] = ENDED;
+      continue;
     }
-    if (!changed) break;
+    placed[t] = best;
+    float* id = idle.data() + (size_t)best * R;
+    float* us = used.data() + (size_t)best * R;
+    for (int r = 0; r < R; ++r) { id[r] -= resreq[r]; us[r] += resreq[r]; }
+    count[best] += 1;
+    run_placed += 1;
   }
+  if (cur >= 0) close_run();
 
   for (int t = 0; t < T; ++t)
-    assignment[t] = active[t] ? chosen[t] : -1;
+    assignment[t] = placed[t] >= 0 && jstate[task_job[t]] != DISCARDED ? placed[t] : -1;
   return 0;
 }
 

@@ -1,7 +1,7 @@
 """Blocked greedy assignment — the fast exact formulation of the session
 kernel.
 
-The plain kernel (ops/kernels.py schedule_pass) scans tasks one by one,
+The plain kernel (ops/kernels.py schedule_episodes) scans tasks one by one,
 paying a full [N]-wide mask+score+argmax per step; at 16k nodes the
 per-iteration cost is ~30-60µs, so 50k tasks take seconds.  This module
 restructures the same sequential-greedy semantics into blocks:
@@ -125,42 +125,16 @@ def make_inner_step(tracked, base_t, alloc_t, maxt_t, real, tolerance,
     return inner
 
 
-def gang_fixpoint(run_pass, task_job, job_min_available, job_ready_count,
-                  n_tasks, t_total, gang_rounds, discard_unstable=False):
-    """Adaptive host-side gang commit/discard loop (run_packed protocol),
-    shared by the blocked and sharded wrappers: ``run_pass(active)`` →
-    (chosen, job_assigned); stops as soon as the active set is stable.
-
-    ``gang_rounds`` bounds the cascade; an unsettled fixpoint ships the
-    last round's commits (individually valid placements computed while
-    later-discarded jobs still held resources).  ``discard_unstable``
-    opts into the reference's Statement semantics instead
-    (statement.go:309-337 discards operations until the set is stable):
-    the loop runs to the true fixpoint, ignoring the round bound.
-    Termination is structural — every non-stable round STRICTLY shrinks
-    the active set (next_active = active & ready-mask ≠ active), so the
-    fixpoint arrives within min(n_jobs, n_tasks)+1 passes."""
+def one_pass(run_pass, n_tasks, t_total) -> np.ndarray:
+    """The blocked and sharded wrappers' single greedy pass over the
+    first ``n_tasks`` rows: ``run_pass(active)`` → (chosen,
+    job_assigned).  Their sessions hold one row per job and need at most
+    one placement a job (``PackedSnapshot.episodic`` is False), so what
+    the pass places is what commits."""
     active = np.zeros(t_total, dtype=bool)
     active[:n_tasks] = True
-    min_avail = job_min_available.astype(np.int64)
-    ready_count = job_ready_count.astype(np.int64)
-
-    chosen_np = np.full(t_total, -1, dtype=np.int32)
-    committed = np.zeros(t_total, dtype=bool)
-    rounds = 0
-    while True:
-        chosen, job_assigned = run_pass(jnp.asarray(active))
-        chosen_np = np.asarray(chosen)
-        ready = np.asarray(job_assigned, dtype=np.int64) + ready_count >= min_avail
-        committed = ready[task_job] & (chosen_np >= 0)
-        next_active = active & ready[task_job]
-        rounds += 1
-        if (next_active == active).all():
-            break
-        if not discard_unstable and rounds >= gang_rounds:
-            break
-        active = next_active
-    return np.where(committed & active, chosen_np, -1)[:n_tasks]
+    chosen, _ = run_pass(jnp.asarray(active))
+    return np.asarray(chosen, dtype=np.int32)[:n_tasks]
 
 
 @functools.partial(
@@ -410,22 +384,20 @@ def _prepare_blocked_dev(snap: PackedSnapshot, block_size: int):
 def run_packed_blocked(
     snap: PackedSnapshot,
     weights: ScoreWeights = DEFAULT_WEIGHTS,
-    gang_rounds: int = 3,
     block_size: int = 64,
     top_k: int = 8,
-    discard_unstable: bool = False,
 ) -> np.ndarray:
-    """Host wrapper with the adaptive gang fixpoint (same protocol as
-    kernels.run_packed) on the blocked pass.
+    """Host wrapper: PackedSnapshot → assignment[n_tasks] from one
+    blocked pass.
 
-    Sessions with hard topology spread run the plain scan instead: the
-    blocked pass keeps each block's top-K candidates valid on the
-    premise that a placement changes one node, and a placement there
-    moves the counts of a whole domain (the dispatcher never picks this
-    executor for them; this covers the Pallas rung's fallback)."""
-    if snap.has_spread:
-        return run_packed(snap, weights=weights, gang_rounds=gang_rounds,
-                          discard_unstable=discard_unstable)
+    Sessions with hard topology spread or multi-task gangs run the plain
+    scan instead: the blocked pass keeps each block's top-K candidates
+    valid on the premise that a placement changes one node, and a
+    placement there moves the counts of a whole domain, or a gang's
+    discard moves many (the dispatcher never picks this executor for
+    them)."""
+    if snap.has_spread or snap.episodic:
+        return run_packed(snap, weights=weights)
     if not f32_lr_exact(snap):
         weights = weights._replace(lr_int_exact=True)
 
@@ -434,13 +406,9 @@ def run_packed_blocked(
         # device-resident — pad on device so the host ships nothing but
         # the dirty-row scatters already applied by the stager
         dev, T_blk = _prepare_blocked_dev(snap, block_size)
-        # the gang fixpoint walks task_job host-side
-        task_job_host = np.zeros(T_blk, dtype=snap.task_job.dtype)
-        task_job_host[: snap.task_job.shape[0]] = snap.task_job
     else:
         arrays, T_blk = prepare_blocked_arrays(snap, block_size)
         dev = {k: jnp.asarray(v) for k, v in arrays.items()}
-        task_job_host = arrays["task_job"]
 
     def run_pass(active):
         return schedule_pass_blocked(
@@ -465,13 +433,4 @@ def run_packed_blocked(
             top_k=top_k,
         )
 
-    return gang_fixpoint(
-        run_pass,
-        task_job_host,
-        snap.job_min_available,
-        snap.job_ready_count,
-        snap.n_tasks,
-        T_blk,
-        gang_rounds,
-        discard_unstable=discard_unstable,
-    )
+    return one_pass(run_pass, snap.n_tasks, T_blk)

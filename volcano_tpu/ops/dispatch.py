@@ -5,21 +5,23 @@ their kernels: :func:`run_allocate` for jax-allocate and
 chip the scheduler holds.
 
 Three formulations share one semantics (identical bindings, proven by
-tests/test_blocked.py and tests/test_pallas.py):
+tests/test_blocked.py and tests/test_pallas.py); a session with
+multi-task gangs (``PackedSnapshot.episodic``) takes only those that
+carry Volcano's per-job commit/discard in the scan — pallas, the plain
+scan and native:
 
   * ``run_packed_pallas`` — the whole greedy scan inside one Pallas TPU
     kernel, node state VMEM-resident (ops/pallas_session.py).  ~50x the
     XLA scan at 50k x 10k.  TPU only, and only within the f32
     floor-division exactness envelope (node capacity * 10 < 2^24).
   * ``run_packed_blocked`` — blocked top-K candidate tracking with exact
-    outside-max stop/fallback (ops/blocked.py).  Best off-TPU at scale.
+    outside-max stop/fallback (ops/blocked.py).  Best off-TPU at scale;
+    one row per job only.
   * ``run_packed`` — the plain lax.scan (ops/kernels.py).  Smallest
     compile, fine for small sessions and the reference for equivalence.
 """
 
 from __future__ import annotations
-
-import os
 
 import numpy as np
 
@@ -46,25 +48,22 @@ _SHARD_MIN_NODES = 2_048
 _FALLBACK = {"native": "xla-scan", "pallas": "blocked", "sharded": "blocked"}
 
 
+def _fallback(executor: str, snap: PackedSnapshot) -> str:
+    """The rung below ``executor`` for this session: the plain scan for
+    a session with multi-task gangs or spread classes, which the blocked
+    formulations do not carry."""
+    rung = _FALLBACK[executor]
+    if rung == "blocked" and (snap.episodic or snap.has_spread):
+        return "xla-scan"
+    return rung
+
+
 def _breaker(name: str):
     """Executor breaker: 3 consecutive failures open it, half-open
     re-probe after 30s promotes the executor back on success."""
     from volcano_tpu.faults.breaker import get_breaker
 
     return get_breaker(name, failure_threshold=3, cooldown_s=30.0)
-
-
-def gang_discard_unstable() -> bool:
-    """Opt-in reference Statement semantics for an unsettled gang
-    cascade (VERDICT weak #6): ``VTPU_GANG_DISCARD_UNSTABLE=1`` makes
-    the host gang loops discard until stable instead of shipping the
-    last bounded round's commits.  Routes around the Pallas/native
-    formulations (their cascades are fixed-round inside the kernel).
-    Same accepted values as the repo's other env flags
-    (utils/asserts.py) — 'false'/'no'/'off' mean OFF."""
-    return os.environ.get("VTPU_GANG_DISCARD_UNSTABLE", "").lower() in (
-        "1", "true", "yes",
-    )
 
 
 def _assignment_valid(snap: PackedSnapshot, out) -> bool:
@@ -123,7 +122,10 @@ def select_executor(
     Hard topology spread is encoded by the Pallas kernel and the plain
     scan only: such a session takes ``pallas`` where it may, else
     ``xla-scan`` — never ``native`` or the blocked formulations, whose
-    candidate tracking assumes a placement changes one node."""
+    candidate tracking assumes a placement changes one node.  A session
+    with multi-task gangs takes ``pallas``, else the plain scan, or
+    ``native`` below the small-area cut: the blocked formulations do not
+    roll a gang back."""
     area = max(snap.n_tasks, 1) * max(snap.n_nodes, 1)
     spread = snap.has_spread
     if area < _SMALL_AREA:
@@ -138,7 +140,7 @@ def select_executor(
 
         if pallas_vmem_bytes(snap) <= VMEM_BUDGET:
             return "pallas"
-    if spread:
+    if spread or snap.episodic:
         return "xla-scan"
     if _device_count() >= 2 and snap.n_nodes >= _SHARD_MIN_NODES:
         return "sharded"
@@ -268,12 +270,22 @@ def _note(executor: str) -> str:
     return executor
 
 
+#: ScanOutcome of the last run_packed_auto — what the scan placed and
+#: rolled back, and the failing steps' reason counts where the
+#: formulation reports them.  Same read-right-after-the-call discipline.
+_last_outcome = None
+
+
+def last_outcome():
+    return _last_outcome
+
+
 def run_packed_auto(
     snap: PackedSnapshot,
     weights: ScoreWeights = DEFAULT_WEIGHTS,
-    gang_rounds: int = 3,
 ) -> np.ndarray:
-    """PackedSnapshot → assignment[T], fastest exact path for the shape.
+    """PackedSnapshot → committed assignment[T], fastest exact path for
+    the shape; the whole outcome is :func:`last_outcome`.
 
     Dispatches on :func:`select_executor` — the single copy of the
     decision tree — so what runs always matches what callers (e.g.
@@ -284,21 +296,26 @@ def run_packed_auto(
     ``volcano_executor_fallbacks_total`` and the outputs of upper rungs
     pass a validity gate so silently-corrupt kernels degrade like raised
     errors."""
+    global _last_outcome
+    _last_outcome = None
+    outcome = _run_outcome(snap, weights)
+    _last_outcome = outcome
+    return outcome.assignment
+
+
+def _run_outcome(snap: PackedSnapshot, weights: ScoreWeights):
+    from volcano_tpu.ops.kernels import ScanOutcome, run_outcome
+
     executor = select_executor(snap, weights)
     from volcano_tpu import faults, trace
     from volcano_tpu.metrics import metrics
 
     fp = faults.get_plane()
-    discard = gang_discard_unstable()
-    if discard and executor in ("pallas", "native"):
-        # these formulations run their gang cascade fixed-round inside
-        # the kernel; the discard-until-stable loop is host-driven
-        executor = "blocked" if executor == "pallas" else "xla-scan"
     if executor in _FALLBACK and not _breaker(executor).allow():
         metrics.register_executor_fallback(
-            executor, _FALLBACK[executor], "circuit-open"
+            executor, _fallback(executor, snap), "circuit-open"
         )
-        executor = _FALLBACK[executor]
+        executor = _fallback(executor, snap)
     rec = trace.get_recorder()
     if rec.enabled:
         rec.event(
@@ -328,15 +345,16 @@ def run_packed_auto(
             # would race the next live cycle's breaker state
             raise _PhaseAbandoned(executor)
         if fp.enabled and fp.should("device.nan"):
-            out = np.full(
-                np.asarray(out).shape, np.iinfo(np.int32).max, dtype=np.int32
-            )
-        if not _assignment_valid(snap, out):
+            out = ScanOutcome.of_assignment(np.full(
+                np.asarray(out.assignment).shape, np.iinfo(np.int32).max,
+                dtype=np.int32,
+            ))
+        if not _assignment_valid(snap, out.assignment):
             raise _CorruptOutput(f"{executor} returned an invalid assignment")
         br.record_success()
         return out
 
-    def degrade(e: Exception):
+    def degrade(e: Exception) -> str:
         from volcano_tpu.faults import watchdog
         from volcano_tpu.utils.logging import get_logger
 
@@ -346,7 +364,7 @@ def run_packed_auto(
             # fallback line — no duplicate fallback allocate competing
             # with the next cycle for the device
             raise _PhaseAbandoned(executor)
-        fallback = _FALLBACK[executor]
+        fallback = _fallback(executor, snap)
         _breaker(executor).record_failure(str(e))
         metrics.register_executor_fallback(
             executor, fallback,
@@ -355,40 +373,35 @@ def run_packed_auto(
         get_logger(__name__).error(
             "%s allocate failed (%s); %s fallback", executor, e, fallback
         )
-        _note(fallback)
+        return _note(fallback)
+
+    def floor(rung: str):
+        if rung == "blocked":
+            from volcano_tpu.ops.blocked import run_packed_blocked
+
+            return ScanOutcome.of_assignment(run_packed_blocked(snap, weights=weights))
+        return run_outcome(snap, weights=weights)
 
     if executor == "native":
         from volcano_tpu import native
 
         try:
-            return attempt(
-                lambda: native.baseline_allocate(snap, gang_rounds=gang_rounds)
-            )
+            return attempt(lambda: native.baseline_outcome(snap))
         except (RuntimeError, ValueError) as e:
             # Native executor hit an internal error mid-session — degrade
             # to the exact XLA scan rather than failing the session.
-            degrade(e)
-            return run_packed(snap, weights=weights, gang_rounds=gang_rounds)
+            return floor(degrade(e))
     if executor == "pallas":
-        from volcano_tpu.ops.blocked import run_packed_blocked
-        from volcano_tpu.ops.pallas_session import run_packed_pallas
+        from volcano_tpu.ops.pallas_session import run_outcome_pallas
 
         try:
-            return attempt(
-                lambda: run_packed_pallas(
-                    snap, weights=weights, gang_rounds=gang_rounds
-                )
-            )
+            return attempt(lambda: run_outcome_pallas(snap, weights=weights))
         except Exception as e:  # noqa: BLE001 — e.g. VMEM overflow at lowering
-            degrade(e)
-            return run_packed_blocked(
-                snap, weights=weights, gang_rounds=gang_rounds
-            )
+            return floor(degrade(e))
     if executor == "sharded":
         import jax
         from jax.sharding import Mesh
 
-        from volcano_tpu.ops.blocked import run_packed_blocked
         from volcano_tpu.ops.sharded import run_packed_sharded
 
         devices = jax.devices()
@@ -396,29 +409,12 @@ def run_packed_auto(
         # run_packed_sharded; the mesh is 1-D over all devices
         mesh = Mesh(np.array(devices), ("nodes",))
         try:
-            return attempt(
-                lambda: run_packed_sharded(
-                    snap, mesh, weights=weights, gang_rounds=gang_rounds,
-                    discard_unstable=discard,
-                )
-            )
+            return attempt(lambda: ScanOutcome.of_assignment(
+                run_packed_sharded(snap, mesh, weights=weights)
+            ))
         except Exception as e:  # noqa: BLE001 — degrade like the other paths
-            degrade(e)
-            return run_packed_blocked(
-                snap, weights=weights, gang_rounds=gang_rounds,
-                discard_unstable=discard,
-            )
-    if executor == "blocked":
-        from volcano_tpu.ops.blocked import run_packed_blocked
-
-        return run_packed_blocked(
-            snap, weights=weights, gang_rounds=gang_rounds,
-            discard_unstable=discard,
-        )
-    return run_packed(
-        snap, weights=weights, gang_rounds=gang_rounds,
-        discard_unstable=discard,
-    )
+            return floor(degrade(e))
+    return floor(executor)
 
 
 #: reason counts of the last run_allocate(explain=True) — [T, P] int32
@@ -428,6 +424,13 @@ def run_packed_auto(
 #: discipline as _last_executor.
 _last_explain_counts = None
 _last_explain_ms = None
+#: True when those counts were taken at each row's failing step of the
+#: scan, False when at the pack's node state
+_last_explain_at_step = False
+
+
+def last_explain_at_step() -> bool:
+    return _last_explain_at_step
 
 
 def last_explain_counts():
@@ -441,38 +444,50 @@ def last_explain_ms():
 def run_allocate(
     snap: PackedSnapshot,
     weights: ScoreWeights = DEFAULT_WEIGHTS,
-    gang_rounds: int = 3,
     explain: bool = False,
-) -> np.ndarray:
+):
     """The jax-allocate action's entry: :func:`run_packed_auto` under
-    the cycle watchdog's remaining budget, then — with ``explain`` — the
-    reason-count reduction over the rows that went unplaced (read back
-    with :func:`last_explain_counts`).  Lazy: a fully placed session
-    computes nothing extra."""
+    the cycle watchdog's remaining budget → ScanOutcome.  With
+    ``explain``, the reason counts of the rows that fit no node are read
+    back with :func:`last_explain_counts`: those of the failing steps
+    where the formulation reports them (:func:`last_explain_at_step`),
+    else a reduction over the unplaced rows at the pack's node state.
+    Lazy: a fully placed session computes nothing extra."""
     from volcano_tpu.faults import watchdog
+    from volcano_tpu.ops.kernels import ScanOutcome
 
-    global _last_explain_counts, _last_explain_ms
+    global _last_explain_counts, _last_explain_ms, _last_explain_at_step
     # cleared up front: an aborted call (deadline, error) must not leave
     # a previous session's counts readable as this session's
     _last_explain_counts = None
     _last_explain_ms = None
+    _last_explain_at_step = False
     # run_packed_auto resolves through the module globals at call time,
     # so a wrapper installed on the module attribute sees every dispatch
     out = watchdog.run_with_deadline(
-        lambda: run_packed_auto(snap, weights=weights, gang_rounds=gang_rounds),
+        lambda: run_packed_auto(snap, weights=weights),
         watchdog.remaining_s(),
         "allocate",
     )
+    outcome = last_outcome()
+    if outcome is None or outcome.assignment is not out:
+        outcome = ScanOutcome.of_assignment(np.asarray(out)[: snap.n_tasks])
     if explain:
-        unplaced = np.nonzero(np.asarray(out)[: snap.n_tasks] < 0)[0]
-        if unplaced.size:
-            from volcano_tpu.ops import explain as _explain
+        failed = outcome.placed[: snap.n_tasks] == -1
+        if outcome.reasons is not None:
+            if failed.any():
+                _last_explain_counts = outcome.reasons
+                _last_explain_at_step = True
+        else:
+            unplaced = np.nonzero(failed)[0]
+            if unplaced.size:
+                from volcano_tpu.ops import explain as _explain
 
-            _last_explain_counts = _explain.run_explain(
-                snap, task_rows=unplaced
-            ).counts
-            _last_explain_ms = _explain.last_run_ms
-    return out
+                _last_explain_counts = _explain.run_explain(
+                    snap, task_rows=unplaced
+                ).counts
+                _last_explain_ms = _explain.last_run_ms
+    return outcome
 
 
 def warmup_kernels(n_tasks: int = 4096, n_nodes: int = 1024,

@@ -9,13 +9,14 @@ pkg/scheduler/actions/allocate/allocate.go:191-224) with one jitted program:
      allocate.go:100-107)
   2. score — closed-form binpack (binpack.go:248-259) +
      least-requested/balanced (vendored k8s priorities) arithmetic
-  3. assignment — lax.scan over tasks in priority order with node state
+  3. assignment — lax.scan over tasks in ORDER with node state
      (idle/used/count) carried, mirroring the sequential feedback of
      Statement.Allocate; deterministic first-index tie-break
-  4. gang commit — jobs reaching min_available keep their placements,
-     others are discarded and the kernel re-runs without them (the
-     Statement.Commit/Discard semantics, statement.go:309-337) until a
-     fixpoint — at most gang_rounds device passes.
+  4. gang commit/discard in the same pass — a task that fits no node
+     ends its job, and a job's run of rows that closes short of
+     minAvailable is undone in the node state (Statement.Commit/Discard,
+     statement.go:309-337, as drive_allocate_loop + gang_end_job apply
+     them one job at a time).
 
 Everything is static-shaped and branch-free inside jit; ties break to the
 lowest node index so host and device paths agree bindings-exactly.
@@ -24,7 +25,7 @@ lowest node index so host and device paths agree bindings-exactly.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -216,6 +217,32 @@ def explain_counts(
 
 # ---- scores (closed-form plugin math) ----
 
+def _split(a):
+    """Dekker's split of a float32 into a high half of 12 significant
+    bits and the rest, so that products of halves are exact."""
+    t = a * 4097.0
+    hi = t - (t - a)
+    return hi, a - hi
+
+
+def rn_correct(x, y, q):
+    """``q`` ≈ x / y, within a few ulps, brought to x / y rounded to
+    nearest, as IEEE division gives it: the residual x - q·y is taken
+    exactly (Dekker's product; Sterbenz's lemma for the subtraction) and
+    its quotient by y added back.  Needed where the divide is a
+    reciprocal and a multiply, as on the TPU; elsewhere the identity."""
+    p = q * y
+    qh, ql = _split(q)
+    yh, yl = _split(y)
+    e = ((qh * yh - p) + qh * yl + ql * yh) + ql * yl
+    return q + ((x - p) - e) / y
+
+
+def div_rn(x, y):
+    """float32 x / y rounded to nearest on every backend (rn_correct)."""
+    return rn_correct(x, y, x / y)
+
+
 def binpack_score(
     task_resreq: jnp.ndarray,  # [T, R]
     node_used: jnp.ndarray,  # [N, R]
@@ -236,10 +263,10 @@ def binpack_score(
     alloc = node_alloc[None, :, :]
     requested_mask = req > 0
     valid = requested_mask & (alloc > 0) & (used_finally <= alloc)
-    lane_score = jnp.where(valid, used_finally * lane_w / jnp.maximum(alloc, 1.0), 0.0)
+    lane_score = jnp.where(valid, div_rn(used_finally * lane_w, jnp.maximum(alloc, 1.0)), 0.0)
     score = jnp.sum(lane_score, axis=-1)
     weight_sum = jnp.sum(jnp.where(requested_mask, lane_w, 0.0), axis=-1)
-    score = jnp.where(weight_sum > 0, score / weight_sum, 0.0)
+    score = jnp.where(weight_sum > 0, div_rn(score, jnp.maximum(weight_sum, 1e-30)), 0.0)
     return score * MAX_PRIORITY * weights.binpack_weight
 
 
@@ -285,13 +312,14 @@ def balanced_resource_score(
 ) -> jnp.ndarray:
     """[T, N] — balanced_resource_allocation.go:41-70.
 
-    Fractions are computed in float32 (the host uses float64); the floor
+    Fractions are computed in float32, rounded to nearest on every
+    backend (div_rn), where the host uses float64; the floor
     can differ by 1 when (1-|Δfrac|)*10 sits within float32 eps of an
     integer.  Bounded, rare, and only able to flip exact-tie argmaxes —
     jax-allocate's validation keeps any such placement feasible."""
     req = task_resreq[:, None, :2] + node_used[None, :, :2]
     cap = node_alloc[None, :, :2]
-    frac = jnp.where(cap > 0, req / jnp.maximum(cap, 1.0), 1.0)
+    frac = jnp.where(cap > 0, div_rn(req, jnp.maximum(cap, 1.0)), 1.0)
     cpu_f, mem_f = frac[..., 0], frac[..., 1]
     diff = jnp.abs(cpu_f - mem_f)
     score = jnp.floor((1.0 - diff) * MAX_PRIORITY)
@@ -316,15 +344,92 @@ def node_scores(
     return s
 
 
-# ---- greedy assignment scan ----
 
-class _ScanState(NamedTuple):
+# ---- greedy assignment scan with Volcano's per-job commit/discard ----
+
+#: ``jstate`` values the episode scans keep per job row
+J_UNSEEN, J_READY, J_ENDED, J_DISCARDED = 0, 1, -1, -2
+
+#: ``placed`` value of a row the scan never tried: its job had already
+#: ended (a task that fit nowhere) or been discarded
+NOT_REACHED = -2
+
+
+class ScanOutcome(NamedTuple):
+    """What one allocate pass decided, row by row (the first ``n_tasks``
+    ordered rows).
+
+    ``assignment`` — the committed node, or -1.  ``placed`` — the node
+    the scan put the row on before its job's commit or discard (kept or
+    rolled back), -1 where the row fit no node, ``NOT_REACHED`` where
+    the scan skipped it.  ``reasons`` — for each row that fit no node,
+    the node counts per first failing predicate at that step of the
+    scan ([n_tasks, N_EXPLAIN_REASONS], zero elsewhere), or None where
+    the formulation does not report them."""
+
+    assignment: np.ndarray
+    placed: np.ndarray
+    reasons: Optional[np.ndarray] = None
+
+    @staticmethod
+    def of_assignment(assignment: np.ndarray) -> "ScanOutcome":
+        """A pass that placed or failed every row once and never rolled
+        one back (a session without multi-task gangs)."""
+        a = np.asarray(assignment, dtype=np.int32)
+        return ScanOutcome(a, a.copy(), None)
+
+
+def class_reason_codes(class_sel, class_tol, node_label_bits, node_taint_bits,
+                       node_ok, xp=jnp):
+    """[C, N] the first static predicate each (feasibility class, node)
+    pair fails, in host order — ``R_UNSCHED``, ``R_SEL``, ``R_TOL`` — or
+    ``N_EXPLAIN_REASONS`` where it passes all three.  The scans read
+    feasibility and the failing step's reasons off the same plane."""
+    sel_ok = xp.all((class_sel[:, None, :] & ~node_label_bits[None, :, :]) == 0, axis=-1)
+    tol_ok = xp.all((node_taint_bits[None, :, :] & ~class_tol[:, None, :]) == 0, axis=-1)
+    ok = xp.asarray(node_ok)[None, :]
+    return xp.where(
+        ~ok, R_UNSCHED,
+        xp.where(~sel_ok, R_SEL, xp.where(~tol_ok, R_TOL, N_EXPLAIN_REASONS)),
+    ).astype(xp.int32)
+
+
+def step_reason_counts(fit, room, code_row, valid, spread=None):
+    """[N_EXPLAIN_REASONS] node counts per first failing predicate of
+    one row at one step — explain_counts' precedence over the scan's
+    live planes: ``fit``, ``room`` [N] bool, ``code_row`` [N] the row's
+    class codes, ``valid`` [N] real nodes; ``spread`` =
+    (label_fail, skew_fail [K, N], absolute [K]) or None."""
+    feasible = N_EXPLAIN_REASONS
+    reason = jnp.where(~fit, R_FIT, jnp.where(~room, R_ROOM, code_row))
+    if spread is not None:
+        label_fail, skew_fail, absolute = spread
+        first = jnp.full(reason.shape, feasible, jnp.int32)
+        for k in reversed(range(label_fail.shape[0])):
+            first = jnp.where(
+                label_fail[k], R_SPREAD_LABEL,
+                jnp.where(skew_fail[k] & ~absolute[k], R_SPREAD, first),
+            )
+        anti = jnp.any(skew_fail & absolute[:, None], axis=0)
+        first = jnp.where((first == feasible) & anti, R_AFFINITY, first)
+        reason = jnp.where(reason == feasible, first, reason)
+    reason = jnp.where(valid, reason, feasible)
+    return jnp.stack([jnp.sum(reason == p, dtype=jnp.int32) for p in range(feasible)])
+
+
+class _EpisodeState(NamedTuple):
     # used_ext packs [used lanes..., task count] so one scatter per step
     # updates both (scatters are the dominant per-step cost at large N).
     used_ext: jnp.ndarray  # [N, R+1]
-    job_assigned: jnp.ndarray  # [J]
     #: [K, N] each node's domain count per spread class, or None
-    spread_count: object = None
+    spread_count: object
+    #: node state when the open run of a gang began (restored on discard)
+    saved_used: jnp.ndarray
+    saved_spread: object
+    jstate: jnp.ndarray  # [J] i32, J_* values
+    cur_job: jnp.ndarray  # job row of the open run, -1 = none
+    run_placed: jnp.ndarray  # placements of the open run
+    run_need: jnp.ndarray  # placements the open run needs to commit
 
 
 #: stands in for "no eligible domain" in the spread minimum: every
@@ -362,6 +467,18 @@ def spread_update(dom, elig, count, inc, best, ok):
     return count + jnp.where(dom == dom[:, best][:, None], add[:, None], 0.0)
 
 
+def step_fit(tolerance, idle, resreq):
+    """[N] resource fit of one row against every node's idle lanes,
+    with the sub-tolerance skip on scalar lanes only (see
+    predicate_mask)."""
+    scalar_lane = jnp.arange(resreq.shape[-1]) >= 2
+    return jnp.all(
+        (resreq[None, :] < idle + tolerance[None, :])
+        | (scalar_lane[None, :] & (resreq[None, :] <= tolerance[None, :])),
+        axis=-1,
+    )
+
+
 def step_feasible_score(
     weights: ScoreWeights,
     tolerance,
@@ -375,18 +492,10 @@ def step_feasible_score(
 ):
     """Per-step feasibility + masked score for the single-chip scan step
     below.  (The blocked/sharded kernels use the same semantics through
-    blocked._block_scores / blocked.make_inner_step; the sharded mesh
-    kernel no longer consumes this helper.)  Sub-tolerance skip on scalar
-    lanes only (see predicate_mask)."""
+    blocked._block_scores / blocked.make_inner_step.)"""
     used = used_ext[:, :-1]
     count = used_ext[:, -1]
-    idle = base - used
-    scalar_lane = jnp.arange(resreq.shape[-1]) >= 2
-    fit = jnp.all(
-        (resreq[None, :] < idle + tolerance[None, :])
-        | (scalar_lane[None, :] & (resreq[None, :] <= tolerance[None, :])),
-        axis=-1,
-    )
+    fit = step_fit(tolerance, base - used, resreq)
     feasible = fit & (count < node_max_tasks) & feas_row & active
     score = node_scores(resreq[None, :], used, node_alloc, weights)[0]
     return feasible, jnp.where(feasible, score, -jnp.inf)
@@ -398,51 +507,122 @@ def step_delta_ext(resreq, ok):
     return jnp.concatenate([resreq, jnp.ones((1,), resreq.dtype)]) * okf
 
 
-def _assign_step(
-    weights: ScoreWeights,
-    tolerance,
-    base,
-    node_alloc,
-    node_max_tasks,
-    state: _ScanState,
-    task: Tuple,
-    spread_nodes=None,
-):
-    """One task: mask → score → argmax → tentative allocate.
+def _close_run(st: _EpisodeState, close, now_placed) -> _EpisodeState:
+    """End the open run where ``close``: a run short of its need is
+    discarded — the node state goes back to where the run began and the
+    job is marked discarded; otherwise the job is ready (J_READY) —
+    the caller marks it ended when a task failed."""
+    disc = close & (now_placed < st.run_need)
+    back = disc & (now_placed > 0)
+    used_ext = jnp.where(back, st.saved_used, st.used_ext)
+    spread = st.spread_count
+    if spread is not None:
+        spread = jnp.where(back, st.saved_spread, spread)
+    cj = jnp.maximum(st.cur_job, 0)
+    jstate = st.jstate.at[cj].set(
+        jnp.where(close, jnp.where(disc, J_DISCARDED, J_READY), st.jstate[cj])
+    )
+    return st._replace(used_ext=used_ext, spread_count=spread, jstate=jstate,
+                       cur_job=jnp.where(close, -1, st.cur_job))
 
-    Mirrors the per-task body of allocate.go:177-230 with the
-    resource-fit + plugin predicates folded into the mask and
-    SelectBestNode's tie-break made deterministic (first index).
-    ``spread_nodes`` = (dom, elig [K, N], absolute [K]) when the
-    session has spread or anti-affinity classes; the task then carries
-    its (thr, inc) columns and the state its running domain counts."""
-    resreq, sel_tol_row, job_idx, active = task[:4]
-    used_ext, job_assigned, spread_count = state
 
+def _episode_step(weights, tolerance, base, node_alloc, node_max_tasks,
+                  class_code, job_need, n_valid, spread_nodes, reasons,
+                  st: _EpisodeState, task):
+    """One ordered row under Volcano's per-job commit/discard
+    (drive_allocate_loop + gang_end_job): consecutive rows of one job
+    form a run — its episodes as ORDER listed them — opened by the
+    job's first row and closed when another job's row arrives, the
+    pass ends, or a row fits no node.  A run that closes short of the
+    job's need (minAvailable less its ready tasks; 0 once the job was
+    ready) is discarded: the node state goes back to the run's start.
+    A row that fits no node ends its job: its later rows are skipped."""
+    resreq, cls, j, valid = task[:4]
+    st = _close_run(st, valid & (j != st.cur_job) & (st.cur_job >= 0), st.run_placed)
+    js = st.jstate[j]
+    alive = valid & (js >= 0)
+    start = alive & (j != st.cur_job)
+    run_need = jnp.where(start, jnp.where(js == J_UNSEEN, job_need[j], 0), st.run_need)
+    keep = start & (run_need >= 2)
+    st = st._replace(
+        run_need=run_need,
+        run_placed=jnp.where(start, 0, st.run_placed),
+        cur_job=jnp.where(start, j, st.cur_job),
+        saved_used=jnp.where(keep, st.used_ext, st.saved_used),
+        saved_spread=(
+            None if st.spread_count is None
+            else jnp.where(keep, st.spread_count, st.saved_spread)
+        ),
+    )
+
+    code_row = class_code[cls]
     feasible, score = step_feasible_score(
         weights, tolerance, base, node_alloc, node_max_tasks,
-        used_ext, resreq, sel_tol_row, active,
+        st.used_ext, resreq, code_row == N_EXPLAIN_REASONS, alive,
     )
+    spread_fail = None
     if spread_nodes is not None:
         thr, inc = task[4:]
         dom, elig, absolute = spread_nodes
-        label_fail, skew_fail = spread_ok(dom, elig, spread_count, thr, absolute)
+        label_fail, skew_fail = spread_ok(dom, elig, st.spread_count, thr, absolute)
+        spread_fail = (label_fail, skew_fail, absolute)
         passes = ~jnp.any(label_fail | skew_fail, axis=0)
         feasible = feasible & passes
         score = jnp.where(passes, score, -jnp.inf)
     best = jnp.argmax(score)  # first max index — deterministic tie-break
     ok = feasible[best]
+    fail = alive & ~ok
 
-    used_ext = used_ext.at[best].add(step_delta_ext(resreq, ok))
-    job_assigned = job_assigned.at[job_idx].add(jnp.where(ok, 1, 0))
+    counts = None
+    if reasons:
+        def why():
+            used = st.used_ext[:, :-1]
+            fit = step_fit(tolerance, base - used, resreq)
+            room = st.used_ext[:, -1] < node_max_tasks
+            valid_n = jnp.arange(base.shape[0]) < n_valid
+            return step_reason_counts(fit, room, code_row, valid_n, spread_fail)
+
+        counts = jax.lax.cond(
+            fail, why, lambda: jnp.zeros((N_EXPLAIN_REASONS,), jnp.int32)
+        )
+
+    used_ext = st.used_ext.at[best].add(step_delta_ext(resreq, ok))
+    spread = st.spread_count
     if spread_nodes is not None:
-        spread_count = spread_update(dom, elig, spread_count, inc, best, ok)
+        spread = spread_update(dom, elig, spread, inc, best, ok)
+    placed_now = st.run_placed + jnp.where(ok, 1, 0)
+    st = st._replace(used_ext=used_ext, spread_count=spread, run_placed=placed_now)
+    st = _close_run(st, fail, placed_now)
+    st = st._replace(jstate=st.jstate.at[j].set(
+        jnp.where(fail & (st.jstate[j] == J_READY), J_ENDED, st.jstate[j])
+    ))
+    placed = jnp.where(alive, jnp.where(ok, best, -1), NOT_REACHED)
+    return st, (placed, counts)
 
-    chosen = jnp.where(ok, best, -1)
-    return _ScanState(used_ext, job_assigned, spread_count), chosen
+
+def _episode_scan(weights, tolerance, base, node_alloc, node_max_tasks,
+                  class_code, used_ext0, job_need, task_job, xs, n_valid,
+                  spread_nodes=None, count0=None, reasons=True):
+    """The pass over the rows ``xs`` = (resreq, feasibility class, job,
+    valid[, thr, inc]) → (assignment, placed, reasons)."""
+    J = job_need.shape[0]
+    init = _EpisodeState(
+        used_ext0, count0, used_ext0, count0,
+        jnp.zeros((J,), jnp.int32), jnp.int32(-1), jnp.int32(0), jnp.int32(0),
+    )
+
+    def step(st, task):
+        return _episode_step(weights, tolerance, base, node_alloc, node_max_tasks,
+                             class_code, job_need, n_valid, spread_nodes, reasons,
+                             st, task)
+
+    final, (placed, counts) = jax.lax.scan(step, init, xs)
+    final = _close_run(final, final.cur_job >= 0, final.run_placed)
+    committed = (placed >= 0) & (final.jstate[task_job] != J_DISCARDED)
+    return jnp.where(committed, placed, -1), placed, counts
 
 
-@functools.partial(jax.jit, static_argnames=("weights", "gang_rounds"))
+@functools.partial(jax.jit, static_argnames=("weights",))
 def schedule_session(
     task_resreq: jnp.ndarray,
     task_job: jnp.ndarray,
@@ -461,69 +641,27 @@ def schedule_session(
     tolerance: jnp.ndarray,
     task_valid: jnp.ndarray,  # [T] bool — padding mask
     weights: ScoreWeights = DEFAULT_WEIGHTS,
-    gang_rounds: int = 3,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Full-session kernel → (assignment[T] node index or -1, committed[T]).
-
-    Gang fixpoint: after each greedy pass, jobs with
-    assigned+ready < minAvailable are discarded (their tasks deactivated)
-    and the pass re-runs from the original state — device analogue of
-    per-job Statement.Commit/Discard.  ``gang_rounds`` bounds the cascade
-    (an unsettled fixpoint ships the last round's commits, which are
-    always individually valid placements).
-    """
-    # Static (state-independent) feasibility per [T, N]: labels, taints,
-    # node readiness.  Resource fit and pod-count recheck dynamically in
-    # the scan.
-    sel_ok = jnp.all(
-        (task_sel_bits[:, None, :] & ~node_label_bits[None, :, :]) == 0, axis=-1
-    )
-    tol_ok = jnp.all(
-        (node_taint_bits[None, :, :] & ~task_tol_bits[:, None, :]) == 0, axis=-1
-    )
-    static_feasible = sel_ok & tol_ok & node_ok[None, :]  # [T, N]
-
-    base = node_idle + node_used
+    """Full-session kernel over raw per-task bitsets → (assignment[T]
+    node index or -1, committed[T]), with the per-job commit/discard of
+    :func:`_episode_step`."""
+    T = task_resreq.shape[0]
+    code = class_reason_codes(task_sel_bits, task_tol_bits, node_label_bits,
+                              node_taint_bits, node_ok)
     used_ext0 = jnp.concatenate(
         [node_used, node_task_count.astype(node_used.dtype)[:, None]], axis=1
     )
-    init = _ScanState(used_ext0, jnp.zeros_like(job_min_available), None)
-
-    def one_pass(active):
-        def step(state, task):
-            return _assign_step(
-                weights, tolerance, base, node_alloc, node_max_tasks, state, task
-            )
-
-        final, chosen = jax.lax.scan(
-            step, init, (task_resreq, static_feasible, task_job, active)
-        )
-        return final, chosen
-
-    def round_body(carry, _):
-        active, _, _ = carry
-        final, chosen = one_pass(active)
-        ready = final.job_assigned + job_ready_count >= job_min_available
-        committed = ready[task_job] & (chosen >= 0)
-        # Discard tasks of non-ready jobs for the next round.
-        next_active = active & ready[task_job]
-        return (next_active, chosen, committed), None
-
-    carry0 = (
-        task_valid,
-        jnp.full_like(task_job, -1),
-        jnp.zeros_like(task_valid),
+    xs = (task_resreq, jnp.arange(T, dtype=jnp.int32), task_job, task_valid)
+    assignment, _, _ = _episode_scan(
+        weights, tolerance, node_idle + node_used, node_alloc, node_max_tasks,
+        code, used_ext0, job_min_available - job_ready_count, task_job, xs,
+        node_idle.shape[0], reasons=False,
     )
-    (active, chosen, committed), _ = jax.lax.scan(
-        round_body, carry0, None, length=gang_rounds
-    )
-
-    assignment = jnp.where(committed, chosen, -1)
-    return assignment, committed
+    return assignment, assignment >= 0
 
 
 @functools.partial(jax.jit, static_argnames=("weights",))
-def schedule_pass(
+def schedule_episodes(
     task_resreq: jnp.ndarray,
     task_job: jnp.ndarray,
     task_feas_class: jnp.ndarray,  # [T] index into class_sel/tol_bits
@@ -538,58 +676,39 @@ def schedule_pass(
     node_task_count: jnp.ndarray,
     node_max_tasks: jnp.ndarray,
     job_min_available: jnp.ndarray,
+    job_ready_count: jnp.ndarray,
     tolerance: jnp.ndarray,
-    active: jnp.ndarray,
+    valid: jnp.ndarray,  # [T] bool
+    n_nodes: jnp.ndarray,  # i32 — valid node rows
     spread=None,  # (thr, inc [T, K], dom, elig, count0 [K, N], absolute [K]) or None
     weights: ScoreWeights = DEFAULT_WEIGHTS,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One greedy pass → (chosen[T], job_assigned[J]).  The host loop in
-    run_packed applies the gang commit/discard between passes — typical
-    sessions converge after one pass instead of paying gang_rounds fixed
-    device rounds.  ``spread`` adds the hard topology spread and
-    anti-affinity mask, the per-class domain counts carried through the
-    scan (ops.packing's spread_task_columns and spread_node_planes).
+):
+    """One pass → (assignment[T], placed[T], reasons[T, P]): the plain
+    scan, the reference the other formulations are held to.  ``spread``
+    adds the hard topology spread and anti-affinity mask, the per-class
+    domain counts carried through the scan (ops.packing's
+    spread_task_columns and spread_node_planes).
 
     Static feasibility (labels/taints/readiness) is evaluated per distinct
     bitset signature class, not per task: the scan gathers a [N] row from
     the small [C, N] matrix instead of slicing a [T, N] one (at 50k×10k
     that matrix is 1 GB and its per-step slice dominated the step cost)."""
-    sel_ok = jnp.all(
-        (class_sel_bits[:, None, :] & ~node_label_bits[None, :, :]) == 0, axis=-1
-    )
-    tol_ok = jnp.all(
-        (node_taint_bits[None, :, :] & ~class_tol_bits[:, None, :]) == 0, axis=-1
-    )
-    class_feasible = sel_ok & tol_ok & node_ok[None, :]  # [C, N]
-
-    base = node_idle + node_used
+    code = class_reason_codes(class_sel_bits, class_tol_bits, node_label_bits,
+                              node_taint_bits, node_ok)
     used_ext0 = jnp.concatenate(
         [node_used, node_task_count.astype(node_used.dtype)[:, None]], axis=1
     )
-
-    xs = (task_resreq, task_feas_class, task_job, active)
+    xs = (task_resreq, task_feas_class, task_job, valid)
     spread_nodes = count0 = None
     if spread is not None:
         thr, inc, dom, elig, count0, absolute = spread
         xs = xs + (thr, inc)
         spread_nodes = (dom, elig, absolute)
-
-    def step(state, task):
-        resreq, feas_cls, job_idx, act = task[:4]
-        return _assign_step(
-            weights,
-            tolerance,
-            base,
-            node_alloc,
-            node_max_tasks,
-            state,
-            (resreq, class_feasible[feas_cls], job_idx, act) + task[4:],
-            spread_nodes,
-        )
-
-    init = _ScanState(used_ext0, jnp.zeros_like(job_min_available), count0)
-    final, chosen = jax.lax.scan(step, init, xs)
-    return chosen, final.job_assigned
+    return _episode_scan(
+        weights, tolerance, node_idle + node_used, node_alloc, node_max_tasks,
+        code, used_ext0, job_min_available - job_ready_count, task_job, xs,
+        n_nodes, spread_nodes, count0,
+    )
 
 
 def _feasibility_classes(snap: PackedSnapshot):
@@ -634,7 +753,7 @@ def _feasibility_classes(snap: PackedSnapshot):
 
 
 def spread_arrays(snap: PackedSnapshot, rows: int, width: int):
-    """schedule_pass's ``spread`` operand for ``rows`` task rows and
+    """schedule_episodes' ``spread`` operand for ``rows`` task rows and
     ``width`` node columns, or None when the session has no spread or
     anti-affinity class."""
     if not snap.has_spread:
@@ -650,25 +769,12 @@ def spread_arrays(snap: PackedSnapshot, rows: int, width: int):
     return tuple(jnp.asarray(a) for a in (thr, inc, dom, elig, count, class_absolute(snap)))
 
 
-def run_packed(
-    snap: PackedSnapshot,
-    weights: ScoreWeights = DEFAULT_WEIGHTS,
-    gang_rounds: int = 3,
-    discard_unstable: bool = False,
-) -> np.ndarray:
-    """Host wrapper: PackedSnapshot → assignment[T] (np.int32), with the
-    gang fixpoint driven host-side (adaptive: stops as soon as the active
-    set is stable, which for well-provisioned sessions is after round 1 —
-    identical outcome to the fixed-round schedule_session).
-
-    ``discard_unstable`` opts into the reference's Statement semantics
-    for an unsettled cascade (statement.go:309-337: discard until
-    stable): the loop ignores the ``gang_rounds`` bound and runs to the
-    true fixpoint.  Terminates structurally — every non-stable round
-    strictly shrinks the active set."""
+def run_outcome(snap: PackedSnapshot, weights: ScoreWeights = DEFAULT_WEIGHTS) -> ScanOutcome:
+    """Host wrapper of the plain scan: PackedSnapshot → ScanOutcome over
+    the first ``n_tasks`` rows."""
     T = snap.task_resreq.shape[0]
-    active = np.zeros(T, dtype=bool)
-    active[: snap.n_tasks] = True
+    valid = np.zeros(T, dtype=bool)
+    valid[: snap.n_tasks] = True
 
     # Large nodes fall outside the f32 floor-division exactness envelope
     # (see least_requested_score) — switch to exact int division.
@@ -699,31 +805,24 @@ def run_packed(
             _dp(snap, "node_task_count"),
             _dp(snap, "node_max_tasks"),
             _dp(snap, "job_min_available"),
+            _dp(snap, "job_ready_count"),
             _dp(snap, "tolerance"),
         )
     ]
     spread = spread_arrays(snap, T, snap.node_idle.shape[0])
-    task_job = snap.task_job
-    min_avail = snap.job_min_available.astype(np.int64)
-    ready_count = snap.job_ready_count.astype(np.int64)
+    assignment, placed, counts = schedule_episodes(
+        *dev, jnp.asarray(valid), jnp.int32(snap.n_nodes), spread=spread,
+        weights=weights,
+    )
+    n = snap.n_tasks
+    return ScanOutcome(
+        np.asarray(assignment)[:n].astype(np.int32),
+        np.asarray(placed)[:n].astype(np.int32),
+        np.asarray(counts)[:n],
+    )
 
-    chosen_np = np.full(T, -1, dtype=np.int32)
-    committed = np.zeros(T, dtype=bool)
-    rounds = 0
-    while True:
-        chosen, job_assigned = schedule_pass(
-            *dev, jnp.asarray(active), spread=spread, weights=weights
-        )
-        chosen_np = np.asarray(chosen)
-        ready = np.asarray(job_assigned, dtype=np.int64) + ready_count >= min_avail
-        committed = ready[task_job] & (chosen_np >= 0)
-        next_active = active & ready[task_job]
-        rounds += 1
-        if (next_active == active).all():
-            break
-        if not discard_unstable and rounds >= gang_rounds:
-            break
-        active = next_active
 
-    assignment = np.where(committed & active, chosen_np, -1)
-    return assignment[: snap.n_tasks]
+def run_packed(snap: PackedSnapshot, weights: ScoreWeights = DEFAULT_WEIGHTS) -> np.ndarray:
+    """PackedSnapshot → committed assignment[n_tasks] (np.int32) of the
+    plain scan (:func:`run_outcome`)."""
+    return run_outcome(snap, weights).assignment

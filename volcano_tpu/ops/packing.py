@@ -205,6 +205,24 @@ class PackedSnapshot:
     def has_spread(self) -> bool:
         return self.spread_max_skew is not None and self.spread_max_skew.size > 0
 
+    def gang_need(self) -> np.ndarray:
+        """[n_tasks] placements each row's job still needs to turn ready
+        (minAvailable less its ready tasks), as the kernels read it."""
+        jobs = self.task_job[: self.n_tasks]
+        return self.job_min_available[jobs].astype(np.int64) - self.job_ready_count[jobs]
+
+    @property
+    def episodic(self) -> bool:
+        """True when a row can be placed and then rolled back, or skipped
+        after its job ended: some job has more than one row, or needs
+        more than one placement to turn ready.  Only the episode scans
+        (ops/kernels.py, ops/pallas_session.py, native) take such a
+        session."""
+        if self.n_tasks == 0:
+            return False
+        jobs = self.task_job[: self.n_tasks]
+        return bool(np.bincount(jobs).max() > 1 or (self.gang_need() >= 2).any())
+
     @property
     def shape_key(self) -> Tuple[int, int, int, int, int]:
         return (

@@ -15,17 +15,17 @@ dominates at 50k steps.  This kernel runs the ENTIRE scan inside one
   * each step is ~90 VPU ops over [NS, 128] node planes (~10 cycles per
     op at 10k nodes) → sub-µs per task instead of tens of µs.
 
-Semantics are op-for-op identical to ops/kernels.py `schedule_pass`
+Semantics are op-for-op identical to ops/kernels.py `schedule_episodes`
 (same predicate mask, same score arithmetic and operation order, same
 first-lowest-node-index tie-break), so host/device/native bindings
-equivalence carries over.  The gang commit/discard fixpoint
-(Statement.Commit/Discard, statement.go:309-337) runs ON DEVICE inside
-the same jitted program (`schedule_session_pallas`): a `lax.while_loop`
-re-runs the kernel with discarded jobs deactivated until the active set
-is stable, so the whole session pays exactly ONE host→device→host round
-trip regardless of how many gang rounds it takes — through a
-high-latency device link each avoided round trip is worth far more than
-the kernel time itself.
+equivalence carries over.  Volcano's per-job gang commit/discard
+(drive_allocate_loop + gang_end_job over Statement.Commit/Discard,
+statement.go:309-337) runs in the same pass: a job's run of rows that
+closes short of minAvailable puts back the node state it began from, a
+task that fits no node ends its job, and that step's per-reason node
+counts ride out beside the placements — so the whole session pays one
+host→device→host round trip and the host never sweeps the nodes for a
+discarded gang.
 
 Hard topology spread and required pod anti-affinity (K classes,
 ops/packing.pack_spread) ride the same layout: each task row gains K
@@ -53,9 +53,18 @@ from jax.experimental.pallas import tpu as pltpu
 from volcano_tpu.ops.chip_memory import pallas_compiler_params
 from volcano_tpu.ops.kernels import (
     _feasibility_classes,
+    class_reason_codes,
     DEFAULT_WEIGHTS,
+    div_rn,
     f32_lr_exact,
     MAX_PRIORITY,
+    N_EXPLAIN_REASONS,
+    R_AFFINITY,
+    R_FIT,
+    R_ROOM,
+    R_SPREAD,
+    R_SPREAD_LABEL,
+    ScanOutcome,
     ScoreWeights,
     SPREAD_BIG,
 )
@@ -81,8 +90,10 @@ def score_planes(
 ):
     """Total node-score plane for one task — the in-kernel copy of
     kernels.py node_scores (binpack + least-requested + balanced), with
-    the same op order and f32 rounding.  Shared by the allocate scan
-    kernel below and the preempt kernel (ops/preempt_pallas.py)."""
+    the same op order and f32 rounding: each division rounded to nearest
+    (kernels.div_rn), as the TPU's reciprocal-and-multiply divide is not
+    (benchmark/tests/score_division_check.py).  Shared by the allocate
+    scan kernel below and the preempt kernel (ops/preempt_pallas.py)."""
     R = len(rr)
     w_bp = float(weights.binpack_weight)
     lane_w = [float(weights.binpack_cpu), float(weights.binpack_memory)] + [
@@ -99,7 +110,7 @@ def score_planes(
             continue
         reqmask = rr[r] > 0.0
         valid = reqmask & (allocpos(r) > 0.0) & (req[r] <= alloc(r))
-        lane = jnp.where(valid, req[r] * lane_w[r] / maxal(r), 0.0)
+        lane = jnp.where(valid, div_rn(req[r] * lane_w[r], maxal(r)), 0.0)
         bp = lane if bp is None else bp + lane
         ws = ws + jnp.where(reqmask, jnp.float32(lane_w[r]), 0.0)
     if bp is None:
@@ -109,7 +120,8 @@ def score_planes(
         # `score * MAX_PRIORITY * weights.binpack_weight` f32 rounding
         # exactly (folding the constants can differ by 1 ulp for
         # non-default weights).
-        s_bp = jnp.where(ws > 0.0, bp / ws, 0.0) * jnp.float32(MAX_PRIORITY)
+        s_bp = jnp.where(ws > 0.0, div_rn(bp, jnp.maximum(ws, 1e-30)), 0.0) * jnp.float32(
+            MAX_PRIORITY)
         if w_bp != 1.0:
             s_bp = s_bp * jnp.float32(w_bp)
 
@@ -125,7 +137,7 @@ def score_planes(
         lane = jnp.where((allocpos(r) > 0.0) & (req[r] <= cap), q, 0.0)
         lr = lane if lr is None else lr + lane
         # balanced fractions reuse req/cap
-        fracs.append(jnp.where(allocpos(r) > 0.0, req[r] / c, 1.0))
+        fracs.append(jnp.where(allocpos(r) > 0.0, div_rn(req[r], c), 1.0))
     s_lr = jnp.floor(lr * 0.5)
 
     # --- balanced resource ---
@@ -137,46 +149,66 @@ def score_planes(
     return s_bp + jnp.float32(w_lr) * s_lr + jnp.float32(w_bal) * s_bal
 
 
-def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights, K: int = 0,
-                 absolute: Tuple[bool, ...] = ()):
+def _make_kernel(R: int, TB: int, NS: int, JS: int, weights: ScoreWeights,
+                 K: int = 0, absolute: Tuple[bool, ...] = (), G: int = 1):
     """Kernel factory — R resource lanes, TB tasks per grid step, NS node
-    sublanes (nodes = NS*128), static plugin weights, K classes, of which
-    those flagged in ``absolute`` (missing entries read False) are of
-    the absolute kind.
+    sublanes (nodes = NS*128), JS job sublanes, static plugin weights, K
+    classes, of which those flagged in ``absolute`` (missing entries read
+    False) are of the absolute kind, G grid steps.
+
+    Gang commit/discard (drive_allocate_loop + gang_end_job, in one
+    pass): consecutive rows of one job form a run, opened by its first
+    row and closed when another job's row arrives, the pass ends, or a
+    row fits no node.  A run needing two or more placements keeps a copy
+    of the node state it began from; closing short of its need restores
+    it, and the job is marked discarded in the per-job state plane.  A
+    row that fits no node ends its job — its later rows are skipped —
+    and records, at that step, how many nodes fail each predicate first
+    (ops/kernels.py step_reason_counts).
 
     Incremental repeated-row fast path: a placement at step k-1 changes
     node state at ONE node, so when task k's row (resreq lanes + class +
-    active) equals task k-1's, every node's masked score is unchanged
-    except the selected node's — the kernel keeps the masked-score plane
-    in VMEM scratch and recomputes only the [1, 128] sublane row holding
-    the previous pick.  Gangs submit replicas with identical rows
-    (job.go:43-60: one PodTemplate per task group), so at gang_size g,
-    (g-1)/g of all steps take the fast path.  Every recomputation uses
-    the same elementwise formulas, so results stay bit-identical to the
-    full per-step recompute (and to kernels.py schedule_pass).  The
-    spread mask, which a placement moves across a whole domain, is kept
-    out of that plane: it is applied to it afresh every step."""
+    active + class columns; the job column aside) equals task k-1's,
+    every node's masked score is unchanged except the selected node's —
+    the kernel keeps the masked-score plane in VMEM scratch and
+    recomputes only the [1, 128] sublane row holding the previous pick.
+    Gangs submit replicas with identical rows (job.go:43-60: one
+    PodTemplate per task group), so at gang_size g, (g-1)/g of all steps
+    take the fast path; a rollback invalidates the plane.  Every
+    recomputation uses the same elementwise formulas, so results stay
+    bit-identical to the full per-step recompute (and to kernels.py
+    schedule_episodes).  The spread mask, which a placement moves across
+    a whole domain, is kept out of that plane: it is applied to it
+    afresh every step."""
 
     TBS = TB // LANES
     kinds = tuple(absolute) + (False,) * (K - len(absolute))
-    RC = R + 2 + 2 * K  # task row width
+    RC = R + 3 + 2 * K  # task row width
+    JC = RC - 1  # job column
     SP = 3 * R + 2  # first spread plane of nd
+    OK = float(N_EXPLAIN_REASONS)  # class code of a passing (class, node) pair
+    PAD = float(N_EXPLAIN_REASONS + 1)  # class code of a padding node
 
     def kernel(
         tol_ref,  # SMEM [1, R]
-        task_ref,  # VMEM [TB, RC] — resreq lanes, feas class, active, thr[K], inc[K]
-        cf_ref,  # VMEM [C, NS, 128] f32 class feasibility (incl. node_ok)
+        task_ref,  # VMEM [TB, RC] — resreq lanes, class, active, thr[K], inc[K], job
+        cf_ref,  # VMEM [C, NS, 128] f32 class codes (kernels.class_reason_codes)
         nd_ref,  # VMEM [3R+2+3K, NS, 128] — base | alloc | used0 | count0, maxt | dom[K] | elig[K] | scount0[K]
         maxal_ref,  # VMEM [R, NS, 128] max(alloc, 1)
         allocpos_ref,  # VMEM [R, NS, 128] f32 (alloc > 0)
-        chosen_ref,  # out VMEM [1, TBS, 128] i32
+        need_ref,  # VMEM [JS, 128] f32 — each job's need (minAvailable - ready)
+        chosen_ref,  # out VMEM [1, TBS, 128] i32 — placed node, -1 failed, -2 skipped
+        why_ref,  # out VMEM [1, P, TBS, 128] i32 — failing steps' reason counts
+        jst_ref,  # out VMEM [JS, 128] f32 — per-job state (kernels.J_*), resident
         used_s,  # scratch VMEM [R, NS, 128]
         cnt_s,  # scratch VMEM [1, NS, 128]
         chosen_s,  # scratch VMEM [TBS, 128] i32
         masked_s,  # scratch VMEM [NS, 128] f32 — masked scores, kept current
         prev_s,  # scratch VMEM [1, RC] f32 — previous task row
-        ctrl_s,  # scratch SMEM [2] i32 — have_prev, prev_best (-1 = none)
-        *spread_s,  # K > 0: scratch VMEM [K, NS, 128] f32 — domain counts
+        ctrl_s,  # scratch SMEM [5] i32 — have_prev, prev_best, run job, run placed, run need
+        sv_used,  # scratch VMEM [R, NS, 128] — node state the open run began from
+        sv_cnt,  # scratch VMEM [1, NS, 128]
+        *spread_s,  # K > 0: scratch VMEM [K, NS, 128] f32 — domain counts, and its copy
     ):
         i = pl.program_id(0)
 
@@ -186,8 +218,14 @@ def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights, K: int = 0,
             cnt_s[:] = nd_ref[3 * R : 3 * R + 1]
             if K:
                 spread_s[0][:] = nd_ref[SP + 2 * K : SP + 3 * K]
+            jst_ref[:] = jnp.zeros((JS, LANES), jnp.float32)
             ctrl_s[0] = 0
             ctrl_s[1] = -1
+            ctrl_s[2] = -1
+            ctrl_s[3] = 0
+            ctrl_s[4] = 0
+
+        why_ref[...] = jnp.zeros(why_ref.shape, jnp.int32)
 
         idxp = (
             jax.lax.broadcasted_iota(jnp.int32, (NS, LANES), 0) * LANES
@@ -201,141 +239,240 @@ def _make_kernel(R: int, TB: int, NS: int, weights: ScoreWeights, K: int = 0,
         clane = jax.lax.broadcasted_iota(jnp.int32, (TBS, LANES), 1)
         lane1 = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
 
+        def job_get(ref, j):
+            return jnp.sum(jnp.where(lane1 == j % LANES, ref[pl.ds(j // LANES, 1), :], 0.0))
+
+        def job_set(j, value):
+            rowj = jst_ref[pl.ds(j // LANES, 1), :]
+            jst_ref[pl.ds(j // LANES, 1), :] = jnp.where(lane1 == j % LANES, value, rowj)
+
+        def close_run(placed):
+            """End the open run: restore the node state and mark the job
+            discarded when it closes short of its need, else mark it
+            ready."""
+            cur = ctrl_s[2]
+            disc = placed < ctrl_s[4]
+
+            @pl.when(jnp.logical_and(disc, placed > 0))
+            def _():
+                used_s[:] = sv_used[:]
+                cnt_s[:] = sv_cnt[:]
+                if K:
+                    spread_s[0][:] = spread_s[1][:]
+                ctrl_s[0] = 0  # the masked-score plane predates the restore
+
+            job_set(cur, jnp.where(disc, -2.0, 1.0))
+            ctrl_s[2] = -1
+
         def step(k, _):
-            row = task_ref[pl.ds(k, 1), :]  # [1, R+2]
+            row = task_ref[pl.ds(k, 1), :]  # [1, RC]
 
             def col(r):
                 return jnp.sum(jnp.where(row_lane == r, row, 0.0))
 
-            act = col(R + 1)
-            cls = col(R).astype(jnp.int32)
-            rr = [col(r) for r in range(R)]
+            act = col(R + 1) > 0.0
+            job = col(JC).astype(jnp.int32)
 
-            have_prev = ctrl_s[0] > 0
-            prev_best = ctrl_s[1]
-            same = jnp.logical_and(have_prev, jnp.all(row == prev_s[:]))
+            @pl.when(jnp.logical_and(act, jnp.logical_and(job != ctrl_s[2], ctrl_s[2] >= 0)))
+            def _():
+                close_run(ctrl_s[3])
 
-            def masked_row(rowslice):
-                """Masked score over one node row-slice view ([NS|1, 128])
-                — the single copy of the predicate + score arithmetic;
-                ``rowslice(ref_3d, plane)`` selects a plane row."""
-                cnt = rowslice(cnt_s, 0)
-                cf = rowslice(cf_ref, cls)
-                fit = None
-                req = []
-                for r in range(R):
-                    used_r = rowslice(used_s, r)
-                    idle_r = rowslice(nd_ref, r) - used_r
-                    lane_ok = rr[r] < idle_r + tol_ref[0, r]
-                    if r >= 2:
-                        lane_ok = jnp.logical_or(lane_ok, rr[r] <= tol_ref[0, r])
-                    fit = lane_ok if fit is None else jnp.logical_and(fit, lane_ok)
-                    req.append(rr[r] + used_r)  # shared by all three scores
-                feas = (
-                    fit
-                    & (cnt < rowslice(nd_ref, 3 * R + 1))
-                    & (cf > 0.0)
-                    & (act > 0.0)
-                )
-                total = score_planes(
-                    rr,
-                    req,
-                    lambda r: rowslice(nd_ref, R + r),
-                    lambda r: rowslice(maxal_ref, r),
-                    lambda r: rowslice(allocpos_ref, r),
-                    weights,
-                    feas.shape,
-                )
-                return jnp.where(feas, total, -jnp.inf)
+            js = job_get(jst_ref, job)
+            alive = jnp.logical_and(act, js >= 0.0)
+            kmask = (csub == k // LANES) & (clane == k % LANES)
 
-            @pl.when(jnp.logical_not(same))
-            def _full():
-                masked_s[:] = masked_row(lambda ref, p: ref[p])
+            @pl.when(jnp.logical_and(alive, job != ctrl_s[2]))
+            def _start():
+                need = jnp.where(js == 0.0, job_get(need_ref, job), 0.0).astype(jnp.int32)
+                ctrl_s[2] = job
+                ctrl_s[3] = 0
+                ctrl_s[4] = need
 
-            @pl.when(jnp.logical_and(same, prev_best >= 0))
-            def _inc():
-                bq = prev_best // LANES
-                masked_s[pl.ds(bq, 1), :] = masked_row(
-                    lambda ref, p: ref[p, pl.ds(bq, 1), :]
+                @pl.when(need >= 2)
+                def _():
+                    sv_used[:] = used_s[:]
+                    sv_cnt[:] = cnt_s[:]
+                    if K:
+                        spread_s[1][:] = spread_s[0][:]
+
+            @pl.when(jnp.logical_not(alive))
+            def _skip():
+                chosen_s[:] = jnp.where(kmask, jnp.int32(-2), chosen_s[:])
+
+            @pl.when(alive)
+            def _place():
+                cls = col(R).astype(jnp.int32)
+                rr = [col(r) for r in range(R)]
+
+                have_prev = ctrl_s[0] > 0
+                prev_best = ctrl_s[1]
+                same = jnp.logical_and(
+                    have_prev, jnp.all((row == prev_s[:]) | (row_lane == JC))
                 )
 
-            # --- spread and anti-affinity classes (kernels.spread_ok) ---
-            masked = masked_s[:]
-            for c in range(K):
-                thr = col(R + 2 + c)
-                count = spread_s[0][c]
-                dom = nd_ref[SP + c]
-                if kinds[c]:
-                    # the minimum is taken as 0; a node without the key passes
-                    passes = (thr < 0.0) | (dom < 0.0) | (count <= thr)
-                else:
-                    mn = jnp.min(jnp.where(nd_ref[SP + K + c] > 0.0, count, SPREAD_BIG))
-                    passes = (thr < 0.0) | ((dom >= 0.0) & (count - mn <= thr))
-                masked = jnp.where(passes, masked, -jnp.inf)
+                def fit_room(rowslice):
+                    """(fit, room, req) over one node row-slice view."""
+                    fit = None
+                    req = []
+                    for r in range(R):
+                        used_r = rowslice(used_s, r)
+                        idle_r = rowslice(nd_ref, r) - used_r
+                        lane_ok = rr[r] < idle_r + tol_ref[0, r]
+                        if r >= 2:
+                            lane_ok = jnp.logical_or(lane_ok, rr[r] <= tol_ref[0, r])
+                        fit = lane_ok if fit is None else jnp.logical_and(fit, lane_ok)
+                        req.append(rr[r] + used_r)  # shared by all three scores
+                    room = rowslice(cnt_s, 0) < rowslice(nd_ref, 3 * R + 1)
+                    return fit, room, req
 
-            # --- lowest-index argmax + row-sliced state update ---
-            m = jnp.max(masked)
-            ok = jnp.isfinite(m)
-            best = jnp.min(jnp.where(masked == m, idxp, INT_BIG))
+                def masked_row(rowslice):
+                    """Masked score over one node row-slice view ([NS|1, 128])
+                    — the single copy of the predicate + score arithmetic;
+                    ``rowslice(ref_3d, plane)`` selects a plane row."""
+                    fit, room, req = fit_room(rowslice)
+                    feas = fit & room & (rowslice(cf_ref, cls) == OK)
+                    total = score_planes(
+                        rr,
+                        req,
+                        lambda r: rowslice(nd_ref, R + r),
+                        lambda r: rowslice(maxal_ref, r),
+                        lambda r: rowslice(allocpos_ref, r),
+                        weights,
+                        feas.shape,
+                    )
+                    return jnp.where(feas, total, -jnp.inf)
 
-            @pl.when(ok)
-            def _update():
-                bq = best // LANES
-                selr = lane1 == best % LANES
-                for r in range(R):
-                    used_s[r, pl.ds(bq, 1), :] = used_s[
-                        r, pl.ds(bq, 1), :
-                    ] + jnp.where(selr, rr[r], 0.0)
-                cnt_s[0, pl.ds(bq, 1), :] = cnt_s[0, pl.ds(bq, 1), :] + jnp.where(
-                    selr, 1.0, 0.0
-                )
-                # kernels.spread_update: the chosen node's whole domain
-                for c in range(K):
-                    dom_b = jnp.sum(jnp.where(selr, nd_ref[SP + c, pl.ds(bq, 1), :], 0.0))
-                    elig_b = jnp.sum(jnp.where(selr, nd_ref[SP + K + c, pl.ds(bq, 1), :], 0.0))
-                    add = col(R + 2 + K + c) * elig_b
-                    spread_s[0][c] = spread_s[0][c] + jnp.where(
-                        nd_ref[SP + c] == dom_b, add, 0.0
+                @pl.when(jnp.logical_not(same))
+                def _full():
+                    masked_s[:] = masked_row(lambda ref, p: ref[p])
+
+                @pl.when(jnp.logical_and(same, prev_best >= 0))
+                def _inc():
+                    bq = prev_best // LANES
+                    masked_s[pl.ds(bq, 1), :] = masked_row(
+                        lambda ref, p: ref[p, pl.ds(bq, 1), :]
                     )
 
-            kmask = (csub == k // LANES) & (clane == k % LANES)
-            chosen_s[:] = jnp.where(
-                kmask, jnp.where(ok, best, jnp.int32(-1)), chosen_s[:]
-            )
-            prev_s[:] = row
-            ctrl_s[0] = 1
-            ctrl_s[1] = jnp.where(ok, best, jnp.int32(-1))
+                # --- spread and anti-affinity classes (kernels.spread_ok) ---
+                masked = masked_s[:]
+                fails = []
+                for c in range(K):
+                    thr = col(R + 2 + c)
+                    count = spread_s[0][c]
+                    dom = nd_ref[SP + c]
+                    on = thr >= 0.0
+                    if kinds[c]:
+                        # the minimum is taken as 0; a node without the key passes
+                        label_fail = None
+                        skew_fail = on & (dom >= 0.0) & (count > thr)
+                    else:
+                        mn = jnp.min(jnp.where(nd_ref[SP + K + c] > 0.0, count, SPREAD_BIG))
+                        label_fail = on & (dom < 0.0)
+                        skew_fail = on & (dom >= 0.0) & (count - mn > thr)
+                    fails.append((label_fail, skew_fail))
+                    bad = skew_fail if label_fail is None else (label_fail | skew_fail)
+                    masked = jnp.where(bad, -jnp.inf, masked)
+
+                # --- lowest-index argmax + row-sliced state update ---
+                m = jnp.max(masked)
+                ok = jnp.isfinite(m)
+                best = jnp.min(jnp.where(masked == m, idxp, INT_BIG))
+
+                @pl.when(ok)
+                def _update():
+                    bq = best // LANES
+                    selr = lane1 == best % LANES
+                    for r in range(R):
+                        used_s[r, pl.ds(bq, 1), :] = used_s[
+                            r, pl.ds(bq, 1), :
+                        ] + jnp.where(selr, rr[r], 0.0)
+                    cnt_s[0, pl.ds(bq, 1), :] = cnt_s[0, pl.ds(bq, 1), :] + jnp.where(
+                        selr, 1.0, 0.0
+                    )
+                    # kernels.spread_update: the chosen node's whole domain
+                    for c in range(K):
+                        dom_b = jnp.sum(jnp.where(selr, nd_ref[SP + c, pl.ds(bq, 1), :], 0.0))
+                        elig_b = jnp.sum(jnp.where(selr, nd_ref[SP + K + c, pl.ds(bq, 1), :], 0.0))
+                        add = col(R + 2 + K + c) * elig_b
+                        spread_s[0][c] = spread_s[0][c] + jnp.where(
+                            nd_ref[SP + c] == dom_b, add, 0.0
+                        )
+                    ctrl_s[3] = ctrl_s[3] + 1
+
+                chosen_s[:] = jnp.where(
+                    kmask, jnp.where(ok, best, jnp.int32(-1)), chosen_s[:]
+                )
+                prev_s[:] = row
+                ctrl_s[0] = 1
+                ctrl_s[1] = jnp.where(ok, best, jnp.int32(-1))
+
+                @pl.when(jnp.logical_not(ok))
+                def _fail():
+                    # why no node fits, at this step (kernels.step_reason_counts)
+                    fit, room, _ = fit_room(lambda ref, p: ref[p])
+                    code = cf_ref[cls]
+                    reason = jnp.where(~fit, float(R_FIT), jnp.where(~room, float(R_ROOM), code))
+                    if K:
+                        first = jnp.full((NS, LANES), OK, jnp.float32)
+                        for c in reversed(range(K)):
+                            label_fail, skew_fail = fails[c]
+                            if kinds[c]:
+                                continue
+                            first = jnp.where(label_fail, float(R_SPREAD_LABEL),
+                                              jnp.where(skew_fail, float(R_SPREAD), first))
+                        anti = None
+                        for c in range(K):
+                            if kinds[c]:
+                                anti = fails[c][1] if anti is None else (anti | fails[c][1])
+                        if anti is not None:
+                            first = jnp.where((first == OK) & anti, float(R_AFFINITY), first)
+                        reason = jnp.where(reason == OK, first, reason)
+                    reason = jnp.where(code == PAD, PAD, reason)
+                    for p in range(N_EXPLAIN_REASONS):
+                        n_p = jnp.sum(jnp.where(reason == float(p), 1, 0))
+                        why_ref[0, p] = jnp.where(kmask, n_p, why_ref[0, p])
+                    close_run(ctrl_s[3])
+                    # a job that was ready when its task failed stays
+                    # committed and ends: mark it so its later rows skip
+                    job_set(job, jnp.where(job_get(jst_ref, job) > 0.0, -1.0,
+                                           job_get(jst_ref, job)))
+
             return 0
 
         jax.lax.fori_loop(0, TB, step, 0)
         chosen_ref[0] = chosen_s[:]
 
+        @pl.when(jnp.logical_and(i == G - 1, ctrl_s[2] >= 0))
+        def _():
+            close_run(ctrl_s[3])
+
     return kernel
 
 
 def _pass_call(
-    taskrow, cf, nd, maxal, allocpos, tol, weights, block_size, interpret,
+    taskrow, cf, nd, maxal, allocpos, tol, need, weights, block_size, interpret,
     absolute=(),
 ):
-    """Build + invoke the pallas_call for one greedy pass → chosen[T_act].
-    All operands already device-resident/derived; traceable inside
-    lax.while_loop (the kernel is a plain XLA custom call)."""
+    """Build + invoke the pallas_call for one pass →
+    (placed[T_act], why[T_act, P], jstate[JS*128])."""
     T_act, RC = taskrow.shape
     R = tol.shape[1]
-    K = (RC - R - 2) // 2
+    K = (RC - R - 3) // 2
     C, NS, _ = cf.shape
+    JS = need.shape[0]
     ND = nd.shape[0]
     assert ND == 3 * R + 2 + 3 * K
     TB = block_size
     assert TB % LANES == 0 and T_act % TB == 0
     TBS = TB // LANES
-    kernel = _make_kernel(R, TB, NS, weights, K, absolute)
     G = T_act // TB
+    P = N_EXPLAIN_REASONS
+    kernel = _make_kernel(R, TB, NS, JS, weights, K, absolute, G)
 
     full = lambda *shape: pl.BlockSpec(
         shape, lambda i: tuple(0 for _ in shape), memory_space=pltpu.VMEM
     )
-    chosen = pl.pallas_call(
+    chosen, why, jst = pl.pallas_call(
         kernel,
         grid=(G,),
         in_specs=[
@@ -345,60 +482,42 @@ def _pass_call(
             full(ND, NS, LANES),
             full(R, NS, LANES),
             full(R, NS, LANES),
+            full(JS, LANES),
         ],
-        out_specs=pl.BlockSpec(
-            (1, TBS, LANES), lambda i: (i, 0, 0), memory_space=pltpu.VMEM
-        ),
-        out_shape=jax.ShapeDtypeStruct((G, TBS, LANES), jnp.int32),
+        out_specs=[
+            pl.BlockSpec((1, TBS, LANES), lambda i: (i, 0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, P, TBS, LANES), lambda i: (i, 0, 0, 0), memory_space=pltpu.VMEM),
+            full(JS, LANES),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((G, TBS, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((G, P, TBS, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((JS, LANES), jnp.float32),
+        ],
         scratch_shapes=[
             pltpu.VMEM((R, NS, LANES), jnp.float32),
             pltpu.VMEM((1, NS, LANES), jnp.float32),
             pltpu.VMEM((TBS, LANES), jnp.int32),
             pltpu.VMEM((NS, LANES), jnp.float32),
             pltpu.VMEM((1, RC), jnp.float32),
-            pltpu.SMEM((2,), jnp.int32),
-        ] + ([pltpu.VMEM((K, NS, LANES), jnp.float32)] if K else []),
+            pltpu.SMEM((5,), jnp.int32),
+            pltpu.VMEM((R, NS, LANES), jnp.float32),
+            pltpu.VMEM((1, NS, LANES), jnp.float32),
+        ] + ([pltpu.VMEM((K, NS, LANES), jnp.float32)] * 2 if K else []),
         compiler_params=pallas_compiler_params(),
         interpret=interpret,
-    )(tol, taskrow, cf, nd, maxal, allocpos)
-    return chosen.reshape(T_act)
+    )(tol, taskrow, cf, nd, maxal, allocpos, need)
+    why = jnp.transpose(why, (0, 2, 3, 1)).reshape(T_act, P)
+    return chosen.reshape(T_act), why, jst.reshape(JS * LANES)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("weights", "block_size", "interpret", "absolute"),
 )
-def schedule_pass_pallas(
-    taskrow: jnp.ndarray,  # [T_act, R+2+2K] f32 — resreq lanes, class, active, spread
-    cf_u8: jnp.ndarray,  # [C, NS, 128] u8 class feasibility (incl. node_ok)
-    nd: jnp.ndarray,  # [3R+2+3K, NS, 128] — base | alloc | used0 | count0, maxt | spread
-    tol: jnp.ndarray,  # [1, R]
-    weights: ScoreWeights = DEFAULT_WEIGHTS,
-    block_size: int = 256,
-    interpret: bool = False,
-    absolute: Tuple[bool, ...] = (),
-) -> jnp.ndarray:
-    """One greedy pass on TPU → chosen[T_act] (node index or -1)."""
-    R = tol.shape[1]
-    # Device-side derivations (XLA, outside the kernel) — keeps the
-    # host→device transfer to taskrow + u8 feasibility + one node array.
-    cf = cf_u8.astype(jnp.float32)
-    alloc = nd[R : 2 * R]
-    maxal = jnp.maximum(alloc, 1.0)
-    allocpos = (alloc > 0.0).astype(jnp.float32)
-    return _pass_call(
-        taskrow, cf, nd, maxal, allocpos, tol, weights, block_size, interpret,
-        absolute,
-    )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("weights", "block_size", "gang_rounds", "interpret", "absolute"),
-)
 def schedule_session_pallas(
     taskrow: jnp.ndarray,  # [T_act, R+2+2K] f32 (active column ignored)
-    cf_u8: jnp.ndarray,  # [C, NS, 128] u8
+    cf_u8: jnp.ndarray,  # [C, NS, 128] u8 class codes
     nd: jnp.ndarray,  # [3R+2+3K, NS, 128]
     tol: jnp.ndarray,  # [1, R]
     task_job: jnp.ndarray,  # [T_act] i32 → job row
@@ -407,66 +526,38 @@ def schedule_session_pallas(
     active0: jnp.ndarray,  # [T_act] bool
     weights: ScoreWeights = DEFAULT_WEIGHTS,
     block_size: int = 256,
-    gang_rounds: int = 3,
     interpret: bool = False,
     absolute: Tuple[bool, ...] = (),
-) -> jnp.ndarray:
-    """Whole session on device → assignment[T_act] (node index or -1,
-    gang-committed only).
-
-    The adaptive gang fixpoint of run_packed (kernels.py:459) runs as a
-    `lax.while_loop` around the Pallas pass: each round re-runs the scan
-    with non-ready jobs' tasks deactivated, stopping as soon as the
-    active set is stable (well-provisioned sessions: one round) or after
-    ``gang_rounds`` rounds (an unsettled fixpoint ships the last round's
-    commits — always individually valid placements).  One fused program
-    ⇒ one host→device→host round trip per session."""
+):
+    """Whole session on device → (assignment[T_act] committed node or
+    -1, placed[T_act], why[T_act, P]) — kernels.ScanOutcome's fields:
+    one pass with the per-job commit/discard inside the kernel, so the
+    session pays one host→device→host round trip."""
     R = tol.shape[1]
     J = job_min_avail.shape[0]
+    JS = -(-J // LANES)
     cf = cf_u8.astype(jnp.float32)
     alloc = nd[R : 2 * R]
     maxal = jnp.maximum(alloc, 1.0)
     allocpos = (alloc > 0.0).astype(jnp.float32)
-    minav = job_min_avail.astype(jnp.int32)
-    readyc = job_ready.astype(jnp.int32)
-
-    def cond(carry):
-        i, _active, _chosen, _committed, done = carry
-        return jnp.logical_and(~done, i < gang_rounds)
-
-    def body(carry):
-        i, active, _chosen, _committed, _done = carry
-        tr = taskrow.at[:, R + 1].set(active.astype(jnp.float32))
-        chosen = _pass_call(
-            tr, cf, nd, maxal, allocpos, tol, weights, block_size, interpret,
-            absolute,
-        )
-        assigned = jnp.zeros((J,), jnp.int32).at[task_job].add(
-            (chosen >= 0).astype(jnp.int32)
-        )
-        ready = assigned + readyc >= minav
-        committed = ready[task_job] & (chosen >= 0)
-        next_active = active & ready[task_job]
-        done = jnp.all(next_active == active)
-        return (i + 1, next_active, chosen, committed, done)
-
-    T_act = taskrow.shape[0]
-    init = (
-        jnp.int32(0),
-        active0,
-        jnp.full((T_act,), -1, jnp.int32),
-        jnp.zeros((T_act,), bool),
-        jnp.array(False),
+    need = job_min_avail.astype(jnp.int32) - job_ready.astype(jnp.int32)
+    need = jnp.minimum(need, 2**24).astype(jnp.float32)
+    need = jnp.zeros((JS * LANES,), jnp.float32).at[:J].set(need).reshape(JS, LANES)
+    tr = jnp.concatenate(
+        [taskrow.at[:, R + 1].set(active0.astype(jnp.float32)),
+         task_job.astype(jnp.float32)[:, None]], axis=1,
     )
-    _, _, chosen, committed, _ = jax.lax.while_loop(cond, body, init)
-    # committed ⊆ {chosen >= 0} ⊆ active-at-pass, so the host's final
-    # `committed & active` mask reduces to `committed`.
-    return jnp.where(committed, chosen, -1)
+    placed, why, jst = _pass_call(
+        tr, cf, nd, maxal, allocpos, tol, need, weights, block_size, interpret,
+        absolute,
+    )
+    committed = (placed >= 0) & (jst[task_job] != -2.0)
+    return jnp.where(committed, placed, -1), placed, why
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("weights", "block_size", "gang_rounds", "interpret", "absolute"),
+    static_argnames=("weights", "block_size", "interpret", "absolute"),
 )
 def schedule_session_pallas_packed(
     taskrow_ext: jnp.ndarray,  # [T_act, R+3+2K] — resreq, class, active0, spread, job
@@ -476,10 +567,9 @@ def schedule_session_pallas_packed(
     jobs2: jnp.ndarray,  # [2, J_pad] i32 — min_available | ready_count
     weights: ScoreWeights = DEFAULT_WEIGHTS,
     block_size: int = 256,
-    gang_rounds: int = 3,
     interpret: bool = False,
     absolute: Tuple[bool, ...] = (),
-) -> jnp.ndarray:
+):
     """Transfer-packed entry: the per-task job row and initial active
     mask ride inside the task rows and the two job vectors ride one
     buffer, so a session ships FIVE host→device transfers instead of
@@ -501,7 +591,6 @@ def schedule_session_pallas_packed(
         active0,
         weights=weights,
         block_size=block_size,
-        gang_rounds=gang_rounds,
         interpret=interpret,
         absolute=absolute,
     )
@@ -522,7 +611,8 @@ def prepare_pallas_arrays(
 ) -> Tuple[dict, int, int]:
     """Host-side packing into the kernel's plane layout (with K spread
     classes: task rows gain thr[K] | inc[K] after the active column, the
-    node array dom[K] | elig[K] | count0[K] after maxt).
+    node array dom[K] | elig[K] | count0[K] after maxt; the job column
+    is appended on the device).
 
     Nodes are cut to NK = ceil(n_nodes/128)*128 (instead of the pow2
     padded width) — every per-step op is O(NK), so the tighter width is a
@@ -540,22 +630,24 @@ def prepare_pallas_arrays(
     R = snap.task_resreq.shape[1]
 
     task_cls, class_sel, class_tol = _feasibility_classes(snap)
-    # class feasibility: selector bits ⊆ node labels, node taints ⊆
-    # tolerations, node_ok — identical to schedule_pass's [C, N] matrix.
-    node_labels = snap.node_label_bits[:NV]
-    node_taints = snap.node_taint_bits[:NV]
-    sel_ok = ((class_sel[:, None, :] & ~node_labels[None, :, :]) == 0).all(-1)
-    tol_ok = ((node_taints[None, :, :] & ~class_tol[:, None, :]) == 0).all(-1)
+    # class codes: the first of node_ok, selector bits ⊆ node labels,
+    # node taints ⊆ tolerations each (class, node) pair fails, or
+    # N_EXPLAIN_REASONS where it passes — schedule_episodes' [C, N]
+    # plane; padding nodes (none past n_nodes) read N_EXPLAIN_REASONS + 1.
+    NV = min(NV, snap.n_nodes)
     C = class_sel.shape[0]
-    cf = np.zeros((C, NK), dtype=np.float32)
-    cf[:, :NV] = sel_ok & tol_ok & snap.node_ok[None, :NV]
+    cf = np.full((C, NK), N_EXPLAIN_REASONS + 1, dtype=np.int32)
+    cf[:, :NV] = class_reason_codes(
+        class_sel, class_tol, snap.node_label_bits[:NV],
+        snap.node_taint_bits[:NV], snap.node_ok[:NV], xp=np,
+    )
 
     K = snap.spread_max_skew.shape[0] if snap.has_spread else 0
     taskrow = np.zeros((T_act, R + 2 + 2 * K), dtype=np.float32)
     n_copy = min(T_act, T_pad)
     taskrow[:n_copy, :R] = snap.task_resreq[:n_copy]
     taskrow[:n_copy, R] = task_cls[:n_copy].astype(np.float32)
-    # active column filled per gang round by the caller
+    # active column filled by the caller
     spread_planes = []
     if K:
         thr, inc = spread_task_columns(snap, T_act)
@@ -604,10 +696,12 @@ def pallas_vmem_bytes(snap: PackedSnapshot, block_size: int = 256) -> int:
     NK = max(LANES, -(-max(snap.n_nodes, 1) // LANES) * LANES)
     _, class_sel, _ = _feasibility_classes(snap)
     K = snap.spread_max_skew.shape[0] if snap.has_spread else 0
-    return kernel_vmem_bytes(class_sel.shape[0], NK, R, block_size, K)
+    return kernel_vmem_bytes(class_sel.shape[0], NK, R, block_size, K,
+                             snap.job_min_available.shape[0])
 
 
-def kernel_vmem_bytes(C: int, NK: int, R: int, block_size: int = 256, K: int = 0) -> int:
+def kernel_vmem_bytes(C: int, NK: int, R: int, block_size: int = 256, K: int = 0,
+                      J: int = 0) -> int:
     """:func:`pallas_vmem_bytes` from the kernel's shape alone."""
     # cf + nd + maxal/allocpos + scratch (used, cnt, masked-score plane)
     # + Mosaic's temporaries for the full-width recompute.  Compiling for
@@ -615,17 +709,22 @@ def kernel_vmem_bytes(C: int, NK: int, R: int, block_size: int = 256, K: int = 0
     # C + 7R + 17 planes against the C + 14R + 6 estimated here
     # (tests/test_tpu_compile.py pins the admitted edge at R=2..4).  Each
     # spread class adds three node planes, its count scratch and about
-    # four temporaries of the per-step mask.
-    n_planes = C + (3 * R + 2) + 2 * R + (R + 2) + (8 * R + 2) + 8 * K
-    # task block streams as [TB, R+2+2K] → tiled to 128 lanes, double-buffered
-    return n_planes * NK * 4 + 2 * block_size * LANES * 4
+    # four temporaries of the per-step mask.  The copy of the node state
+    # a gang's run began from adds R + 1 planes and one per class.
+    n_planes = C + (3 * R + 2) + 2 * R + (R + 2) + (8 * R + 2) + 8 * K + (R + 1 + K)
+    # task block streams as [TB, R+3+2K] → tiled to 128 lanes, and the
+    # failing steps' reason counts as [P, TB], both double-buffered; the
+    # job need and state planes are [J/128, 128] each
+    job_planes = 2 * (-(-J // LANES)) * LANES * 4
+    return (n_planes * NK * 4 + 2 * block_size * LANES * 4
+            + 2 * N_EXPLAIN_REASONS * block_size * 4 + job_planes)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
         "T_rows", "R", "U", "C", "ND", "NS", "JP", "K",
-        "weights", "block_size", "gang_rounds", "interpret", "absolute",
+        "weights", "block_size", "interpret", "absolute",
     ),
 )
 def schedule_session_pallas_buf(
@@ -636,10 +735,9 @@ def schedule_session_pallas_buf(
     K: int = 0,
     weights: ScoreWeights = DEFAULT_WEIGHTS,
     block_size: int = 256,
-    gang_rounds: int = 3,
     interpret: bool = False,
     absolute: Tuple[bool, ...] = (),
-) -> jnp.ndarray:
+):
     """Two-buffer entry: the per-SESSION payload and the per-CLUSTER
     payload (class feasibility + node planes) arrive as two byte
     buffers, bitcast-unpacked on device.  The cluster buffer is
@@ -700,7 +798,7 @@ def schedule_session_pallas_buf(
 
     return schedule_session_pallas_packed(
         taskrow_ext, cf_u8, nd, tol, jobs2,
-        weights=weights, block_size=block_size, gang_rounds=gang_rounds,
+        weights=weights, block_size=block_size,
         interpret=interpret, absolute=absolute,
     )
 
@@ -796,7 +894,6 @@ def pallas_session_payload_bytes(snap: PackedSnapshot, block_size: int = 256) ->
 def make_session_dispatch(
     snap: PackedSnapshot,
     weights: ScoreWeights = DEFAULT_WEIGHTS,
-    gang_rounds: int = 3,
     block_size: int = 256,
     interpret: bool = False,
     prestage: bool = False,
@@ -853,7 +950,7 @@ def make_session_dispatch(
             return schedule_session_pallas_packed(
                 *(jnp.asarray(a) for a in args5),
                 weights=weights, block_size=block_size,
-                gang_rounds=gang_rounds, interpret=interpret, absolute=absolute,
+                interpret=interpret, absolute=absolute,
             )
     else:
         task_job16[:n_tj] = snap.task_job[:n_tj].astype(np.uint16)
@@ -879,7 +976,7 @@ def make_session_dispatch(
             T_rows=T_rows, R=R, U=U_pad, C=arrays["cf_u8"].shape[0],
             ND=arrays["nd"].shape[0], NS=arrays["nd"].shape[1], JP=JP, K=K,
             weights=weights, block_size=block_size,
-            gang_rounds=gang_rounds, interpret=interpret, absolute=absolute,
+            interpret=interpret, absolute=absolute,
         )
 
         def dispatch():
@@ -889,23 +986,38 @@ def make_session_dispatch(
     return dispatch, T_act
 
 
+def run_outcome_pallas(
+    snap: PackedSnapshot,
+    weights: ScoreWeights = DEFAULT_WEIGHTS,
+    block_size: int = 256,
+    interpret: bool = False,
+) -> ScanOutcome:
+    """Host wrapper: PackedSnapshot → ScanOutcome over the first
+    ``n_tasks`` rows.  Packs, makes ONE fused device call (the per-job
+    commit/discard included) and fetches the result.  The session ships
+    as one byte buffer; cluster planes ride the device-resident cache."""
+    dispatch, T_act = make_session_dispatch(
+        snap, weights=weights, block_size=block_size, interpret=interpret,
+    )
+    assignment, placed, why = (np.asarray(x) for x in dispatch())
+    n = min(snap.n_tasks, T_act)
+    out = ScanOutcome(
+        np.full(snap.n_tasks, -1, dtype=np.int32),
+        np.full(snap.n_tasks, -2, dtype=np.int32),
+        np.zeros((snap.n_tasks, N_EXPLAIN_REASONS), dtype=np.int32),
+    )
+    out.assignment[:n] = assignment[:n]
+    out.placed[:n] = placed[:n]
+    out.reasons[:n] = why[:n]
+    return out
+
+
 def run_packed_pallas(
     snap: PackedSnapshot,
     weights: ScoreWeights = DEFAULT_WEIGHTS,
-    gang_rounds: int = 3,
     block_size: int = 256,
     interpret: bool = False,
 ) -> np.ndarray:
-    """Host wrapper: PackedSnapshot → assignment[T].  Packs, makes ONE
-    fused device call (gang fixpoint included — schedule_session_pallas),
-    fetches the committed assignment.  The session ships as one byte
-    buffer; cluster planes ride the device-resident cache."""
-    dispatch, T_act = make_session_dispatch(
-        snap, weights=weights, gang_rounds=gang_rounds,
-        block_size=block_size, interpret=interpret,
-    )
-    out = np.asarray(dispatch())
-    assignment = np.full(snap.n_tasks, -1, dtype=np.int32)
-    n = min(snap.n_tasks, T_act)
-    assignment[:n] = out[:n]
-    return assignment
+    """PackedSnapshot → committed assignment[n_tasks] of
+    :func:`run_outcome_pallas`."""
+    return run_outcome_pallas(snap, weights, block_size, interpret).assignment

@@ -46,7 +46,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from volcano_tpu.ops.blocked import (
     _block_scores,
-    gang_fixpoint,
+    one_pass,
     INT_BIG,
     make_inner_step,
     run_packed_blocked,
@@ -357,18 +357,15 @@ def run_packed_sharded(
     snap: PackedSnapshot,
     mesh: Mesh,
     weights: ScoreWeights = DEFAULT_WEIGHTS,
-    gang_rounds: int = 3,
     block_size: int = 64,
     top_k: int = 8,
-    discard_unstable: bool = False,
 ) -> np.ndarray:
-    """Host wrapper: PackedSnapshot → assignment[T] on a device mesh,
-    with the adaptive gang fixpoint (same protocol as run_packed_blocked)
-    around the sharded blocked pass.  Sessions with hard topology spread
-    take run_packed_blocked's way to the plain scan."""
-    if snap.has_spread:
-        return run_packed_blocked(snap, weights=weights, gang_rounds=gang_rounds,
-                                  discard_unstable=discard_unstable)
+    """Host wrapper: PackedSnapshot → assignment[n_tasks] from one
+    sharded blocked pass (run_packed_blocked's protocol).  Sessions with
+    hard topology spread or multi-task gangs take run_packed_blocked's
+    way to the plain scan."""
+    if snap.has_spread or snap.episodic:
+        return run_packed_blocked(snap, weights=weights)
     n_dev = mesh.devices.size
     if not f32_lr_exact(snap):
         weights = weights._replace(lr_int_exact=True)
@@ -377,21 +374,11 @@ def run_packed_sharded(
     fn = make_sharded_session(
         mesh, weights=weights, block_size=block_size, top_k=top_k
     )
-    # Place each invariant array ONCE with its final sharding — every
-    # device receives only its node shard; only `active` changes
-    # between gang rounds.
+    # Place each array with its final sharding — every device receives
+    # only its node shard.
     dev = [
         jax.device_put(a, NamedSharding(mesh, spec))
         for a, spec in zip(arrays, _IN_SPECS)
     ]
 
-    return gang_fixpoint(
-        lambda active: fn(*dev, active),
-        arrays[1],
-        snap.job_min_available,
-        snap.job_ready_count,
-        snap.n_tasks,
-        T_blk,
-        gang_rounds,
-        discard_unstable=discard_unstable,
-    )
+    return one_pass(lambda active: fn(*dev, active), snap.n_tasks, T_blk)

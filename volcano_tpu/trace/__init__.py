@@ -123,6 +123,7 @@ SPAN_NAMES = (
     "vtpu/allocate.explain",  # the reason-count reduction (ops/explain)
     "vtpu/allocate.apply",  # jax-allocate APPLY
     "vtpu/allocate.fast_apply",  # the bulk commit inside APPLY
+    "vtpu/allocate.gang_discard",  # recording the discarded gangs, inside the bulk commit
     "vtpu/allocate.explain_tail",  # APPLY's pack and reduction of the tasks ORDER left out
     "vtpu/allocate.host_sweep",  # one host predicate sweep, one task
     "vtpu/preempt.pack",

@@ -83,7 +83,7 @@ class Journal:
 
     def write_snapshot(
         self, cycle: int, snap, assignment, executor: str = "",
-        weights=None, gang_rounds=None,
+        weights=None,
     ) -> str:
         from volcano_tpu.ops.packing import save_snapshot
 
@@ -99,8 +99,6 @@ class Journal:
         if weights is not None:
             # ScoreWeights NamedTuple → float lanes (bool lanes included)
             extras["weights"] = np.asarray(tuple(weights), dtype=np.float64)
-        if gang_rounds is not None:
-            extras["gang_rounds"] = np.array(gang_rounds, dtype=np.int64)
         save_snapshot(snap, path, **extras)
         return path
 

@@ -74,7 +74,7 @@ class NullRecorder:
 
     def capture(
         self, snap, assignment, executor: str = "",
-        weights=None, gang_rounds=None,
+        weights=None,
     ) -> None:
         pass
 
@@ -277,19 +277,19 @@ class TraceRecorder:
 
     def capture(
         self, snap, assignment, executor: str = "",
-        weights=None, gang_rounds=None,
+        weights=None,
     ) -> None:
         """Persist the packed session + kernel assignment for the current
-        cycle when the sampling knob says so.  ``weights`` /
-        ``gang_rounds`` record the kernel parameters the assignment was
-        computed with, so replay re-runs the exact same configuration."""
+        cycle when the sampling knob says so.  ``weights`` records the
+        kernel parameters the assignment was computed with, so replay
+        re-runs the exact same configuration."""
         if not self.should_capture():
             return
         cid = self.cycle_id
         try:
             self.journal.write_snapshot(
                 cid, snap, assignment, executor,
-                weights=weights, gang_rounds=gang_rounds,
+                weights=weights,
             )
         except Exception:  # noqa: BLE001 — deliberate broad guard
             # same invariant as end_cycle: forensics must never break

@@ -66,7 +66,7 @@ class ReplayResult:
         )
 
 
-def run_snapshot(snap, executor: str = "jax", weights=None, gang_rounds: int = 3):
+def run_snapshot(snap, executor: str = "jax", weights=None):
     """PackedSnapshot → assignment[T] through the named executor."""
     from volcano_tpu.ops.kernels import DEFAULT_WEIGHTS
 
@@ -84,23 +84,23 @@ def run_snapshot(snap, executor: str = "jax", weights=None, gang_rounds: int = 3
                 "capture recorded non-default weights — replay it with "
                 "the jax/blocked executor instead"
             )
-        return native.baseline_allocate(snap, gang_rounds=gang_rounds)
+        return native.baseline_allocate(snap)
     if executor == "jax":
         from volcano_tpu.ops.kernels import run_packed
 
-        return run_packed(snap, weights=weights, gang_rounds=gang_rounds)
+        return run_packed(snap, weights=weights)
     if executor == "blocked":
         from volcano_tpu.ops.blocked import run_packed_blocked
 
-        return run_packed_blocked(snap, weights=weights, gang_rounds=gang_rounds)
+        return run_packed_blocked(snap, weights=weights)
     if executor == "pallas":
         from volcano_tpu.ops.pallas_session import run_packed_pallas
 
-        return run_packed_pallas(snap, weights=weights, gang_rounds=gang_rounds)
+        return run_packed_pallas(snap, weights=weights)
     if executor == "auto":
         from volcano_tpu.ops.dispatch import run_packed_auto
 
-        return run_packed_auto(snap, weights=weights, gang_rounds=gang_rounds)
+        return run_packed_auto(snap, weights=weights)
     raise ValueError(f"unknown executor {executor!r}; expected one of {EXECUTORS}")
 
 
@@ -152,11 +152,10 @@ def replay(
                 RuntimeWarning,
                 stacklevel=2,
             )
-    gang_rounds = int(extras.get("gang_rounds", 3))
+    # journals written before the per-job commit/discard moved into the
+    # scan may carry a ``gang_rounds`` extra: it bounds nothing now
     replayed = np.asarray(
-        run_snapshot(
-            snap, executor=executor, weights=weights, gang_rounds=gang_rounds
-        ),
+        run_snapshot(snap, executor=executor, weights=weights),
         dtype=np.int64,
     )
 
