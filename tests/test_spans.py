@@ -228,6 +228,7 @@ def test_cycle_spans_are_all_named_in_span_names(tmp_path):
         "vtpu/action.backfill", "vtpu/allocate.order", "vtpu/allocate.pack",
         "vtpu/allocate.dispatch", "vtpu/allocate.apply",
         "vtpu/allocate.host_sweep", "vtpu/statement.commit", "vtpu/cache.flush",
+        "vtpu/cache.bind_batch",
     } <= names
 
 

@@ -502,8 +502,7 @@ def try_fast_apply(
     import time
 
     t0 = time.perf_counter()
-    if bulk:
-        cache.bind_batch([(t, t.node_name) for t in bulk])
+    counts = cache.bind_batch([(t, t.node_name) for t in bulk]) if bulk else None
     # what the scheduling thread actually paid for the commit: with the
     # pipelined plane this is the mutex-held state mutation plus the
     # queue handoff — the binder/bus round trips land on the bind
@@ -513,6 +512,11 @@ def try_fast_apply(
     _ja.last_phase_stats["commit_handoff_ms"] = (
         time.perf_counter() - t0
     ) * 1e3
+    # the batch's binds and the distinct jobs and nodes it marked (a
+    # cache stand-in that records binds without marking returns None)
+    binds, jobs_marked, nodes_marked = counts or (0, 0, 0)
+    _ja.last_phase_stats.update(commit_binds=binds, commit_jobs_marked=jobs_marked,
+                                commit_nodes_marked=nodes_marked)
     _ja.last_phase_stats["fast_apply_tasks"] = len(bulk)
     _ja.last_phase_stats["fast_apply_explained"] = len(failed)
     n_disc = len(discarded_jobs)
@@ -523,7 +527,7 @@ def try_fast_apply(
                         ("gang_outcomes_device", len(decided_jobs))):
         _ja.last_phase_stats[key] = _ja.last_phase_stats.get(key, 0) + n_jobs
     # journal only after the batch landed — "bind" means an actual
-    # cache bind, and bind_batch mutates nothing when it raises
+    # cache bind, and bind_batch dispatches nothing when it raises
     if ssn._trace.enabled:
         for t in bulk:
             ssn._trace.decision("bind", t.uid, t.node_name)

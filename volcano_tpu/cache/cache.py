@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import threading
 from concurrent.futures import Future, ThreadPoolExecutor
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from volcano_tpu.api import (
     ClusterInfo,
@@ -500,9 +500,10 @@ class SchedulerCache(Cache):
         self._rev += 1
         self._job_mut_rev[uid] = self._rev
         # every handler marks AFTER mutating the JobInfo, so the ledger
-        # observes the post-mutation truth here — one diff per event,
-        # never a sweep.  (delete_pod_group marks with pod_group already
-        # None before dropping the job, so the retraction is covered.)
+        # observes the post-mutation truth here — one diff per event (per
+        # job of a bind_batch), never a sweep.  (delete_pod_group marks
+        # with pod_group already None before dropping the job, so the
+        # retraction is covered.)
         self.share_ledger.observe(self.jobs.get(uid), uid)
 
     def _mark_topology(self) -> None:
@@ -1081,38 +1082,65 @@ class SchedulerCache(Cache):
         if fp.enabled and fp.should("cache.bind_fail"):
             raise RuntimeError("fault-injected bind failure")
 
-    def bind_batch(self, pairs) -> None:
+    def bind_batch(self, pairs) -> Tuple[int, int, int]:
         """Bind many (task_info, hostname) pairs: the same per-task state
         mutations as :meth:`bind` under ONE mutex hold, with the
         binder/event effects submitted as one job that preserves task
         order.  This is the bulk-commit path for fully-placed device
         sessions (actions/fast_apply.py) — at 50k binds the per-call
-        mutex/submit overhead of bind() dominates the real work."""
-        bound = []
-        with self._mutex:
-            # resolve everything before mutating anything, so a bad pair
-            # cannot leave earlier tasks mutated with their binder
-            # effects dropped (per-task bind() submits effects pairwise;
-            # the batch must not weaken that failure contract)
-            resolved = []
-            for task_info, hostname in pairs:
-                job, task = self._find_job_and_task(task_info)
-                node = self.nodes.get(hostname)
-                if node is None:
-                    raise KeyError(
-                        f"failed to bind task {task.uid} to host {hostname}:"
-                        " host not found"
-                    )
-                resolved.append((job, task, node, hostname))
-            for job, task, node, hostname in resolved:
-                job.update_task_status(task, TaskStatus.Binding)
-                task.node_name = hostname
-                node.add_task(task)
-                self._mark_job(task.job)
-                self._mark_node(hostname)
-                bound.append((task, hostname))
+        mutex/submit overhead of bind() dominates the real work.
 
-        self._dispatch_binds(bound)
+        Each distinct job and node is marked once, after the batch's
+        mutations, not once per bind: a gang of k workers observes its
+        Pending bucket once instead of k times over k-1, k-2, … 0 tasks.
+        The ledger's sums are exact, so one observe after a job's last
+        bind leaves what k observes would, and no reader sees the state
+        between binds, which all happen under the one mutex hold.
+        Returns ``(binds, jobs, nodes)``: the pairs bound and the
+        distinct jobs and nodes marked."""
+        bound = []
+        # job uids in the order of each job's LAST bind, so the ledger's
+        # queue and namespace dicts end in the order per-bind marks leave
+        # them (a queue whose only job is re-observed moves to the end);
+        # node names in first-seen order
+        jobs: Dict[str, None] = {}
+        nodes: Dict[str, None] = {}
+        with trace.span("vtpu/cache.bind_batch", "cache") as sp:
+            with self._mutex:
+                # resolve everything before mutating anything, so a bad
+                # pair cannot leave earlier tasks mutated with their
+                # binder effects dropped (per-task bind() submits effects
+                # pairwise; the batch must not weaken that failure contract)
+                resolved = []
+                for task_info, hostname in pairs:
+                    job, task = self._find_job_and_task(task_info)
+                    node = self.nodes.get(hostname)
+                    if node is None:
+                        raise KeyError(
+                            f"failed to bind task {task.uid} to host {hostname}:"
+                            " host not found"
+                        )
+                    resolved.append((job, task, node, hostname))
+                try:
+                    for job, task, node, hostname in resolved:
+                        jobs.pop(task.job, None)
+                        jobs[task.job] = None
+                        nodes[hostname] = None
+                        job.update_task_status(task, TaskStatus.Binding)
+                        task.node_name = hostname
+                        node.add_task(task)
+                        bound.append((task, hostname))
+                finally:
+                    # a raise part-way (an out-of-sync node's add_task)
+                    # still marks every job and node the loop touched:
+                    # the ledger is never left behind a job's state
+                    for uid in jobs:
+                        self._mark_job(uid)
+                    for name in nodes:
+                        self._mark_node(name)
+            sp.set(binds=len(bound), jobs=len(jobs), nodes=len(nodes))
+            self._dispatch_binds(bound)
+        return len(bound), len(jobs), len(nodes)
 
     # ---- commit dispatch: pipelined plane or synchronous effects ----
 

@@ -112,6 +112,8 @@ class ShareLedger:
         already maintained on JobInfo, only the Pending bucket is
         summed — so a bind burst over a 1M-resident cache touches one
         job's pending tasks per event, never the other 999 999 jobs.
+        ``SchedulerCache.bind_batch`` observes each job of its batch
+        once, after the batch's last bind, not once per bind.
         """
         if job is None or job.pod_group is None:
             self.forget(uid)

@@ -131,6 +131,7 @@ SPAN_NAMES = (
     "vtpu/preempt.apply",  # evictions, pipelines and their commit
     "vtpu/statement.commit",  # Statement.commit
     "vtpu/cache.flush",  # SchedulerCache.flush
+    "vtpu/cache.bind_batch",  # SchedulerCache.bind_batch (args binds, jobs, nodes)
     "vtpu/gc",  # a generation-2 collection (utils/gcutil.py)
     "vtpu/gc.quiesce",  # the collector policy's thaw, collect and freeze, after vtpu/cycle
 )
